@@ -69,9 +69,8 @@ STABLEHLO_OP: dict[str, str] = {
 
 # sub-jaxpr-carrying primitives that are structurally transparent (no
 # control-flow semantics of their own)
-_TRANSPARENT = ("pjit", "closed_call", "core_call", "custom_jvp_call",
-                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
-                "checkpoint", "custom_lin")
+_TRANSPARENT = ("jit", "closed_call", "call", "custom_jvp_call",
+                "custom_vjp_call", "remat2", "custom_lin")
 
 
 def _axes_of(eqn: Any) -> tuple[str, ...]:

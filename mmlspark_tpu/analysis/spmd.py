@@ -89,9 +89,13 @@ class ShardState:
     partial: frozenset = frozenset()
 
     @classmethod
-    def from_names(cls, names: dict, ndim: int) -> "ShardState":
-        """From a shard_map ``in_names``/``out_names`` dim→axes dict."""
-        return cls(tuple(tuple(names.get(d, ())) for d in range(ndim)))
+    def from_spec(cls, spec: Any, ndim: int) -> "ShardState":
+        """From one entry of a shard_map eqn's ``in_specs``/``out_specs``
+        (a ``PartitionSpec``: per dim None, one axis name, or a tuple of
+        them; trailing dims it does not mention are replicated)."""
+        dims = [() if e is None else (e,) if isinstance(e, str) else tuple(e)
+                for e in spec]
+        return cls(tuple(dims + [()] * (ndim - len(dims))))
 
     def axes_used(self) -> set[str]:
         return {a for axes in self.dims for a in axes} | set(self.partial)
@@ -336,10 +340,10 @@ def _verify_shard_map_eqn(eqn: Any, producers: dict,
     findings: list[SpmdFinding] = []
 
     in_states = []
-    for k, (names, var) in enumerate(zip(eqn.params["in_names"],
-                                         eqn.invars)):
+    for k, (spec, var) in enumerate(zip(eqn.params["in_specs"],
+                                        eqn.invars)):
         ndim = len(getattr(var.aval, "shape", ()))
-        st = ShardState.from_names(names, ndim)
+        st = ShardState.from_spec(spec, ndim)
         in_states.append(st)
         # SPMD101: axis names the mesh does not carry
         bad = [a for a in st.axes_used() if a not in mesh_shape]
@@ -380,10 +384,10 @@ def _verify_shard_map_eqn(eqn: Any, producers: dict,
     # claims replicated (SPMD103 — the check check_vma=False disables)
     out_vary = varying_axes(body, in_states)
     out_states = []
-    for k, (names, var, vary) in enumerate(zip(eqn.params["out_names"],
-                                               eqn.outvars, out_vary)):
+    for k, (spec, var, vary) in enumerate(zip(eqn.params["out_specs"],
+                                              eqn.outvars, out_vary)):
         ndim = len(getattr(var.aval, "shape", ()))
-        st = ShardState.from_names(names, ndim)
+        st = ShardState.from_spec(spec, ndim)
         claimed_replicated = big_axes - st.axes_used()
         escape = set(vary) & claimed_replicated
         if escape:
@@ -426,7 +430,7 @@ def _shard_map_sites(jaxpr: Any, prefix: str):
                    or eqn.params.get("fun_jaxpr")) \
                 if isinstance(eqn.params, dict) else None
             if sub is not None:
-                subs = [(name if name not in ("pjit", "closed_call")
+                subs = [(name if name not in ("jit", "closed_call")
                          else "", sub)]
         for label, sub in subs:
             sub = sub.jaxpr if hasattr(sub, "jaxpr") else sub

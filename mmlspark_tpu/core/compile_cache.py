@@ -256,10 +256,14 @@ class CompileCache:
 
     # -- load --
 
-    def load(self, fingerprint: str, shape_key: str) -> Any | None:
-        """Deserialize one cached executable. ``None`` on a plain miss;
-        :class:`CompileCacheError` (after quarantining the entry) when
-        the entry exists but must not be served."""
+    def load(self, fingerprint: str, shape_key: str,
+             devices: Any) -> Any | None:
+        """Deserialize one cached executable onto ``devices`` (the
+        program's own mesh devices, in mesh order — left to default, jax
+        loads onto every device of the backend, and a one-device program
+        then refuses its arguments on a multi-device host). ``None`` on a
+        plain miss; :class:`CompileCacheError` (after quarantining the
+        entry) when the entry exists but must not be served."""
         d = self._entry_dir(fingerprint, shape_key)
         if not os.path.isdir(d):
             return None
@@ -272,7 +276,9 @@ class CompileCache:
             with open(os.path.join(d, TREES_FILE), "rb") as f:
                 in_tree, out_tree = pickle.load(f)
             from jax.experimental import serialize_executable as se
-            loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+            loaded = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=list(devices))
         except CompileCacheError:
             self._quarantine(d)
             raise
@@ -442,10 +448,11 @@ class CachedJit:
     """
 
     def __init__(self, jitted: Any, fingerprint: str,
-                 cache: CompileCache):
+                 cache: CompileCache, devices: Any):
         self._jit = jitted
         self.fingerprint = fingerprint
         self._cache = cache
+        self._devices = tuple(devices)
         self._programs: dict[str, Any] = {}
         self._lock = threading.Lock()
 
@@ -479,7 +486,7 @@ class CachedJit:
     def _resolve(self, key: str, args: tuple) -> Any:
         cache = self._cache
         try:
-            prog = cache.load(self.fingerprint, key)
+            prog = cache.load(self.fingerprint, key, self._devices)
         except CompileCacheError as e:
             _log.warning("compile cache: %s — compiling in memory", e)
             prog = None

@@ -213,9 +213,9 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
             return bundle.module.apply({"params": params}, x, output=node)
 
         if mesh.devices.size == 1:
-            # single-device fast path: plain placement avoids the sharded
-            # transfer/fetch machinery (which costs a round-trip per shard —
-            # pathological through remote-device tunnels)
+            # single-device path: plain placement and a plain jit instead
+            # of a one-shard NamedSharding (the fork train/loop.py and
+            # core/plan.py share; unmeasured on the chip — ROADMAP Design 3)
             dev = mesh.devices.reshape(-1)[0]
             dev_params = jax.device_put(bundle.params, dev)
             fn = jax.jit(fwd)

@@ -36,8 +36,8 @@ class BiLSTMTagger(nn.Module):
     # lax.scan unroll factor for the recurrence: an RNN step's matmuls are
     # tiny, so per-iteration loop overhead dominates — unrolling 16 steps
     # per scan iteration measured 11.7 → 25.0M tokens/s at B=64/L=613 on
-    # v5e (knee at 16; 64+ regresses and blows up compile time,
-    # PERF_NOTES round 5). Params are unaffected — execution detail only
+    # v5e in round 5 (knee at 16; 64+ regresses and blows up compile
+    # time; not re-measured). Params are unaffected — execution detail only
     unroll: int = 16
 
     OUTPUT_NAMES = ("features", "logits")

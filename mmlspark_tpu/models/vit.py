@@ -34,8 +34,8 @@ class BhtdSelfAttention(nn.Module):
     so checkpoints are interchangeable — only the compute layout differs:
     the head axis moves next to batch BEFORE the score/weighted-sum
     einsums instead of XLA inserting transposes around each one
-    (measured ~4% faster fwd+bwd at ViT-B shapes on v5e, PERF_NOTES
-    round 4).
+    (measured ~4% faster fwd+bwd at ViT-B shapes on v5e in round 4; not
+    re-measured on current code).
 
     ``impl`` selects the attention compute (same params either way):
 

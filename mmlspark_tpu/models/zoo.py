@@ -31,8 +31,8 @@ class PatchConv3x3(nn.Module):
     2×2 space-to-depth form — numerically identical, MXU-shaped.
 
     A direct RGB-stem conv contracts over just 3 of the MXU's 128 lanes —
-    measured ~1.7 TFLOP/s on v5e, ~40× off peak, dominating the whole CIFAR
-    step (PERF_NOTES.md). Reorganizing 2×2 pixel blocks into channels makes
+    measured ~1.7 TFLOP/s on v5e in round 2, ~40× off peak, dominating the
+    whole CIFAR step (not re-measured on current code). Reorganizing 2×2 pixel blocks into channels makes
     the same op a [B·H/2·W/2, 9·4·cin] × [9·4·cin, 4·features] matmul
     (contraction 108 wide, output 256 wide for the CIFAR stem): 4× fewer
     output tiles, 4× the contraction depth. The block-form weight matrix is
@@ -111,7 +111,7 @@ class ConvNetCifar(nn.Module):
     num_classes: int = 10
     # MXU-sized widths: measured step MFU on v5e is 54.9% at (64,128,256)
     # but 76.7% at (128,256,512) — the narrow stem/blocks leave MXU lanes
-    # idle, wide ones fill them (PERF_NOTES.md round-2 table)
+    # idle, wide ones fill them (round-2 measurement, not re-measured)
     widths: Sequence[int] = (128, 256, 512)
     dense_width: int = 512
     dtype: Any = jnp.bfloat16
@@ -229,8 +229,8 @@ def _folded_resnet_bundle(name: str, factory: Any, num_classes: int,
     bn_net = factory(num_classes=num_classes, norm="batch", **kw)
     dummy = jnp.zeros((1, input_size, input_size, 3), jnp.float32)
     # init + fold are host-side setup (the fold itself is numpy): pin them
-    # to the CPU backend so bundle construction never pays a remote-device
-    # compile/transfer for a 224² init it immediately folds away. A
+    # to the CPU backend so bundle construction never compiles a 224² init
+    # for the accelerator only to fold it away on the host. A
     # JAX_PLATFORMS pin that excludes cpu makes the backend unavailable —
     # fall back to the default device there
     import contextlib
@@ -260,8 +260,8 @@ def resnet50_infer_bundle(num_classes: int = 1000, input_size: int = 224,
     is the TPU-native equivalent: ``norm="none"`` architecture + folded
     params (bf16 by default — frozen inference weights need no f32
     master) + the space-to-depth stem (``stem="s2d"``, same param layout).
-    Measured on v5e at batch 256/224²: 0.39 MFU (GroupNorm train variant)
-    → 0.64 MFU folded (PERF_NOTES round 5)."""
+    Measured on v5e at batch 256/224² in round 5: 0.39 MFU (GroupNorm
+    train variant) → 0.64 MFU folded; not re-measured on current code."""
     from mmlspark_tpu.models.resnet import resnet50
     return _folded_resnet_bundle("ResNet50_Infer", resnet50, num_classes,
                                  input_size, seed, param_dtype, stem=stem,

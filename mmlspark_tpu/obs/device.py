@@ -185,14 +185,8 @@ def poll_memory(reg: Any = None) -> dict:
     # imports jax early but calls jax.distributed.initialize() later
     # (the poll would lock it into single-process mode / grab HBM).
     # Poll only once the app itself has brought a backend up.
-    try:
-        from jax._src import xla_bridge as _xb
-        initialized = (_xb.backends_are_initialized()
-                       if hasattr(_xb, "backends_are_initialized")
-                       else bool(getattr(_xb, "_backends", None)))
-    except Exception:  # pragma: no cover - private-API drift
-        initialized = False
-    if not initialized:
+    from jax._src import xla_bridge as _xb
+    if not _xb.backends_are_initialized():
         return {}
 
     reg = reg if reg is not None else _registry()

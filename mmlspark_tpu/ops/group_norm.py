@@ -1,22 +1,24 @@
 """Fused GroupNorm(+activation) Pallas kernel for NHWC feature maps.
 
-Motivation (PERF_NOTES round 3): ResNet-50 featurization is
-bandwidth-limited and its GroupNorm layers are pure HBM traffic — XLA
-lowers GN as separate reduce + normalize passes over the feature map.
-This kernel reads each sample's (H·W, C) block into VMEM once and does
-everything there: per-group statistics via two tiny mask matmuls
-(lane-aligned — no awkward lane-dim reshapes), normalization, scale/bias,
-and the optional ReLU that always follows GN in the ResNet blocks. One
-HBM read + one HBM write per element.
+ResNet-50's GroupNorm layers are pure HBM traffic — XLA lowers GN as
+separate reduce + normalize passes over the feature map. This kernel
+reads each sample's (H·W, C) block into VMEM once and does everything
+there: per-group statistics via two tiny mask matmuls (lane-aligned — no
+awkward lane-dim reshapes), normalization, scale/bias, and the optional
+ReLU that always follows GN in the ResNet blocks. One HBM read + one HBM
+write per element.
 
 Per-sample VMEM footprint: the largest ResNet-50 GN input is 56·56·256
 (f32 ≈ 3.2 MB in + out) — comfortably inside the ~16 MB budget, so the
-grid is simply the batch dimension.
+grid is simply the batch dimension. The wrapper hands the kernel
+``[N, H·W, C]`` (a free reshape in XLA), so no spatial size needs an
+in-kernel relayout.
 
 Training still works: ``jax.custom_vjp`` routes the backward through the
 jnp reference implementation (correctness first; the forward is the
-featurize/inference hot path). Non-TPU backends run the same kernel in
-interpreter mode, keeping CPU tests honest.
+featurize/inference hot path). The kernel is always compiled by Mosaic;
+off-TPU a caller asks for jax's Pallas interpreter explicitly
+(``pltpu.force_tpu_interpret_mode()``, what tier-1 does).
 """
 
 from __future__ import annotations
@@ -25,6 +27,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from mmlspark_tpu.ops.pallas.budget import (
+    VMEM_BUDGET, lane_pad, note_vmem_fallback,
+)
 
 
 def group_norm_reference(x: jnp.ndarray, scale: jnp.ndarray,
@@ -47,12 +53,9 @@ def group_norm_reference(x: jnp.ndarray, scale: jnp.ndarray,
 
 def _gn_kernel(x_ref, scale_ref, bias_ref, o_ref, *, num_groups: int,
                eps: float, relu: bool):
-    import jax.experimental.pallas as pl  # noqa: F401 (kernel namespace)
-
-    h, w, c = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    hw = h * w
+    hw, c = x_ref.shape[1], x_ref.shape[2]
     cg = c // num_groups
-    xs = x_ref[0].reshape(hw, c).astype(jnp.float32)
+    xs = x_ref[0].astype(jnp.float32)
 
     # channel→group aggregation as a mask matmul (lane-aligned; avoids
     # lane-dim reshapes that Mosaic lays out badly)
@@ -85,11 +88,11 @@ def _gn_kernel(x_ref, scale_ref, bias_ref, o_ref, *, num_groups: int,
     rstd_c = jnp.dot(rstd, mask.T, precision=hi)       # (1, C)
 
     out = xc * rstd_c
-    out = out * scale_ref[0].reshape(1, c).astype(jnp.float32) \
-        + bias_ref[0].reshape(1, c).astype(jnp.float32)
+    out = out * scale_ref[...].astype(jnp.float32) \
+        + bias_ref[...].astype(jnp.float32)
     if relu:
         out = jnp.maximum(out, 0.0)
-    o_ref[0] = out.reshape(h, w, c).astype(o_ref.dtype)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _group_norm_fwd_pallas(x: jnp.ndarray, scale: jnp.ndarray,
@@ -101,22 +104,23 @@ def _group_norm_fwd_pallas(x: jnp.ndarray, scale: jnp.ndarray,
     n, h, w, c = x.shape
     kern = functools.partial(_gn_kernel, num_groups=num_groups, eps=eps,
                              relu=relu)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0),
+            pl.BlockSpec((1, h * w, c), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, c), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, c), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0),
+        out_specs=pl.BlockSpec((1, h * w, c), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n, h, w, c), x.dtype),
-        interpret=jax.default_backend() != "tpu",
-    )(x, scale.reshape(1, c), bias.reshape(1, c))
+        out_shape=jax.ShapeDtypeStruct((n, h * w, c), x.dtype),
+        name="group_norm",
+    )(x.reshape(n, h * w, c), scale.reshape(1, c), bias.reshape(1, c))
+    return out.reshape(n, h, w, c)
 
 
 def _fits_vmem(h: int, w: int, c: int, itemsize: int) -> bool:
@@ -125,11 +129,10 @@ def _fits_vmem(h: int, w: int, c: int, itemsize: int) -> bool:
     The lane dim pads to 128, and the kernel holds the input block, an f32
     working copy, its square, the f32 output, and the cast output —
     roughly ``HW × C_pad × (2·itemsize + 12)`` bytes. Blocks that would
-    blow the ~16 MB budget fall back to the XLA lowering (the 112×112×64
-    ResNet stem GN is the notable case: C=64 pads 2×)."""
-    c_pad = -(-c // 128) * 128
-    est = h * w * c_pad * (2 * itemsize + 12)
-    return est < 14 * 2 ** 20
+    blow the ~16 MB budget take the XLA lowering, visibly (the
+    112×112×64 ResNet stem GN is the notable case: C=64 pads 2×)."""
+    est = h * w * lane_pad(c) * (2 * itemsize + 12)
+    return est < VMEM_BUDGET
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -151,11 +154,13 @@ def _validate_groups(c: int, num_groups: int) -> None:
 def group_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                num_groups: int, eps: float = 1e-6,
                relu: bool = False) -> jnp.ndarray:
-    """Fused GroupNorm(+ReLU): Pallas forward (when the per-sample block
-    fits VMEM), reference-impl backward; XLA reference otherwise."""
+    """Fused GroupNorm(+ReLU): Pallas forward, reference-impl backward.
+    A per-sample block past the VMEM budget runs the XLA reference and
+    says so (warning + the ``ops.pallas.vmem_fallback`` counter)."""
     n, h, w, c = x.shape
     _validate_groups(c, num_groups)
     if not _fits_vmem(h, w, c, x.dtype.itemsize):
+        note_vmem_fallback("group_norm", (h, w, c))
         return group_norm_reference(x, scale, bias, num_groups, eps, relu)
     return _group_norm_custom(x, scale, bias, num_groups, eps, relu)
 

@@ -1,35 +1,33 @@
 """Hand-written Pallas kernels — the repo's kernel library.
 
 XLA schedules most device compute well (elementwise chains fuse into
-the surrounding program for free), so kernels exist only where the
-default lowering measurably loses to a VMEM-resident formulation:
+the surrounding program for free), so a kernel exists only where a
+VMEM-resident formulation avoids HBM traffic the default lowering pays:
 
-* :mod:`~mmlspark_tpu.ops.pallas.resize` — the train-input gather path
-  (crop + bilinear resize + normalize), which XLA lowers as four
-  batched gathers plus three f32 blend passes through HBM;
 * :mod:`~mmlspark_tpu.ops.pallas.attention` — flash-style fused
   attention (online-softmax tiling): the serving-path attention of
-  ``models/vit.py`` and the local block of
-  ``parallel/ring_attention.py``, replacing three HBM materializations
-  of the ``[B, H, Tq, Tk]`` score matrix.
+  ``models/vit.py``, the local block of ``parallel/ring_attention.py``
+  and the q_len=1 decode step of ``serve/generate.py``, replacing three
+  HBM materializations of the ``[B, H, Tq, Tk]`` score matrix.
 
-Every kernel keeps the PR 10 discipline: ONE shared body = Pallas
-kernel = XLA reference = numpy oracle, the kernel ULP-pinned against
-the reference UNDER JIT, ``interpret=True`` off-TPU so CPU tier-1
-executes the kernel body itself, and an ``impl: auto|xla|pallas`` flag
-with a VMEM-budget fallback to the reference.
+(The fused GroupNorm kernel lives next to its reference in
+``ops/group_norm.py``.)
+
+Every kernel keeps one discipline: ONE shared body = Pallas kernel = XLA
+reference = numpy oracle, the kernel pinned against the reference UNDER
+JIT, compiled by Mosaic on the chip and interpreted only where a caller
+asks for it (``pltpu.force_tpu_interpret_mode()`` — tier-1's
+``pallas_interpret`` fixture), and a VMEM-budget miss that is logged and
+counted (:mod:`~mmlspark_tpu.ops.pallas.budget`), never silent.
+``chip_smoke.py`` compiles each of them at one deployment shape.
 """
 
 from mmlspark_tpu.ops.pallas.attention import (
-    attention_block_update, flash_attention, flash_attention_host,
-    flash_attention_reference,
-)
-from mmlspark_tpu.ops.pallas.resize import (
-    fused_resize_norm, fused_resize_norm_host, fused_resize_norm_reference,
+    attention_block_update, decode_attention, flash_attention,
+    flash_attention_host, flash_attention_reference,
 )
 
 __all__ = [
-    "attention_block_update", "flash_attention", "flash_attention_host",
-    "flash_attention_reference", "fused_resize_norm",
-    "fused_resize_norm_host", "fused_resize_norm_reference",
+    "attention_block_update", "decode_attention", "flash_attention",
+    "flash_attention_host", "flash_attention_reference",
 ]

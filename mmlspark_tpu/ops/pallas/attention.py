@@ -1,7 +1,8 @@
 """Fused flash-style attention (online-softmax tiling) as one Pallas pass.
 
-The serving-path attention of :mod:`mmlspark_tpu.models.vit` and the
-local block of :mod:`mmlspark_tpu.parallel.ring_attention`. Under plain
+The serving-path attention of :mod:`mmlspark_tpu.models.vit`, the local
+block of :mod:`mmlspark_tpu.parallel.ring_attention`, and the q_len=1
+KV-cache decode step of :mod:`mmlspark_tpu.serve.generate`. Under plain
 XLA, attention materializes the ``[B, H, Tq, Tk]`` score matrix in HBM
 three times over (scores → masked scores → softmax weights) before the
 weighted sum; the kernel keeps one (batch, head) tile's Q/K/V blocks in
@@ -10,7 +11,7 @@ et al.'s FlashAttention recurrence — the same recurrence
 ``ring_attention`` already runs across ring hops, here applied across
 K blocks inside one chip), so the score matrix never touches HBM.
 
-The PR 10 kernel discipline (``ops/pallas/resize.py``):
+Kernel discipline:
 
 * ONE shared body — :func:`_online_update` (a single K/V block's
   online-softmax update over 2-D ``[T, D]`` tiles) and
@@ -18,22 +19,26 @@ The PR 10 kernel discipline (``ops/pallas/resize.py``):
   namespace, so the SAME code is the Pallas kernel body, the XLA
   reference (``vmap`` over batch × heads), and the numpy oracle —
   implementations cannot drift apart op by op;
-* the kernel is pinned ≤ 1 ULP against :func:`flash_attention_reference`
-  UNDER JIT (eager comparisons drift via FMA contraction — repo
-  convention), and the numpy oracle is pinned against the jitted
-  reference (tests/test_attention.py);
-* ``interpret=True`` off-TPU, so CPU tier-1 executes the kernel body
-  itself, not a shadow path;
+* the kernels are compiled by Mosaic, never interpreted by choice of the
+  wrapper: off-TPU the caller asks for the interpreter explicitly
+  (``jax.experimental.pallas.tpu.force_tpu_interpret_mode()``, what
+  tier-1 does), and without it a non-TPU backend refuses loudly;
 * ``impl: auto | xla | pallas`` selects the backend (auto = kernel on
-  TPU, reference elsewhere), and tiles past the VMEM budget fall back
-  to the reference — identical math, different schedule.
+  TPU, reference elsewhere). A tile past the VMEM budget under ``auto``
+  takes the reference and says so (a warning and the
+  ``ops.pallas.vmem_fallback`` counter); under ``pallas`` it raises.
+
+What reaches the kernel is shaped for the compiler: ``Tq`` is padded to
+the f32 sublane tile and ``Tk`` to a whole number of ``block_k`` stripes
+(padded keys are masked, padded query rows sliced away), the contraction
+``Q·Kᵀ`` is a transposed-rhs ``dot_general`` (no in-kernel transpose),
+and the mask is not an ``[B, Tq, Tk]`` tensor but the ``[B, 1, Tk]``
+key-validity row plus the causal triangle rebuilt from iotas per stripe.
 
 Masking semantics match ``parallel/ring_attention``: ``kv_mask`` is a
 ``[B, Tk]`` key-validity mask (True = real key), ``causal`` adds the
 lower-triangular constraint, and fully-masked query rows yield EXACT
-zeros (the guarded accumulator), not NaN. The mask ships as one
-``[B, Tq, Tk]`` int8 tensor consumed identically by all three
-implementations.
+zeros (the guarded accumulator), not NaN.
 """
 
 from __future__ import annotations
@@ -45,6 +50,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from mmlspark_tpu.ops.pallas.budget import (
+    VMEM_BUDGET, lane_pad, note_vmem_fallback,
+)
+
 IMPLS = ("auto", "xla", "pallas")
 
 # K-block width of the online-softmax loop: one MXU-lane-aligned stripe
@@ -54,6 +63,20 @@ DEFAULT_BLOCK_K = 128
 # the denominator guard for fully-masked query rows (exactly the
 # ring/ulysses value, so the paths agree bit-for-bit on masked rows)
 _DENOM_FLOOR = np.float32(1e-30)
+
+# the f32 sublane tile: kernel query rows are padded to a multiple of it
+# (a Tq=1 decode step becomes one aligned 8-row MXU pass)
+_SUBLANES = 8
+
+
+def _qk_t(q, ks, xp):
+    """``q [Tq, D] · ksᵀ [D, Tk] → [Tq, Tk]`` contracting the last dim of
+    both operands — the transposed-rhs matmul form, so the kernel never
+    materializes ``ksᵀ`` (Mosaic lowers it to one ``tpu.matmul``)."""
+    if xp is np:
+        return np.dot(q, ks.T)
+    return jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
@@ -65,7 +88,7 @@ def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
     ``[Tq, D]`` f32. Returns the updated ``(m, denom, acc)``. Also the
     per-hop local-block update of ``ring_attention`` (each ring step IS
     one such update with the resident K/V block)."""
-    scores = xp.dot(q, ks.T) * scale
+    scores = _qk_t(q, ks, xp) * scale
     scores = xp.where(keep, scores, -xp.inf)
     blk_max = xp.max(scores, axis=-1, keepdims=True)
     m_new = xp.maximum(m, blk_max)
@@ -77,28 +100,36 @@ def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
     return m_new, denom, acc
 
 
-def _flash_tile(q, k, v, keep, scale, xp, block_k: int):
+def _flash_tile(q, stripe, tk: int, scale, xp, block_k: int):
     """Full attention for one (batch, head) tile via the online-softmax
-    block loop: ``q`` ``[Tq, D]``, ``k``/``v`` ``[Tk, D]``, ``keep``
-    ``[Tq, Tk]`` bool → ``[Tq, D]`` f32. The block loop is a static
-    python loop (``Tk``/``block_k`` are trace-time constants), so the
-    SAME code unrolls identically in the kernel, the XLA reference, and
-    the numpy oracle."""
+    block loop: ``q`` ``[Tq, D]`` f32 against ``tk`` keys → ``[Tq, D]``
+    f32. ``stripe(start, stop)`` yields one K stripe as ``(ks, vs,
+    keep)`` — ``[w, D]`` f32 keys and values and the ``[Tq, w]`` bool
+    mask: slices of the operands and of the materialized mask in the
+    reference and the oracle; in the kernel, loads of exactly that stripe
+    from the refs (the mask rebuilt from the key row and iotas), so a
+    stripe never exists as a slice of a larger in-register value. The
+    block loop is a static python loop (``tk``/``block_k`` are trace-time
+    constants), so the SAME code unrolls identically in the kernel, the
+    XLA reference, and the numpy oracle."""
     tq, d = q.shape
-    tk = k.shape[0]
     m = xp.full((tq, 1), -xp.inf, np.float32)
     denom = xp.zeros((tq, 1), np.float32)
     acc = xp.zeros((tq, d), np.float32)
     for start in range(0, tk, block_k):
-        stop = min(start + block_k, tk)
-        m, denom, acc = _online_update(
-            q, k[start:stop], v[start:stop], keep[:, start:stop],
-            m, denom, acc, scale, xp)
+        ks, vs, keep = stripe(start, min(start + block_k, tk))
+        m, denom, acc = _online_update(q, ks, vs, keep, m, denom, acc,
+                                       scale, xp)
     return acc / xp.maximum(denom, _DENOM_FLOOR)
 
 
+def _sliced(k, v, keep):
+    """The reference's and the oracle's stripe source: plain slices."""
+    return lambda a, b: (k[a:b], v[a:b], keep[:, a:b])
+
+
 def _mask3(b: int, tq: int, tk: int, kv_mask, causal: bool):
-    """The one ``[B, Tq, Tk]`` int8 mask every implementation consumes
+    """The ``[B, Tq, Tk]`` int8 mask the reference and the oracle consume
     (True→1 = attend). Built with jnp (traced); callers on the host
     oracle path convert with numpy themselves via :func:`host_mask3`."""
     if kv_mask is None:
@@ -140,9 +171,9 @@ def flash_attention_reference(q, k, v, mask3, scale,
 
     def tile(q2, k2, v2, keep2):
         return _flash_tile(q2.astype(jnp.float32),
-                           k2.astype(jnp.float32),
-                           v2.astype(jnp.float32),
-                           keep2 != 0, s, jnp, block_k)
+                           _sliced(k2.astype(jnp.float32),
+                                   v2.astype(jnp.float32), keep2 != 0),
+                           k2.shape[0], s, jnp, block_k)
 
     over_h = jax.vmap(tile, in_axes=(0, 0, 0, None))
     return jax.vmap(over_h)(q, k, v, mask3)
@@ -162,74 +193,121 @@ def flash_attention_host(q, k, v, mask3, scale,
     for bi in range(b):
         keep = mask3[bi] != 0
         for hi in range(h):
-            out[bi, hi] = _flash_tile(q[bi, hi], k[bi, hi], v[bi, hi],
-                                      keep, s, np, block_k)
+            out[bi, hi] = _flash_tile(
+                q[bi, hi], _sliced(k[bi, hi], v[bi, hi], keep),
+                k.shape[2], s, np, block_k)
     return out
 
 
 # ---- the Pallas kernels ----
 
-def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *,
-                  scale: np.float32, block_k: int):
-    # one (batch, head) tile per program: refs arrive [1, 1, T, D] /
-    # [1, Tq, Tk]; squeeze to the 2-D tiles the shared body works on
+def _flash_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, *,
+                  scale: np.float32, block_k: int, causal: bool):
+    # one (batch, head) tile per program: refs arrive [1, 1, T, D] and
+    # the key-validity row [1, 1, Tk] int32; the shared body works on
+    # 2-D tiles, each K stripe loaded from the refs as it is needed
     q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    keep = mask_ref[0] != 0
-    o_ref[0, 0] = _flash_tile(q, k, v, keep, scale, jnp, block_k)
+    tq = q.shape[0]
+
+    def stripe(start, stop):
+        width = stop - start
+        keep = jnp.broadcast_to(kv_ref[0, :, start:stop],
+                                (tq, width)) != 0
+        if causal:
+            row = jax.lax.broadcasted_iota(jnp.int32, (tq, width), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (tq, width), 1)
+            keep = keep & (col + start <= row)
+        return (k_ref[0, 0, start:stop, :].astype(jnp.float32),
+                v_ref[0, 0, start:stop, :].astype(jnp.float32), keep)
+
+    o_ref[0, 0] = _flash_tile(q, stripe, k_ref.shape[2], scale, jnp,
+                              block_k)
 
 
-def _flash_call(q, k, v, mask3, scale, block_k: int):
+def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
     tk = k.shape[2]
+    # shape the operands for the compiler: Tq to whole f32 sublane
+    # tiles, Tk to whole block_k stripes. Padded keys are masked out of
+    # every denominator; padded query rows are sliced away below
+    pad_q = -tq % _SUBLANES
+    pad_k = -tk % block_k
+    kv_row = (jnp.ones((b, tk), jnp.int32) if kv_mask is None
+              else jnp.asarray(kv_mask, bool).astype(jnp.int32))
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        kv_row = jnp.pad(kv_row, ((0, 0), (0, pad_k)))
+    tq_p, tk_p = tq + pad_q, tk + pad_k
+
+    def tile(i, j):
+        return (i, j, 0, 0)
+
     kern = functools.partial(_flash_kernel, scale=np.float32(scale),
-                             block_k=block_k)
-    return pl.pallas_call(
+                             block_k=block_k, causal=causal)
+    out = pl.pallas_call(
         kern,
         grid=(b, h),
         in_specs=[
-            pl.BlockSpec((1, 1, tq, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tq, tk), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((1, 1, tq_p, d), tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, tk_p, d), tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, tk_p, d), tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, tk_p), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, tq, d), lambda i, j: (i, j, 0, 0),
+        out_specs=pl.BlockSpec((1, 1, tq_p, d), tile,
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, h, tq, d), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
-    )(q, k, v, mask3)
+        out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d), jnp.float32),
+        name="flash_attention",
+    )(q, k, v, kv_row.reshape(b, 1, tk_p))
+    return out[:, :, :tq] if pad_q else out
 
 
-def _fits_vmem(tq: int, tk: int, d: int, block_k: int) -> bool:
-    """Conservative per-(batch, head) VMEM bound: f32 Q/K/V tiles and
-    accumulator (lane dim padded to 128), the int8 mask, and two f32
-    score stripes of ``block_k``. Past the ~16 MB budget the wrapper
-    falls back to the XLA reference — identical math."""
-    d_pad = -(-d // 128) * 128
-    bk = -(-min(block_k, tk) // 128) * 128
-    est = 4 * (2 * tk * d_pad + 2 * tq * d_pad) \
-        + tq * (-(-tk // 128) * 128) + 4 * 2 * tq * bk
-    return est < 14 * 2 ** 20
+def _fits_vmem(tq: int, tk: int, d: int, block_k: int,
+               mask_bytes: int = 0) -> bool:
+    """Conservative per-(batch, head) VMEM bound, f32 operands assumed:
+    the double-buffered Q/K/V/out blocks, their f32 working copies and
+    the accumulator (lane dim padded to 128), three f32-sized score
+    stripes of ``block_k``, plus ``mask_bytes`` per score element for
+    a kernel that takes a materialized mask (the ring block update)."""
+    d_pad = lane_pad(d)
+    bk = lane_pad(min(block_k, tk))
+    est = 4 * d_pad * (3 * (tq + 2 * tk) + 3 * tq) \
+        + 4 * 3 * tq * bk + 2 * mask_bytes * tq * lane_pad(tk)
+    return est < VMEM_BUDGET
 
 
 def resolve_impl(impl: str) -> str:
     """``auto`` → the kernel on the TPU backend, the XLA reference
-    elsewhere (tier-1 exercises the kernel explicitly via
-    ``impl="pallas"``, which runs it in interpreter mode off-TPU)."""
+    elsewhere."""
     if impl not in IMPLS:
         raise ValueError(
             f"unknown attention impl {impl!r}; one of {IMPLS}")
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
+
+
+def _takes_kernel(impl: str, kernel: str, fits: bool, shape: tuple) -> bool:
+    """The one dispatch decision of every wrapper below. A tile that
+    does not fit VMEM never switches implementation quietly: under
+    ``auto`` the reference runs and the miss is logged and counted,
+    under an explicit ``pallas`` it raises."""
+    if resolve_impl(impl) != "pallas":
+        return False
+    if fits:
+        return True
+    if impl == "pallas":
+        raise ValueError(
+            f"{kernel}: tile {shape} exceeds the VMEM budget and "
+            "impl='pallas' was demanded; use impl='auto' or 'xla'")
+    note_vmem_fallback(kernel, shape)
+    return False
 
 
 def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
@@ -244,174 +322,29 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
     b, h, tq, d = q.shape
     tk = k.shape[2]
     s = _resolve_scale(scale, d)
-    mask3 = _mask3(b, tq, tk, kv_mask, causal)
-    if resolve_impl(impl) == "pallas" and _fits_vmem(tq, tk, d, block_k):
-        return _flash_call(q, k, v, mask3, s, block_k)
-    return flash_attention_reference(q, k, v, mask3, s, block_k)
-
-
-# ---- the KV-cache decode variant (q_len=1 against cached K/V) ----
-#
-# Autoregressive serving (serve/generate.py) holds a slot-major KV-cache
-# [slots, H, T_max, D] as plan-managed device state and issues ONE query
-# row per slot per token step. The decode attention is the same online-
-# softmax recurrence restricted to Tq=1 — ONE shared body
-# (`_decode_tile`) that is the Pallas kernel, the XLA reference, and the
-# numpy oracle — with the slot's validity mask ([S, T] — True up to the
-# slot's current length) standing in for the causal constraint (the
-# cache never holds a future position). A fully-masked slot (inactive,
-# length 0) yields EXACT zeros via the shared denominator floor, which
-# is what lets inactive slots ride the fixed-shape decode program
-# without polluting anything.
-
-
-def decode_mask2(s: int, tk: int, kv_mask):
-    """The one ``[S, Tk]`` int8 validity mask the decode implementations
-    consume (True→1 = attend). Traced (jnp); the host oracle converts
-    with :func:`host_decode_mask2`."""
-    if kv_mask is None:
-        return jnp.ones((s, tk), jnp.int8)
-    return jnp.asarray(kv_mask, bool).astype(jnp.int8)
-
-
-def host_decode_mask2(s: int, tk: int, kv_mask) -> np.ndarray:
-    """Numpy twin of :func:`decode_mask2` for the oracle path."""
-    if kv_mask is None:
-        return np.ones((s, tk), np.int8)
-    return np.asarray(kv_mask, bool).astype(np.int8)
-
-
-def _decode_tile(q, k, v, keep, scale, xp, block_k: int):
-    """THE shared decode body: attention of one query row against one
-    (slot, head) cache tile via the online-softmax block loop. ``q``
-    ``[1, D]`` f32, ``k``/``v`` ``[Tk, D]`` f32, ``keep`` ``[1, Tk]``
-    bool → ``[1, D]`` f32.
-
-    Same recurrence as :func:`_flash_tile`, with the two ``Tq=1``
-    contractions written as broadcast-multiply + axis reductions instead
-    of ``xp.dot``: a ``dot_general`` with an M=1 operand reassociates
-    under vmap batching (the reference) vs. the standalone lowering (the
-    kernel tile), drifting tens of ULPs — the reduce form lowers
-    bit-identically both ways, which is what lets the ≤ 1 ULP pin hold
-    for the decode variant too."""
-    tk = k.shape[0]
-    m = xp.full((1, 1), -xp.inf, np.float32)
-    denom = xp.zeros((1, 1), np.float32)
-    acc = xp.zeros((1, k.shape[1]), np.float32)
-    for start in range(0, tk, block_k):
-        stop = min(start + block_k, tk)
-        ks, vs, kp = k[start:stop], v[start:stop], keep[:, start:stop]
-        # [1, bk] scores: sum over D of q ⊙ ks (the vmap-stable form)
-        scores = xp.sum(q[:, None, :] * ks[None, :, :], axis=-1) * scale
-        scores = xp.where(kp, scores, -xp.inf)
-        blk_max = xp.max(scores, axis=-1, keepdims=True)
-        m_new = xp.maximum(m, blk_max)
-        corr = xp.where(xp.isfinite(m), xp.exp(m - m_new), np.float32(0))
-        p = xp.exp(xp.where(xp.isfinite(scores), scores - m_new,
-                            -xp.inf))
-        # [1, D] weighted values: sum over the block of p ⊙ vs
-        acc = acc * corr + xp.sum(p[0][:, None] * vs, axis=0)[None]
-        denom = denom * corr + xp.sum(p, axis=-1, keepdims=True)
-        m = m_new
-    return acc / xp.maximum(denom, _DENOM_FLOOR)
-
-
-def decode_attention_reference(q, k, v, mask2, scale,
-                               block_k: int = DEFAULT_BLOCK_K):
-    """Pure-XLA anchor of the decode variant: the SAME ``_decode_tile``
-    body vmapped over (slot, head). ``q`` ``[S, H, D]``, ``k``/``v``
-    ``[S, H, Tk, D]``, ``mask2`` ``[S, Tk]`` int8 (shared across heads).
-    Returns ``[S, H, D]`` float32."""
-    s = np.float32(scale)
-
-    def tile(q1, k2, v2, keep1):
-        out = _decode_tile(q1[None].astype(jnp.float32),
-                           k2.astype(jnp.float32),
-                           v2.astype(jnp.float32),
-                           keep1[None] != 0, s, jnp, block_k)
-        return out[0]
-
-    over_h = jax.vmap(tile, in_axes=(0, 0, 0, None))
-    return jax.vmap(over_h)(q, k, v, mask2)
-
-
-def decode_attention_host(q, k, v, mask2, scale,
-                          block_k: int = DEFAULT_BLOCK_K) -> np.ndarray:
-    """Numpy oracle of the decode variant: identical tile body,
-    python-looped over (slot, head)."""
-    q = np.asarray(q, np.float32)
-    k = np.asarray(k, np.float32)
-    v = np.asarray(v, np.float32)
-    mask2 = np.asarray(mask2)
-    sc = np.float32(scale)
-    s, h, d = q.shape
-    out = np.empty((s, h, d), np.float32)
-    for si in range(s):
-        keep = mask2[si][None] != 0
-        for hi in range(h):
-            out[si, hi] = _decode_tile(q[si, hi][None], k[si, hi],
-                                       v[si, hi], keep, sc, np,
-                                       block_k)[0]
-    return out
-
-
-def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *,
-                   scale: np.float32, block_k: int):
-    # one (slot, head) tile per program: q arrives [1, 1, D] (a single
-    # query row), K/V [1, 1, Tk, D], the mask [1, Tk]; the shared body
-    # runs on the 2-D [1, D] / [Tk, D] tiles
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    keep = mask_ref[:] != 0
-    o_ref[0] = _decode_tile(q, k, v, keep, scale, jnp, block_k)
-
-
-def _decode_call(q, k, v, mask2, scale, block_k: int):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    s, h, d = q.shape
-    tk = k.shape[2]
-    kern = functools.partial(_decode_kernel, scale=np.float32(scale),
-                             block_k=block_k)
-    return pl.pallas_call(
-        kern,
-        grid=(s, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
-    )(q, k, v, mask2)
+    if _takes_kernel(impl, "flash_attention",
+                     _fits_vmem(tq, tk, d, block_k), (tq, tk, d)):
+        return _flash_call(q, k, v, kv_mask, causal, s, block_k)
+    return flash_attention_reference(
+        q, k, v, _mask3(b, tq, tk, kv_mask, causal), s, block_k)
 
 
 def decode_attention(q, k, v, kv_mask=None, scale=None,
                      impl: str = "auto", block_k: int = DEFAULT_BLOCK_K):
-    """Single-token decode attention against cached K/V.
+    """Single-token decode attention against cached K/V — flash
+    attention at ``Tq=1``, through the same kernel.
 
-    ``q`` ``[S, H, D]`` (one query per slot), ``k``/``v`` ``[S, H, Tk, D]``
-    (the slot-major cache, one slot's layer-slice per row), ``kv_mask``
-    ``[S, Tk]`` bool (True = valid cached position; typically
-    ``arange(Tk) <= position``). Returns ``[S, H, D]`` float32;
-    fully-masked slots yield exact zeros. Same ``impl``/VMEM-fallback
-    discipline as :func:`flash_attention`."""
-    s_, h, d = q.shape
-    tk = k.shape[2]
-    sc = _resolve_scale(scale, d)
-    mask2 = decode_mask2(s_, tk, kv_mask)
-    if resolve_impl(impl) == "pallas" and _fits_vmem(1, tk, d, block_k):
-        return _decode_call(q, k, v, mask2, sc, block_k)
-    return decode_attention_reference(q, k, v, mask2, sc, block_k)
+    Autoregressive serving (serve/generate.py) holds a slot-major
+    KV-cache and issues ONE query row per slot per token step: ``q``
+    ``[S, H, D]``, ``k``/``v`` ``[S, H, Tk, D]`` (one slot's layer-slice
+    per row), ``kv_mask`` ``[S, Tk]`` bool (True = valid cached
+    position; typically ``arange(Tk) <= position``) standing in for the
+    causal constraint (the cache never holds a future position).
+    Returns ``[S, H, D]`` float32; a fully-masked slot (inactive,
+    length 0) yields EXACT zeros, which is what lets inactive slots
+    ride the fixed-shape decode program without polluting anything."""
+    return flash_attention(q[:, :, None, :], k, v, kv_mask=kv_mask,
+                           scale=scale, impl=impl, block_k=block_k)[:, :, 0]
 
 
 # ---- the ring-hop local block: one online update as a kernel ----
@@ -460,7 +393,7 @@ def _update_call(q4, k4, v4, mask3, m, denom, acc, scale):
         out_shape=(jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, tq, d), jnp.float32)),
-        interpret=jax.default_backend() != "tpu",
+        name="attention_block_update",
     )(q4, k4, v4, mask3, m, denom, acc)
 
 
@@ -474,12 +407,15 @@ def attention_block_update(q4, k4, v4, keep3, m, denom, acc, scale,
     f32. ``impl="xla"`` runs the shared body vmapped (exactly the
     historical inline update); ``impl="pallas"`` runs it as one fused
     kernel per (batch, head) tile — the score block never leaves VMEM.
+    The kernel takes the mask as int32 (one 32-bit tile layout for
+    every operand it compares or selects on).
     """
     s = np.float32(scale)
-    if resolve_impl(impl) == "pallas" \
-            and _fits_vmem(q4.shape[2], k4.shape[2], q4.shape[3],
-                           k4.shape[2]):
-        return _update_call(q4, k4, v4, keep3.astype(jnp.int8),
+    tq, tk, d = q4.shape[2], k4.shape[2], q4.shape[3]
+    if _takes_kernel(impl, "attention_block_update",
+                     _fits_vmem(tq, tk, d, tk, mask_bytes=4),
+                     (tq, tk, d)):
+        return _update_call(q4, k4, v4, keep3.astype(jnp.int32),
                             m, denom, acc, s)
 
     def upd(q2, k2, v2, keep2, m2, d2, a2):

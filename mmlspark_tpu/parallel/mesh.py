@@ -35,26 +35,6 @@ import numpy as np
 AXES = ("dp", "fsdp", "tp", "sp", "pp", "ep")
 
 
-def shard_map(f: Any, mesh: Any, in_specs: Any, out_specs: Any,
-              check_vma: bool | None = None) -> Any:
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it as ``jax.shard_map`` (with the replication check
-    named ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (named ``check_rep``). Every
-    in-repo shard_map call goes through this shim so the parallel paths run
-    on both."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:
     """Logical parallelism layout; -1 on ``dp`` means "all remaining"."""

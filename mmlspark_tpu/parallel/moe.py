@@ -195,8 +195,7 @@ def moe_apply(params: dict, x: Any, mesh, capacity_factor: float = 2.0,
         aux = E * jnp.sum(frac * mean_p)
         return y, aux[None]
 
-    from mmlspark_tpu.parallel.mesh import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(moe_in_specs(), P(token_axes), P(token_axes)),
         out_specs=(P(token_axes), P()),

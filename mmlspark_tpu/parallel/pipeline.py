@@ -166,12 +166,11 @@ def pipeline_apply(block_fn: Callable, stacked_params: Any, x: Any,
         return jax.lax.psum(out, "pp")
 
     data_axes = ("dp", "fsdp")
-    from mmlspark_tpu.parallel.mesh import shard_map
     # trace-computed layer stacks (the Trainer re-stacks block{i} params
     # at trace time) must be pinned replicated or the pp-unaware dp axis
     # corrupts them on entry — see commit_replicated
     stacked_params = commit_replicated(stacked_params, mesh)
-    out = shard_map(
+    out = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P("pp"), P(None, data_axes)),
         out_specs=P(None, data_axes),

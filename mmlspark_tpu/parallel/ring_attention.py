@@ -50,14 +50,20 @@ def _masked_softmax(scores, jnp):
 
 
 def _local_attention(q, k, v, scale, mask=None):
-    """Plain softmax attention on local blocks: [B, Lq, H, D] x [B, Lk, H, D]."""
+    """Plain softmax attention on local blocks: [B, Lq, H, D] x [B, Lk, H, D].
+
+    Scores accumulate and the softmax runs in float32 whatever the
+    operand dtype (bf16 operands are the MXU's native f32-accumulate
+    pass); the weights return to ``v``'s dtype for the weighted sum, so a
+    bf16 model's output stays bf16. A no-op for float32 operands."""
     import jax.numpy as jnp
 
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask, scores, -jnp.inf)
     w = _masked_softmax(scores, jnp)
-    return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+    return jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
 
 
 def attention_reference(q, k, v, causal: bool = False, kv_mask=None):
@@ -67,7 +73,10 @@ def attention_reference(q, k, v, causal: bool = False, kv_mask=None):
     """
     import jax.numpy as jnp
 
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    # a python float: weakly typed, so it never widens a bf16 model's
+    # activations (a numpy float64 scalar is strongly typed and promoted
+    # everything after the first attention layer to float32)
+    scale = float(1.0 / np.sqrt(q.shape[-1]))
     mask = None
     if causal:
         L = q.shape[1]
@@ -95,8 +104,7 @@ def _run_sharded(body, mesh, axis, batch_axis, q, k, v, kv_mask):
     b_axis = _resolve_batch_axis(mesh, batch_axis)
     spec = P(b_axis, axis, None, None)
     mask_spec = P(b_axis, axis)
-    from mmlspark_tpu.parallel.mesh import shard_map
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(spec, spec, spec, mask_spec),
                        out_specs=spec, check_vma=False)
     if kv_mask is None:

@@ -210,6 +210,12 @@ class ServeSupervisor:
     def start(self) -> "ServeSupervisor":
         if self._started:
             return self
+        if self.cfg.initial_backends > 1:
+            refusal = self._chip_refusal()
+            if refusal:
+                raise RuntimeError(
+                    f"initial_backends={self.cfg.initial_backends}: "
+                    + refusal)
         self._started = True
         for _ in range(self.cfg.initial_backends):
             self._spawn(self._alloc_bid(), generation=0, ledger=Ledger())
@@ -277,6 +283,27 @@ class ServeSupervisor:
         }
 
     # -- spawn/respawn (watch thread, or start() before it runs) --
+
+    def _chip_refusal(self) -> str | None:
+        """Why this host cannot run a second backend process, or None.
+
+        Backends are whole processes that each bring up the default JAX
+        backend. On a TPU host that is the TPU, a chip belongs to one
+        process at a time, and nothing here assigns chips to children —
+        a second backend would hang or die fighting the first for them.
+        Refuse loudly instead. (``JAX_PLATFORMS=cpu`` in the
+        supervisor's environment or ``extra_env`` is the explicit way to
+        rehearse a multi-backend fleet on such a host.) The supervisor
+        finds this out from device files, never by touching a backend
+        itself."""
+        from mmlspark_tpu.utils.env import children_reach_tpu
+        if not children_reach_tpu(self.cfg.extra_env):
+            return None
+        return ("this is a TPU host and every fleet backend process "
+                "would claim its chips; one process per chip — run one "
+                "backend per host (it can drive all the host's chips: "
+                "--mesh dp=N), or set JAX_PLATFORMS=cpu to rehearse on "
+                "virtual devices")
 
     def _alloc_bid(self) -> int:
         bid = self._next_bid
@@ -511,6 +538,13 @@ class ServeSupervisor:
             self._execute_scale_down(action.reason)
 
     def _execute_scale_up(self, reason: str) -> None:
+        refusal = self._chip_refusal() if self._live_count() else None
+        if refusal:
+            _log.error("fleet scale-up refused: %s", refusal)
+            self._journal.record("scale_up_refused",
+                                 {"reason": reason, "detail": refusal})
+            self._last_scale = time.monotonic()  # cooldown, no retry storm
+            return
         bid = self._alloc_bid()
         self._journal.record("scale_up", {
             "bid": bid, "reason": reason,

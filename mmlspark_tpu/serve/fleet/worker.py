@@ -261,6 +261,8 @@ def run_backend_worker(beacon_interval_s: float = 0.25) -> int:
     if info is None:
         raise SystemExit("not under a fleet supervisor "
                          "(MMLSPARK_TPU_SERVICE_DIR unset)")
+    from mmlspark_tpu.utils.jit_cache import place_compilation_cache
+    place_compilation_cache()
     os.makedirs(info.service_dir, exist_ok=True)
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_: stop.set())

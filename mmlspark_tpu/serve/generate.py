@@ -76,8 +76,13 @@ def build_prefill_step(model):
         L = tokens.shape[1]
         logits, (pk, pv) = model.apply(
             {"params": params}, tokens, mask=attn_mask, return_cache=True)
-        ck = bufs["k"].at[slot_ids, :, :, :L, :].set(pk)
-        cv = bufs["v"].at[slot_ids, :, :, :L, :].set(pv)
+        # the cache is allocated in the model's own K/V dtype; the cast
+        # is explicit so a mismatch can never ride an implicit scatter
+        # conversion (which jax is turning into an error)
+        ck = bufs["k"].at[slot_ids, :, :, :L, :].set(
+            pk.astype(bufs["k"].dtype))
+        cv = bufs["v"].at[slot_ids, :, :, :L, :].set(
+            pv.astype(bufs["v"].dtype))
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
         first = jnp.argmax(last, axis=-1).astype(jnp.int32)
@@ -303,8 +308,15 @@ class GenerateBatcher:
         heads = model.num_heads
         hd = model.embed_dim // model.num_heads
         shape = (S, layers, heads, cfg.t_max, hd)
+        # the cache holds what the model's attention layers produce: a
+        # bf16 model gets a bf16 cache (half the HBM), read off an
+        # abstract trace of the prefill forward — nothing executes
+        kv_dtype = jax.eval_shape(
+            lambda p, t: model.apply({"params": p}, t, return_cache=True),
+            params, jax.ShapeDtypeStruct((1, cfg.prefill_buckets[0]),
+                                         jnp.int32))[1][0].dtype
         self._state = plan.allocate_segment_state(
-            f"{name}.kv", {"k": shape, "v": shape})
+            f"{name}.kv", {"k": shape, "v": shape}, dtype=kv_dtype)
         # the engine IS the cache host: obs.runtime.compiled_programs
         # walks this object's _plan_cache, so the two stateful programs
         # below are the ONLY entries and the ladder budget is auditable
@@ -548,10 +560,15 @@ class GenerateBatcher:
         S = self.config.slots
         act = self._mask.copy()
         refs = [self._slots.owner(s) for s in range(S)]
+        # the dispatch is asynchronous and the host→device transfer may
+        # read (on the CPU backend: alias) the numpy buffers it is
+        # handed after this call returns — the mirrors are mutated on
+        # the very next lines, so the program gets private copies
         out = self._decode.dispatch(
-            self._params, self._carry, jnp.asarray(self._inject_tok),
-            jnp.asarray(self._inject), jnp.asarray(self._positions),
-            jnp.asarray(act))
+            self._params, self._carry,
+            jnp.asarray(self._inject_tok.copy()),
+            jnp.asarray(self._inject.copy()),
+            jnp.asarray(self._positions.copy()), jnp.asarray(act))
         self._carry = out
         self._inject[:] = False
         n_active = int(act.sum())
@@ -639,9 +656,9 @@ class GenerateBatcher:
                    else int(max_new_tokens))
         S = cfg.slots
         bucket = cfg.prefill_bucket_for(len(prompt), self.name)
-        shape = self._state.buffers["k"].shape
-        bufs = {"k": jnp.zeros(shape, jnp.float32),
-                "v": jnp.zeros(shape, jnp.float32)}
+        live = self._state.buffers["k"]
+        bufs = {"k": jnp.zeros(live.shape, live.dtype),
+                "v": jnp.zeros(live.shape, live.dtype)}
         P = cfg.prefill_rows
         toks = np.zeros((P, bucket), np.int32)
         am = np.zeros((P, bucket), bool)
@@ -667,9 +684,11 @@ class GenerateBatcher:
         positions[0] = n
         active[0] = True
         for _ in range(max_new - 1):
+            # copies for the two arrays mutated right after the async
+            # dispatch (same aliasing hazard as advance_decode)
             bufs, carry = self._decode.jitted(
                 bufs, self._params, carry, jnp.asarray(inject_tok),
-                jnp.asarray(inject), jnp.asarray(positions),
+                jnp.asarray(inject.copy()), jnp.asarray(positions.copy()),
                 jnp.asarray(active))
             inject[0] = False
             positions[0] += 1

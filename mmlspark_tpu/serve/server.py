@@ -520,6 +520,8 @@ class ModelServer:
         from mmlspark_tpu.analysis.spmd import audit_plan_spmd
         from mmlspark_tpu.serve.mesh import MODEL_PARALLEL_AXES
 
+        import jax
+
         expect_axes = (tuple(a for a in MODEL_PARALLEL_AXES)
                        if mesh_spec.model_parallel else None)
         try:
@@ -527,9 +529,13 @@ class ModelServer:
                                     mesh=replicas.replicas[0].mesh,
                                     expect_axes=expect_axes,
                                     precision=policy)
-        except Exception as e:  # abstract trace failed: not a verdict
-            _log.info("serve[%s]: sharded SPMD audit skipped (%s)",
-                      name, e)
+        except jax.errors.JAXTypeError as e:
+            # a stage body that needs concrete values cannot be traced
+            # over ShapeDtypeStructs: not a verdict. Anything else (a
+            # verifier bug, a changed jaxpr layout) must fail the load —
+            # a safety check that skips itself on error fails open
+            _log.warning("serve[%s]: sharded SPMD audit skipped, stage "
+                         "not abstractly traceable (%s)", name, e)
             return
         if not audit.ok:
             raise ModelLoadError(name, message=(

@@ -298,7 +298,7 @@ def build(repo_dir: str, scale: str = "small") -> list:
         publish(b, "synthetic-standin", "ResNet", 50)
         print("ResNet50_Infer (full size, folded inference variant)")
         # the featurization-serving form: frozen-BN folded + bf16 + s2d
-        # stem (models/resnet.py; 0.64 MFU vs 0.39 unfolded, PERF_NOTES)
+        # stem (models/resnet.py)
         b = get_model("ResNet50_Infer", num_classes=10, input_size=224)
         publish(b, "synthetic-standin", "ResNet-folded", 50)
         print("ViT_B16 (full size, stand-in weights)")

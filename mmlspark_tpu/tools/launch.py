@@ -90,6 +90,17 @@ def launch_local(cmd: Sequence[str], num_processes: int,
     check spans the whole worker set. When the coordinator port was
     auto-picked, a coordinator bind failure retries the whole launch on a
     fresh port (advisor round 4: the free-port probe is racy)."""
+    if num_processes > 1 and not cpu_devices:
+        from mmlspark_tpu.utils.env import children_reach_tpu
+        if children_reach_tpu(extra_env):
+            # one process per chip, and nothing here assigns chips to
+            # ranks: N local workers would fight over the same devices
+            raise ValueError(
+                f"launch: {num_processes} local worker processes on a "
+                "TPU host would all claim its chips (a chip belongs to "
+                "one process) — pass cpu_devices/--cpu-devices N to "
+                "rehearse on virtual CPU devices, or run one process "
+                "that drives all local chips")
     auto_port = coordinator is None
     attempts = max(1, port_retries) if auto_port else 1
     for attempt in range(attempts):
@@ -233,8 +244,12 @@ def main(argv: Sequence[str] | None = None) -> int:
         return launch_pod(cmd, args.coordinator, args.num_processes)
     if not args.num_processes or args.num_processes < 1:
         ap.error("--num-processes is required in local mode")
-    return launch_local(cmd, args.num_processes, args.coordinator,
-                        args.cpu_devices, args.grace_seconds)
+    try:
+        return launch_local(cmd, args.num_processes, args.coordinator,
+                            args.cpu_devices, args.grace_seconds)
+    except ValueError as e:  # a request this host cannot honour
+        print(str(e), file=sys.stderr)
+        return 2
 
 
 if __name__ == "__main__":

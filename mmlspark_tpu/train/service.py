@@ -570,6 +570,7 @@ class TrainSupervisor:
 
     def __init__(self, cfg: ServiceConfig):
         self.cfg = cfg
+        self._refuse_unrunnable_on_tpu_host()
         os.makedirs(cfg.service_dir, exist_ok=True)
         self._decisions_path = os.path.join(cfg.service_dir,
                                             "decisions.jsonl")
@@ -594,6 +595,37 @@ class TrainSupervisor:
                 cfg.publish, cfg.service_dir,
                 run_id=f"train-{os.getpid()}-{int(time.time())}",
                 train_journal=self._decisions_path)
+
+    def _refuse_unrunnable_on_tpu_host(self) -> None:
+        """A ladder rung this supervisor cannot honour on real chips is
+        refused at construction, loudly, never degraded: all workers
+        start on THIS host and each brings up the default JAX backend,
+        so on a TPU host ``world > 1`` means processes fighting over the
+        same chips (one process per chip, and nothing here assigns
+        them), and ``devices=N`` means N *virtual CPU* devices — a job
+        that asked for four devices next to four chips would train on
+        the CPU without a word. ``JAX_PLATFORMS=cpu`` in the
+        supervisor's environment (or ``extra_env``) is the explicit way
+        to rehearse such a ladder on a TPU host. Decided from device
+        files — the supervisor itself never touches a backend."""
+        from mmlspark_tpu.utils.env import children_reach_tpu
+        if not children_reach_tpu(self.cfg.extra_env):
+            return
+        for topo in self.cfg.topologies:
+            if topo.devices is not None:
+                raise ValueError(
+                    f"Topology(world={topo.world}, devices={topo.devices})"
+                    " on a TPU host: `devices` grants virtual CPU "
+                    "devices, not chips — drop it to train on the "
+                    "host's chips (one worker drives them all), or set "
+                    "JAX_PLATFORMS=cpu to rehearse on virtual devices")
+            if topo.world > 1:
+                raise ValueError(
+                    f"Topology(world={topo.world}) on a TPU host: every "
+                    "worker process would claim the host's chips; one "
+                    "process per chip — use world=1 (one worker drives "
+                    "all local chips), or set JAX_PLATFORMS=cpu to "
+                    "rehearse on virtual devices")
 
     # -- observability of the supervisor itself --
 
@@ -1090,6 +1122,9 @@ def run_selftest_worker() -> int:
             raise SystemExit("not under a train service supervisor "
                              f"({ENV_DIR} unset)")
         import jax
+
+        from mmlspark_tpu.utils.jit_cache import place_compilation_cache
+        place_compilation_cache()
         # pin the platform only when the supervisor granted virtual
         # devices (Topology.devices set ⇒ JAX_PLATFORMS=cpu in our env);
         # a devices=None rung inherits the environment — real

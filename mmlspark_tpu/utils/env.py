@@ -47,6 +47,33 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def tpu_chips_on_host() -> list[str]:
+    """Device files of this host's TPU chips, found WITHOUT initialising
+    a JAX backend — a supervisor that touched JAX would itself hold the
+    chips its workers need (a chip belongs to one process at a time).
+    ``/dev/accel*`` is the PCI driver's naming, ``/dev/vfio/<n>`` the
+    VFIO one (v5e); empty on a host with no TPU."""
+    import glob
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def children_reach_tpu(extra_env: Any = None) -> bool:
+    """Would a child process started from here (this process's
+    environment overlaid with ``extra_env``, the way every launcher in
+    the repo builds a worker's environment) bring up the TPU backend?
+    True when the host has TPU chips and the child's ``JAX_PLATFORMS``
+    is not pinned to something else (``cpu`` — the explicit way to
+    rehearse on virtual devices on a TPU host)."""
+    import os
+    pinned = (extra_env or {}).get("JAX_PLATFORMS",
+                                   os.environ.get("JAX_PLATFORMS", ""))
+    platforms = [p.strip() for p in pinned.lower().split(",") if p.strip()]
+    if platforms and "tpu" not in platforms:
+        return False
+    return bool(tpu_chips_on_host())
+
+
 def default_matmul_dtype():
     """bfloat16 on TPU (MXU-native), float32 elsewhere."""
     import jax.numpy as jnp

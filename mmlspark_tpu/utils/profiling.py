@@ -12,7 +12,7 @@ compiled program, so these helpers expose the JAX/XLA profiler:
             model.transform(table)
 
 Traces capture per-op device timelines (MXU occupancy, HBM stalls, ICI
-collectives) — the data behind every PERF_NOTES round.
+collectives) — only the process that holds the chip can trace it.
 """
 
 from __future__ import annotations

@@ -21,8 +21,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def pin_platform() -> None:
-    """The env var alone is not enough where an experimental TPU platform
-    plugin is installed — pin the platform through the config too."""
+    """Pin the platform through the config as well as the env var, so a
+    jax that something imported before the var was set still obeys it."""
     import jax
     jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 

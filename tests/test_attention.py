@@ -6,7 +6,8 @@ comparisons drift via FMA contraction — repo convention), the numpy
 oracle is pinned against the jitted reference, fully-masked rows are
 exact zeros, and the ring/ulysses sequence-parallel paths keep their
 reference parity with ``impl="pallas"`` (the local block as a kernel).
-Runs in interpreter mode on the CPU backend — the kernel body itself
+Runs through jax's Pallas interpreter on the CPU backend, asked for
+explicitly (the ``pallas_interpret`` fixture) — the kernel body itself
 executes, not a shadow path."""
 
 import numpy as np
@@ -17,6 +18,8 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.ops.pallas import attention as fa
 from mmlspark_tpu.parallel.ring_attention import attention_reference
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 
 def bhtd(B=2, H=3, T=48, D=16, seed=0):
@@ -84,11 +87,11 @@ class TestKernelUlpPins:
 
 
 class TestDecodeAttention:
-    """The KV-cache decode variant (round 18): one query row per slot
-    against the slot-major cache. Same kernel discipline — pallas ≤ 1
-    ULP vs the jitted XLA reference, the numpy oracle pinned against the
-    jitted reference, fully-masked slots exact zeros — plus the semantic
-    anchor: a decode step IS flash attention at ``Tq=1``."""
+    """The KV-cache decode variant: one query row per slot against the
+    slot-major cache — flash attention at ``Tq=1`` through the same
+    kernel. Pallas within a stated tolerance of the jitted XLA
+    reference, the numpy oracle pinned against the jitted reference,
+    fully-masked slots exact zeros."""
 
     def shkd(self, S=4, H=2, Tk=32, D=8, seed=11):
         r = np.random.default_rng(seed)
@@ -99,7 +102,13 @@ class TestDecodeAttention:
                            <= np.asarray([5, 31, 0, 17])[:, None])
         return q, k, v, mask
 
-    def test_kernel_matches_reference_under_jit_one_ulp(self):
+    def test_kernel_matches_reference_under_jit(self):
+        # a tolerance, not a ULP pin: the kernel contracts the one query
+        # row as an 8-row MXU tile (Tq padded to the sublane tile) while
+        # the vmapped reference contracts an M=1 dot_general, and XLA
+        # accumulates the two in different orders — D-term f32 sums
+        # differ by ~D·eps relative (measured 109 ULP at D=8). Same
+        # bound the recurrence-vs-plain-softmax pins above use
         q, k, v, mask = self.shkd()
 
         def run(impl):
@@ -107,18 +116,18 @@ class TestDecodeAttention:
                 a, b, c, kv_mask=mask, impl=impl, block_k=16))
             return np.asarray(fn(q, k, v))
 
-        np.testing.assert_array_max_ulp(run("xla"), run("pallas"),
-                                        maxulp=1)
+        np.testing.assert_allclose(run("pallas"), run("xla"),
+                                   rtol=2e-5, atol=2e-6)
 
     def test_numpy_oracle_pinned_against_jitted_reference(self):
         q, k, v, mask = self.shkd(seed=12)
         ref = np.asarray(jax.jit(
             lambda a, b, c: fa.decode_attention(
                 a, b, c, kv_mask=mask, impl="xla", block_k=16))(q, k, v))
-        m2 = fa.host_decode_mask2(4, 32, np.asarray(mask))
-        host = fa.decode_attention_host(
-            np.asarray(q), np.asarray(k), np.asarray(v), m2,
-            fa._resolve_scale(None, 8), block_k=16)
+        m3 = fa.host_mask3(4, 1, 32, np.asarray(mask), False)
+        host = fa.flash_attention_host(
+            np.asarray(q)[:, :, None, :], np.asarray(k), np.asarray(v),
+            m3, fa._resolve_scale(None, 8), block_k=16)[:, :, 0]
         np.testing.assert_allclose(host, ref, rtol=1e-5, atol=1e-6)
 
     def test_decode_is_flash_attention_at_tq_one(self):
@@ -192,12 +201,16 @@ class TestSequenceParallelImpls:
     must hold either way (small shapes here; the long-context pins ride
     the slow suite below)."""
 
-    def test_ring_parity_pallas(self, sp_mesh):
+    def test_ring_parity_pallas(self):
         # the xla path is covered transitively: attention_block_update's
         # xla/pallas agreement is pinned bitwise above, and the slow
-        # suite (test_sequence_parallel) runs ring's default path — one
-        # sp=8 shard_map compile here is the tier-1 budget's worth
+        # suite (test_sequence_parallel) runs ring's default path. A
+        # 4-hop ring (sp=4 on a prefix of the 8 devices): every hop is an
+        # interpreted kernel call per device, and an 8-hop ring costs
+        # twice the tier-1 seconds for the same coverage
+        from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh
         from mmlspark_tpu.parallel.ring_attention import ring_attention
+        sp_mesh = make_mesh(MeshSpec(dp=1, sp=4))
         r = np.random.default_rng(7)
         B, L, H, D = 1, 16, 2, 8
         q, k, v = (jnp.asarray(r.normal(size=(B, L, H, D)), jnp.float32)
@@ -256,8 +269,8 @@ class TestLongContextPallas:
 class TestViTFlashWiring:
     """The serving-path attention of models/vit.py: same param tree as
     the einsum path (checkpoints interchangeable), flash_xla and
-    flash_pallas bit-identical under jit, outputs close to the bhtd
-    baseline."""
+    flash_pallas within f32 rounding of each other under jit, outputs
+    close to the bhtd baseline."""
 
     def test_flash_variants_share_params_and_agree(self):
         from mmlspark_tpu.models.vit import vit_tiny
@@ -276,8 +289,12 @@ class TestViTFlashWiring:
                 lambda xx, m=m: m.apply({"params": params}, xx))(x))
             np.testing.assert_allclose(outs[ai], base, rtol=1e-4,
                                        atol=1e-5)
-        np.testing.assert_array_equal(outs["flash_xla"],
-                                      outs["flash_pallas"])
+        # not bit-equal: the kernel pads T=5 to whole tiles (8 query
+        # rows, one 128-key stripe), so its reductions run over a
+        # different tree than the reference's — last-digit f32 drift
+        np.testing.assert_allclose(outs["flash_xla"],
+                                   outs["flash_pallas"],
+                                   rtol=1e-5, atol=1e-6)
 
     def test_unknown_flash_impl_raises(self):
         from mmlspark_tpu.models.vit import vit_tiny

@@ -41,8 +41,12 @@ def _args():
 
 
 def _cached(tmp_path, fp=FP):
+    import jax
+
     cache = cc.CompileCache(str(tmp_path / "cache"))
-    return cc.CachedJit(_jitted(), fp, cache), cache
+    # an uncommitted jit runs on the default device; the 8-device test
+    # mesh makes "load onto the program's own devices" observable
+    return cc.CachedJit(_jitted(), fp, cache, jax.devices()[:1]), cache
 
 
 def _bundle():

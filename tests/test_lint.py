@@ -63,7 +63,7 @@ def step2(x, k):
 def fit(batches):
     for b in batches:
         f = jax.jit(lambda p, v: v + b)                   # JX102
-    g = jax.shard_map(step, None, None, None)             # JX103
+    g = jax.experimental.shard_map.shard_map(step, None)  # JX103
     h = getattr(jax, "shard_map")                         # JX103
     return f, g, h
 
@@ -120,14 +120,15 @@ def test_fixture_yields_exactly_the_seeded_findings():
     assert got == want, (got, want)
 
 
-def test_shim_surface_is_not_flagged():
-    # calling THROUGH the compat shim is what JX103 tells you to do; the
-    # rule must only fire on jax-rooted spellings
-    src = ("from mmlspark_tpu.parallel import mesh as mesh_lib\n"
+def test_direct_shard_map_is_not_flagged():
+    # jax.shard_map is the call the tree makes (no version shim); the
+    # rule only fires on the legacy experimental spelling
+    src = ("import jax\n"
            "def f(body, m, i, o):\n"
-           "    return mesh_lib.shard_map(body, m, i, o)\n")
+           "    return jax.shard_map(body, mesh=m, in_specs=i,\n"
+           "                         out_specs=o, check_vma=False)\n")
     assert lint_source(src, "x.py") == []
-    src2 = "import jax\ng = jax.shard_map(None, None, None, None)\n"
+    src2 = "from jax.experimental.shard_map import shard_map\n"
     assert [f.rule for f in lint_source(src2, "x.py")] == ["JX103"]
 
 

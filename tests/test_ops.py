@@ -1,9 +1,10 @@
 """Pallas device kernels: fused GroupNorm (ops/group_norm.py).
 
-Runs in interpreter mode on the CPU backend (the kernel itself executes,
-not a shadow implementation), checking numerical equivalence against the
-jnp reference, the custom-vjp gradient path, the VMEM-fit fallback gate,
-and checkpoint-compatible wiring into ResNet."""
+Runs through jax's Pallas interpreter on the CPU backend, asked for
+explicitly (the ``pallas_interpret`` fixture — the kernel itself
+executes, not a shadow implementation), checking numerical equivalence
+against the jnp reference, the custom-vjp gradient path, the VMEM-fit
+fallback gate, and checkpoint-compatible wiring into ResNet."""
 
 import numpy as np
 import pytest
@@ -13,6 +14,8 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.ops import group_norm, group_norm_reference
 from mmlspark_tpu.ops.group_norm import _fits_vmem
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 
 class TestKernelEquivalence:
@@ -121,14 +124,30 @@ class TestVmemGate:
         assert _fits_vmem(56, 56, 256, 2)      # biggest mid-stage block
         assert _fits_vmem(28, 28, 512, 2)
 
-    def test_fallback_still_correct(self):
-        # a shape routed to the reference path must match it exactly
+    def test_fallback_still_correct_and_is_counted(self):
+        # a shape routed to the reference path must match it exactly —
+        # and the switch must be visible, not quiet
+        from mmlspark_tpu.obs.metrics import registry
+        from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
+        counter = registry().counter(FALLBACK_COUNTER, kernel="group_norm")
+        before = counter.value
         r = np.random.default_rng(3)
         x = jnp.asarray(r.normal(size=(1, 112, 112, 64)).astype(np.float32))
         s, b = jnp.ones(64), jnp.zeros(64)
         np.testing.assert_allclose(
             np.asarray(group_norm(x, s, b, 8)),
             np.asarray(group_norm_reference(x, s, b, 8)), rtol=1e-6)
+        assert counter.value == before + 1
+
+    def test_no_interpreter_off_tpu_refuses_loudly(self):
+        # the wrapper never picks interpret mode from the backend: off
+        # the chip, without the explicit request, the kernel refuses
+        from jax.experimental.pallas import tpu as pltpu
+        x = jnp.ones((1, 8, 8, 32))
+        s, b = jnp.ones(32), jnp.zeros(32)
+        with pltpu.force_tpu_interpret_mode(None):
+            with pytest.raises(ValueError, match="interpret mode"):
+                group_norm(x, s, b, 8)
 
 
 class TestResNetWiring:

@@ -1018,15 +1018,13 @@ class CollectiveLeak(Transformer, DeviceStage, HasInputCol, HasOutputCol):
     def device_fn_mesh(self, meta, mesh):
         from jax.sharding import PartitionSpec as P
 
-        from mmlspark_tpu.parallel.mesh import shard_map
-
         def fwd(params, x):
             import jax
 
             def body(v):
                 return jax.lax.psum(v, "pp")
 
-            return shard_map(body, mesh=mesh, in_specs=(P(),),
+            return jax.shard_map(body, mesh=mesh, in_specs=(P(),),
                              out_specs=P(), check_vma=False)(
                                  x.astype(np.float32))
 

@@ -35,7 +35,9 @@ from mmlspark_tpu.analysis.spmd import (  # noqa: E402
     ENTRY_POINTS, ShardState, audit_plan_spmd, check_divisibility,
     verify_entry_point, verify_function,
 )
-from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh, shard_map  # noqa: E402
+from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh  # noqa: E402
+
+shard_map = jax.shard_map
 
 from lint_jax import lint_source  # noqa: E402
 
@@ -484,19 +486,17 @@ def body(v):
 FIXTURE_JX203 = '''
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from mmlspark_tpu.parallel.mesh import shard_map
 
 def apply(params, x, mesh):
     def body(p, xl):
         return (xl @ p).sum(0, keepdims=True)
-    return shard_map(body, mesh=mesh, in_specs=(P("pp"), P(None, ("dp",))),
+    return jax.shard_map(body, mesh=mesh, in_specs=(P("pp"), P(None, ("dp",))),
                      out_specs=P(None, ("dp",)), check_vma=False)(params, x)
 '''
 
 FIXTURE_JX204 = '''
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from mmlspark_tpu.parallel.mesh import shard_map
 
 def dispatch(params, x, mesh):
     def body(p, xl):
@@ -505,7 +505,7 @@ def dispatch(params, x, mesh):
         slots = jnp.einsum("ne,nd->ed", onehot.astype(jnp.float32), xl)
         slots = jax.lax.all_to_all(slots.reshape(4, 2, -1), "ep", 0, 0)
         return slots.reshape(xl.shape[0], -1) + pos.sum()
-    return shard_map(body, mesh=mesh, in_specs=(P(), P(("ep",))),
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), P(("ep",))),
                      out_specs=P(("ep",)), check_vma=False)(params, x)
 '''
 

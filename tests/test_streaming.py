@@ -122,7 +122,7 @@ class TestStreamReaders:
 
 class TestStreamTraining:
     def test_convnet_trains_from_chunked_stream(self, image_dir):
-        """The VERDICT item: train the CIFAR ConvNet from a chunked stream
+        """The review item: train the CIFAR ConvNet from a chunked stream
         without ever materializing the dataset."""
         def source():
             for chunk in stream_images(image_dir, chunk_rows=16):

@@ -1,11 +1,10 @@
-"""On-device train preprocessing (train/preprocess.py + ops/pallas).
+"""On-device train preprocessing (train/preprocess.py + ops/resize.py).
 
 Four contract families:
 
-* **Kernel parity** — the Pallas fused crop→resize→normalize kernel is
-  ≤ 1 ULP from its pure-XLA reference (bit-identical under jit), runs in
-  interpreter mode on this CPU backend (the kernel body executes, not a
-  shadow path), and the numpy host oracle tracks both to FMA tolerance.
+* **Geometry parity** — the fused crop→resize→normalize pass (pure XLA)
+  agrees with itself jitted and eager, and the numpy host oracle tracks
+  it to FMA tolerance.
 * **Spec semantics** — validation, static geometry replay (the
   analyzer's ``infer_schema`` face), deterministic per-step PRNG folds.
 * **End-to-end wire-form parity** — thin uint8 batches vs
@@ -22,8 +21,8 @@ import pytest
 import jax
 
 from mmlspark_tpu.models.zoo import ConvNetCifar
-from mmlspark_tpu.ops.pallas.resize import (
-    fused_resize_norm, fused_resize_norm_host, fused_resize_norm_reference,
+from mmlspark_tpu.ops.resize import (
+    fused_resize_norm, fused_resize_norm_host,
 )
 from mmlspark_tpu.train import (
     DevicePreprocess, TrainConfig, Trainer, envelope_batch, host_preprocess,
@@ -36,7 +35,7 @@ def _images(n=6, h=24, w=20, c=3, seed=0):
     return r.integers(0, 256, (n, h, w, c)).astype(np.uint8)
 
 
-class TestFusedKernel:
+class TestFusedGeometry:
     CROP, OUT = (20, 16), (8, 8)
 
     def _offsets(self, n, seed=1):
@@ -44,37 +43,27 @@ class TestFusedKernel:
         return (r.integers(0, 5, n).astype(np.int32),
                 r.integers(0, 5, n).astype(np.int32))
 
-    def _run(self, impl, x, oy, ox, jit=True):
+    def _run(self, x, oy, ox, jit=True):
         fn = lambda a, b, c: fused_resize_norm(  # noqa: E731
-            a, b, c, self.CROP, self.OUT, 1 / 255.0, impl=impl)
+            a, b, c, self.CROP, self.OUT, 1 / 255.0)
         if jit:
             fn = jax.jit(fn)
         return np.asarray(fn(x, oy, ox))
 
-    def test_pallas_within_1_ulp_of_reference(self):
-        # the acceptance pin, in the context the train step uses (the
-        # ops trace into one jitted program): <= 1 ULP — in fact XLA
-        # lowers both to the identical arithmetic, so bit-equal too
-        x = _images()
-        oy, ox = self._offsets(len(x))
-        ref = self._run("xla", x, oy, ox)
-        ker = self._run("pallas", x, oy, ox)
-        np.testing.assert_array_max_ulp(ref, ker, maxulp=1)
-        np.testing.assert_array_equal(ref, ker)
-
     def test_eager_drift_bounded_by_fma_contraction(self):
-        # un-jitted, the vmapped reference gets FMA-contracted
-        # differently than the interpreted kernel: 2 ULP bound
+        # un-jitted, the vmapped blend gets FMA-contracted differently
+        # than inside one jitted program (the context the train step
+        # uses): 2 ULP bound
         x = _images()
         oy, ox = self._offsets(len(x))
         np.testing.assert_array_max_ulp(
-            self._run("xla", x, oy, ox, jit=False),
-            self._run("pallas", x, oy, ox, jit=False), maxulp=2)
+            self._run(x, oy, ox), self._run(x, oy, ox, jit=False),
+            maxulp=2)
 
     def test_host_oracle_tracks_to_fma_tolerance(self):
         x = _images()
         oy, ox = self._offsets(len(x))
-        ref = np.asarray(fused_resize_norm_reference(
+        ref = np.asarray(fused_resize_norm(
             x, oy, ox, self.CROP, self.OUT, 1 / 255.0))
         host = fused_resize_norm_host(x, oy, ox, self.CROP, self.OUT,
                                       1 / 255.0)
@@ -86,30 +75,13 @@ class TestFusedKernel:
         x = _images(4, 8, 8)
         z = np.zeros(4, np.int32)
         out = np.asarray(fused_resize_norm(
-            x, z, z, (8, 8), (8, 8), 1 / 255.0, impl="xla"))
+            x, z, z, (8, 8), (8, 8), 1 / 255.0))
         np.testing.assert_array_equal(
             out, x.astype(np.float32) * np.float32(1 / 255.0))
-
-    def test_vmem_overflow_falls_back_to_reference(self):
-        from mmlspark_tpu.ops.pallas.resize import _fits_vmem
-        assert not _fits_vmem(4096, 4096, 224, 224, 3)
-        assert _fits_vmem(96, 96, 32, 32, 3)  # the CIFAR-scale case
-        # a forced-pallas call on an oversized block still computes (the
-        # reference path), and matches the explicit reference exactly
-        assert not _fits_vmem(512, 512, 32, 32, 3)
-        big = _images(1, 512, 512)
-        z = np.zeros(1, np.int32)
-        a = np.asarray(fused_resize_norm(big, z, z, (512, 512), (32, 32),
-                                         1.0, impl="pallas"))
-        b = np.asarray(fused_resize_norm(big, z, z, (512, 512), (32, 32),
-                                         1.0, impl="xla"))
-        np.testing.assert_array_equal(a, b)
 
     def test_bad_inputs_raise(self):
         x = _images(2, 8, 8)
         z = np.zeros(2, np.int32)
-        with pytest.raises(ValueError, match="unknown fused_resize_norm"):
-            fused_resize_norm(x, z, z, (8, 8), (4, 4), 1.0, impl="cuda")
         with pytest.raises(ValueError, match="larger than the source"):
             fused_resize_norm(x, z, z, (16, 8), (4, 4), 1.0)
 
@@ -125,8 +97,6 @@ class TestDevicePreprocessSpec:
             DevicePreprocess.parse("resize=32")
 
     def test_validation_rejects_bad_fields(self):
-        with pytest.raises(ValueError, match="impl"):
-            DevicePreprocess(impl="tpu")
         with pytest.raises(ValueError, match="resize"):
             DevicePreprocess(resize=(0, 32))
         with pytest.raises(ValueError, match="contrast"):
@@ -185,7 +155,7 @@ class TestDevicePreprocessSpec:
         host = host_preprocess(spec, x, 1 / 255.0)
         z = np.zeros(5, np.int32)
         dev = np.asarray(fused_resize_norm(
-            x, z, z, (40, 36), (16, 12), 1 / 255.0, impl="xla"))
+            x, z, z, (40, 36), (16, 12), 1 / 255.0))
         np.testing.assert_array_max_ulp(host, dev, maxulp=2)
         with pytest.raises(ValueError, match="src_crop"):
             host_preprocess(DevicePreprocess(src_crop=(8, 8)), x, 1.0)
@@ -222,7 +192,7 @@ class TestEnvelopeBatch:
         # the shared-constants contract: every weight array is f32, so
         # the numpy oracle blends in the same precision the device
         # paths canonicalize to
-        from mmlspark_tpu.ops.pallas.resize import _grids
+        from mmlspark_tpu.ops.resize import _grids
         for g in _grids(20, 16, 8, 8)[4:]:
             assert g.dtype == np.float32
 

@@ -10,11 +10,12 @@ jax initialization) catching the mistakes that cost the most on TPU:
 * **JX102 jit in loop** — ``jax.jit(...)`` constructed inside a for/while
   body: every iteration builds a fresh callable with an empty compile
   cache (the classic accidental-recompile).
-* **JX103 raw shard_map** — importing/calling ``jax.shard_map`` or
-  ``jax.experimental.shard_map`` directly instead of the
-  ``mmlspark_tpu/parallel/mesh.py`` compat shim (the shim papers over the
-  check_rep/check_vma rename across jax versions; direct use breaks one
-  side or the other).
+* **JX103 legacy shard_map** — importing ``jax.experimental.shard_map``
+  (the pre-``jax.shard_map`` spelling, with the replication check named
+  ``check_rep``) or probing for the entry point with
+  ``getattr(jax, "shard_map")``. The tree is written for the installed
+  jax (``pyproject.toml`` pins it): ``jax.shard_map(..., check_vma=...)``
+  directly, no version shim.
 * **JX104 mutable Param default** — ``Param(default=[])`` / ``{}`` /
   ``set()``: the default is shared across every stage instance.
 * **JX105 blocking scalar fetch in a step loop** — ``float()``/``int()``/
@@ -118,7 +119,7 @@ docs/static_analysis.md:
   unjustified one is itself a finding (**JX300**);
 * the curated :data:`DEFAULT_ALLOWLIST` below (file-suffix → rules,
   with a per-entry justification), for files whose whole purpose is the
-  exception (the shard_map shim itself).
+  exception (currently none).
 
 Usage::
 
@@ -144,19 +145,13 @@ import sys
 # files whose entire purpose is the exception; suffix-matched against the
 # normalized path, each rule carrying its justification so the gate
 # stays reviewable in one place.
-DEFAULT_ALLOWLIST: dict[str, dict] = {
-    # the compat shim itself: it must touch both jax.shard_map spellings
-    "mmlspark_tpu/parallel/mesh.py": {
-        "JX103": "the compat shim is the one module that must spell "
-                 "jax.shard_map directly (both sides of the "
-                 "check_rep/check_vma rename)"},
-}
+DEFAULT_ALLOWLIST: dict[str, dict] = {}
 
 RULES = {
     "JX101": "host sync inside a jit-compiled function",
     "JX102": "jax.jit constructed inside a loop body",
-    "JX103": "shard_map used directly; route through parallel/mesh.py's "
-             "compat shim",
+    "JX103": "legacy jax.experimental.shard_map / version-probing "
+             "getattr; call jax.shard_map(..., check_vma=...) directly",
     "JX104": "mutable default value in a Param declaration",
     "JX105": "blocking scalar fetch on a step output inside the step loop; "
              "record the device scalar and resolve it one step later",
@@ -677,15 +672,12 @@ class _Linter(ast.NodeVisitor):
                        "callable (and compile cache) every iteration; "
                        "hoist it out of the loop")
         func = node.func
-        # jax.shard_map(...) / jax.experimental.shard_map.shard_map(...) —
-        # but NOT the shim's own surface (mesh.shard_map / mesh_lib.
-        # shard_map), which is exactly what the rule tells you to call
-        if isinstance(func, ast.Attribute) and func.attr == "shard_map":
-            root = func.value
-            while isinstance(root, ast.Attribute):
-                root = root.value
-            if isinstance(root, ast.Name) and root.id == "jax":
-                self._emit(node, "JX103", RULES["JX103"])
+        # jax.experimental.shard_map.shard_map(...) — the legacy dotted
+        # spelling; jax.shard_map(...) itself is the call to make
+        if (isinstance(func, ast.Attribute) and func.attr == "shard_map"
+                and isinstance(func.value, ast.Attribute)
+                and func.value.attr == "shard_map"):
+            self._emit(node, "JX103", RULES["JX103"])
         # getattr(jax, "shard_map")
         if (isinstance(func, ast.Name) and func.id == "getattr"
                 and len(node.args) >= 2

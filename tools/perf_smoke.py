@@ -23,10 +23,8 @@ flake on a loaded CI box):
   deterministic counts, not wall clock — with loss histories equal to
   ≤ 1e-5 across the two wire forms (the stochastic draws fold from the
   global step, so both runs augment identically), exactly ONE compiled
-  step program per input shape, a bit-reproducible resume from a
-  mid-epoch checkpoint (the PRNG-fold correctness observable), and the
-  Pallas fused-geometry kernel pinned ≤ 1 ULP equal to its pure-XLA
-  reference in CPU interpret mode.
+  step program per input shape, and a bit-reproducible resume from a
+  mid-epoch checkpoint (the PRNG-fold correctness observable).
 * **train elastic recovery** — a supervised worker hard-killed mid-run
   (preemption exit code) must be detected by the training service
   supervisor, re-scaled onto the surviving topology (8 → 4 virtual
@@ -263,7 +261,6 @@ def check_train_device_preprocess(min_reduction: float = 4.0) -> dict:
     from mmlspark_tpu import obs
     from mmlspark_tpu.models.zoo import ConvNetCifar
     from mmlspark_tpu.obs import runtime as obs_rt
-    from mmlspark_tpu.ops.pallas.resize import fused_resize_norm
     from mmlspark_tpu.train.loop import TrainConfig, Trainer
     from mmlspark_tpu.train.preprocess import (
         DevicePreprocess, host_preprocess,
@@ -378,22 +375,6 @@ def check_train_device_preprocess(min_reduction: float = 4.0) -> dict:
             assert np.array_equal(np.asarray(a), np.asarray(b)), (
                 "resumed params are not bit-identical to the "
                 "uninterrupted run")
-
-        # ---- Pallas fused-geometry kernel ≤ 1 ULP from the pure-XLA
-        #      reference, in CPU interpret mode, inside jit (the context
-        #      the step uses) ----
-        import jax as _jax
-        src = rng.integers(0, 256, (6, 24, 20, 3)).astype(np.uint8)
-        oy = rng.integers(0, 5, 6).astype(np.int32)
-        ox = rng.integers(0, 5, 6).astype(np.int32)
-
-        def run_impl(impl):
-            fn = _jax.jit(lambda a, b, c: fused_resize_norm(
-                a, b, c, (20, 16), (8, 8), 1.0 / 255.0, impl=impl))
-            return np.asarray(fn(src, oy, ox))
-
-        np.testing.assert_array_max_ulp(run_impl("xla"),
-                                        run_impl("pallas"), maxulp=1)
     finally:
         obs.disable()
         obs.clear()

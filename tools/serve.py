@@ -239,6 +239,8 @@ def main(argv: list[str] | None = None) -> int:
         # a misordered/duplicate --buckets ladder is a typed refusal
         print(str(e), file=sys.stderr)
         return 2
+    from mmlspark_tpu.utils.jit_cache import place_compilation_cache
+    place_compilation_cache()
     server = ModelServer(config)
     versions = None
     provenance = None
