@@ -346,14 +346,15 @@ def bench_serve_precision(jm, rng, n_total: int = 128,
                 continue
             parity = max(float(np.abs(got[i] - ref[i]).max())
                          for i in got)
-            # traced pass: the compute/transfer/idle attribution per
-            # precision (obs device pillar), archived in BENCH_OBS.json
+            # traced pass: the host's phase split per precision (obs
+            # boundary spans), archived in BENCH_OBS.json
             obs.registry().reset()
+            obs.clear()  # boundary spans record with the tracer off too
             obs.enable(device=True)
             try:
                 for i in range(8):
                     client.predict("m", tables[i], timeout=600)
-                split = obs.device_time_split()
+                split = obs.host_phase_split()
             finally:
                 obs.disable()
                 obs.clear()
@@ -364,7 +365,7 @@ def bench_serve_precision(jm, rng, n_total: int = 128,
                 "serve_p99_ms": e2e.get("p99"),
                 "parity_max_abs": parity,
                 "occupancy_mean": snap.get("batch_occupancy_mean"),
-                "device_split": split,
+                "host_split": split,
             }
             if precision is not None:
                 rec["calibration_parity"] = load_snap.get(
@@ -960,10 +961,11 @@ def main() -> int:
                           "fused_h2d_mb": round(cnt.upload_bytes / 2**20, 2)}
         from mmlspark_tpu import obs
         obs.registry().reset()
+        obs.clear()  # boundary spans record with the tracer off too
         # device=True: the traced pass also captures per-segment compile
-        # cost + XLA cost/memory gauges (plan.segment.*) and the
-        # compute/transfer/idle split of the HOST spans (not a device
-        # busy/idle share — that needs a profiler trace)
+        # cost + XLA cost/memory gauges (plan.segment.*); the host's
+        # phase split comes from the always-on boundary spans (not a
+        # device busy/idle share — that needs a profiler trace)
         obs.enable(device=True)
         try:
             with plan_lib.count_crossings() as chk:
@@ -976,7 +978,7 @@ def main() -> int:
         # snapshot schema as the /metrics endpoint
         obs_snapshot = obs.registry().snapshot()
         obs_counters = obs_snapshot["counters"]
-        device_split = obs.device_time_split()
+        host_split = obs.host_phase_split()
         obs.clear()
         obs.registry().reset()
         obs.device.reset()
@@ -984,7 +986,7 @@ def main() -> int:
             obs_counters.get("plan.h2d_uploads", 0) == chk.uploads
             and obs_counters.get("plan.d2h_fetches", 0) == chk.fetches
             and obs_counters.get("plan.h2d_bytes", 0) == chk.upload_bytes)
-        pipe_crossings["device_split"] = device_split
+        pipe_crossings["host_split"] = host_split
         pipe_crossings["segment_gauges"] = {
             k: v for k, v in obs_snapshot["gauges"].items()
             if k.startswith("plan.segment.")}

@@ -42,6 +42,7 @@ from mmlspark_tpu.data.table import DataTable
 from mmlspark_tpu.obs import device as _obs_dev
 from mmlspark_tpu.obs import runtime as _obs_rt
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
+from mmlspark_tpu.obs.spans import boundary_span as _obs_boundary
 from mmlspark_tpu.obs.spans import span as _obs_span
 
 _log = get_logger(__name__)
@@ -71,25 +72,27 @@ def minibatches(batch: np.ndarray, size: int
 # ---- the H2D / D2H crossing points. Every device entry and exit of the
 #      minibatch pipeline goes through these two functions, so crossing
 #      counts are observable: tools/perf_smoke.py monkeypatches them, and
-#      the obs registry counts them (plan.h2d_uploads / plan.h2d_bytes /
-#      plan.d2h_fetches, plus one plan.h2d_shapes series per distinct
-#      upload shape — the recompile observable) when tracing is on ----
+#      the obs registry counts them — plan.h2d_uploads / plan.h2d_bytes /
+#      plan.d2h_bytes always (the boundary tier's counters), and with
+#      tracing on plan.d2h_fetches plus one plan.h2d_shapes series per
+#      distinct upload shape (the recompile observable) ----
 
 def _upload(chunk: np.ndarray, target: Any) -> Any:
     """ONE host→device transfer of one minibatch."""
     import jax
+    nbytes = int(getattr(chunk, "nbytes", 0))
+    reg = _obs_registry()
+    reg.counter("plan.h2d_uploads").add()
+    reg.counter("plan.h2d_bytes").add(nbytes)
+    labels = None
     if _obs_rt._enabled:
-        nbytes = int(getattr(chunk, "nbytes", 0))
         shape = getattr(chunk, "shape", None)
-        reg = _obs_registry()
-        reg.counter("plan.h2d_uploads").add()
-        reg.counter("plan.h2d_bytes").add(nbytes)
         if shape is not None:
             reg.counter("plan.h2d_shapes",
                         shape=str(tuple(shape))).add()
-        with _obs_span("plan/h2d", "plan", {"bytes": nbytes}):
-            return jax.device_put(chunk, target)
-    return jax.device_put(chunk, target)
+        labels = {"bytes": nbytes}
+    with _obs_boundary("plan/h2d", "plan", labels, nbytes=nbytes):
+        return jax.device_put(chunk, target)
 
 
 def _issue_fetch(outs: tuple) -> None:
@@ -180,11 +183,12 @@ def _windowed_dispatch(fn: Callable, dev_params: Any, batch: np.ndarray,
 
     def drain_one() -> None:
         outs, valid = window.popleft()
-        with _obs_span("plan/d2h", "plan"):
+        # the host blocks here until the device has produced this
+        # minibatch: the span is the wait for the device
+        with _obs_boundary("plan/d2h", "plan"):
             host = [np.asarray(o)[:valid] for o in outs]
-        if _obs_rt._enabled:
-            _obs_registry().counter("plan.d2h_bytes").add(
-                sum(int(h.nbytes) for h in host))
+        _obs_registry().counter("plan.d2h_bytes").add(
+            sum(int(h.nbytes) for h in host))
         pieces.append(host)
 
     for chunk, valid in minibatches(batch, size):
@@ -194,7 +198,7 @@ def _windowed_dispatch(fn: Callable, dev_params: Any, batch: np.ndarray,
         attrib = _obs_rt._enabled and _obs_dev._enabled
         labels = ({"shape": str(tuple(chunk.shape))}
                   if _obs_rt._enabled else None)
-        with _obs_span("plan/dispatch", "plan", labels):
+        with _obs_boundary("plan/dispatch", "plan", labels):
             committed = _upload(chunk, target)
             if attrib:
                 # device attribution: detect a fresh XLA compile via
@@ -211,7 +215,7 @@ def _windowed_dispatch(fn: Callable, dev_params: Any, batch: np.ndarray,
         if attrib:
             # outside the dispatch span: cost capture AOT-recompiles the
             # program once per entry shape, and that second compile must
-            # not inflate the compute side of device_time_split()
+            # not count as dispatch time in host_phase_split()
             _obs_dev.note_dispatch(fn, dev_params, chunk, label,
                                    cache_before, dur_call)
         window.append((outs, valid))
@@ -253,7 +257,8 @@ def pipeline_minibatches(fn: Callable, dev_params: Any, batch: np.ndarray,
     pieces, _shapes, drain_rest = _windowed_dispatch(
         fn, dev_params, batch, size, target, max_inflight, label=label)
     drain_rest()
-    return _assemble_outputs(pieces)
+    with _obs_boundary("transform/assemble", "plan"):
+        return _assemble_outputs(pieces)
 
 
 # ---- segment entry: host column → one stacked device-ready array ----
@@ -776,24 +781,34 @@ def _cached_segment(seg: _Segment, cache_host: Any) -> tuple:
 
 def _run_segment(seg: _Segment, table: DataTable,
                  cache_host: Any) -> DataTable | None:
-    """Execute a fused segment; None if entry coercion fails (host path)."""
-    coerced = _coerce_entry(table, seg.entry_col, seg.entry_meta)
-    if coerced is None:
-        return None
-    batch, ctx = coerced
-    size, max_inflight = _segment_minibatch(seg)
-    fn, dev_params, target, dp = _cached_segment(seg, cache_host)
+    """Execute a fused segment; None if entry coercion fails (host path).
+    Carries the same boundary spans as ``JaxModel.transform``: one
+    ``transform`` root a call, ``transform/coerce`` and
+    ``transform/assemble`` under it beside the dispatch seams' own."""
+    with _obs_boundary("transform", "plan", rows=len(table)) as root:
+        with _obs_boundary("transform/coerce", "plan"):
+            coerced = _coerce_entry(table, seg.entry_col, seg.entry_meta)
+        if coerced is None:
+            root.rows = 0  # declined: the host path scores these rows
+            return None
+        batch, ctx = coerced
+        size, max_inflight = _segment_minibatch(seg)
+        fn, dev_params, target, dp = _cached_segment(seg, cache_host)
 
-    # minibatch must divide over the data axes (shared sizing helper)
-    size = dp_rounded_minibatch(size, dp, len(batch))
+        # minibatch must divide over the data axes (shared sizing helper)
+        size = dp_rounded_minibatch(size, dp, len(batch))
+        root.minibatches = -(-len(batch) // size)
 
-    names = "→".join(type(s).__name__ for s in seg.stages)
-    with timed(f"FusedSegment[{names}]", _log, len(table)):
-        outs = pipeline_minibatches(fn, dev_params, batch, size, target,
-                                    max_inflight, label=names)
-    for col, values in zip(seg.out_cols, outs):
-        emitter = seg.stages[seg.emitters[col]]
-        table = emitter.device_emit(table, values, seg.out_metas[col], ctx)
+        names = "→".join(type(s).__name__ for s in seg.stages)
+        with timed(f"FusedSegment[{names}]", _log, len(table)):
+            outs = pipeline_minibatches(fn, dev_params, batch, size, target,
+                                        max_inflight, label=names)
+        with _obs_boundary("transform/assemble", "plan"):
+            for col, values in zip(seg.out_cols, outs):
+                emitter = seg.stages[seg.emitters[col]]
+                table = emitter.device_emit(table, values,
+                                            seg.out_metas[col], ctx)
+    _obs_registry().counter("transform.rows").add(len(table))
     return table
 
 

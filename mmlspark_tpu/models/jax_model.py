@@ -46,6 +46,8 @@ from mmlspark_tpu.core.stage import (
 )
 from mmlspark_tpu.data.table import DataTable
 from mmlspark_tpu.models.bundle import ModelBundle, PREPROCESSORS
+from mmlspark_tpu.obs.metrics import registry as _obs_registry
+from mmlspark_tpu.obs.spans import boundary_span as _obs_boundary
 from mmlspark_tpu.parallel import mesh as mesh_lib
 
 _log = get_logger(__name__)
@@ -239,12 +241,15 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
         size = self.minibatch_size or config.get("default_minibatch_size")
         if len(table) == 0:
             return table.with_column(self.output_col, [])
-        with timed(f"JaxModel[{bundle.name}:{node}]", _log, len(table)):
-            batch = coerce_input_matrix(table, self.input_col,
-                                        bundle.input_spec)
+        with timed(f"JaxModel[{bundle.name}:{node}]", _log, len(table)), \
+                _obs_boundary("transform", "plan", rows=len(table)) as root:
+            with _obs_boundary("transform/coerce", "plan"):
+                batch = coerce_input_matrix(table, self.input_col,
+                                            bundle.input_spec)
             fn, dev_params, data, dp = self._compiled_apply(bundle, node)
             # minibatch must divide over the data axes (shared sizing)
             size = dp_rounded_minibatch(size, dp, len(batch))
+            root.minibatches = -(-len(batch) // size)
             # the three-stage upload/compute/fetch software pipeline with
             # the max_inflight HBM bound, shared with fused pipeline
             # segments (core.plan)
@@ -252,11 +257,11 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
                 fn, dev_params, batch, size, data,
                 int(self.max_inflight),
                 label=f"JaxModel[{bundle.name}:{node}]")[0]
-        if result.ndim == 1:
-            out_col: Any = result
-        else:
-            out_col = list(result)
-        return table.with_column(self.output_col, out_col)
+            with _obs_boundary("transform/assemble", "plan"):
+                out_col: Any = result if result.ndim == 1 else list(result)
+                out = table.with_column(self.output_col, out_col)
+        _obs_registry().counter("transform.rows").add(len(out))
+        return out
 
     # ---- static schema inference ----
 

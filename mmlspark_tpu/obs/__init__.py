@@ -39,8 +39,8 @@ timeline:
   hang (``MMLSPARK_TPU_FLIGHT=<dir>``).
 * :mod:`~mmlspark_tpu.obs.device` — **device attribution**: per-segment
   compile-time histograms, XLA cost/memory gauges
-  (``plan.segment.*``), live device-memory polling, and the
-  compute/transfer/idle timeline split.
+  (``plan.segment.*``), live device-memory polling, and the host
+  phase split over the always-on boundary spans.
 * :mod:`~mmlspark_tpu.obs.anomaly` — the **train anomaly plane**:
   non-finite loss sentinel (typed :class:`NonFiniteLossError`) and
   multi-host straggler detection (``train.host_skew``).
@@ -73,7 +73,7 @@ from mmlspark_tpu.obs.runtime import (  # noqa: F401
     clear, compiled_programs, disable, enable, enabled,
 )
 from mmlspark_tpu.obs.runtime import spans as captured  # noqa: F401
-from mmlspark_tpu.obs.spans import event, span  # noqa: F401
+from mmlspark_tpu.obs.spans import boundary_span, event, span  # noqa: F401
 from mmlspark_tpu.obs.context import (  # noqa: F401
     REQUEST_JOURNEY, bind, check_journey, mint, request_traces,
 )
@@ -97,7 +97,7 @@ from mmlspark_tpu.obs.anomaly import (  # noqa: F401
     NonFiniteLossError, NonFiniteSentinel, StragglerDetector,
 )
 from mmlspark_tpu.obs.device import (  # noqa: F401
-    device_time_split, poll_memory,
+    host_phase_split, poll_memory,
 )
 
 __all__ = [
@@ -118,19 +118,20 @@ __all__ = [
     "StragglerDetector",
     "anomaly",
     "bind",
+    "boundary_span",
     "captured",
     "check_journey",
     "chrome_trace",
     "clear",
     "compiled_programs",
     "device",
-    "device_time_split",
     "disable",
     "enable",
     "enabled",
     "event",
     "fleet",
     "flight",
+    "host_phase_split",
     "lockwitness",
     "metrics_snapshot",
     "mint",

@@ -1,11 +1,11 @@
 """Device attribution — compiled-program cost, memory accounting, and the
-compute/transfer/idle split.
+host's phase split.
 
 PR 5 made the crossing *counts* observable (every H2D/D2H through the
 plan seams lands in the registry), but the device itself stayed dark:
 what did each compiled segment cost to build, how much HBM does it
-touch, and how much of a step's wall clock is compute versus transfer
-versus host idle? This module is that accounting, in three pieces, all
+touch, and which phase of the host's work fills a call's wall clock?
+This module is that accounting, in four pieces, the first three
 recorded through the shared registry (the one-substrate rule):
 
 * **compile attribution** (:func:`note_dispatch`) — the plan dispatch
@@ -22,8 +22,8 @@ recorded through the shared registry (the one-substrate rule):
   read (the dispatch cache's executable is not introspectable, so this
   is a second compile of an identical program — the documented price of
   the opt-in pillar; the plan seam calls it *outside* the
-  ``plan/dispatch`` span so the recompile lands in the split's idle
-  time, never its compute), populating ``plan.segment.flops``,
+  ``plan/dispatch`` span so the recompile is unspanned time in the
+  split, never dispatch), populating ``plan.segment.flops``,
   ``plan.segment.bytes`` and ``plan.segment.peak_hbm`` gauges keyed by
   ``{segment=…, shape=…}``. ``peak_hbm`` prefers the backend's
   ``memory_analysis`` (argument + output + temp buffers); backends that
@@ -34,15 +34,18 @@ recorded through the shared registry (the one-substrate rule):
   ``device.mem_bytes_in_use{device=…}`` / ``device.mem_peak_bytes`` /
   ``device.mem_limit_bytes`` gauges; dryrun/CPU devices return nothing
   and the poll is a cheap no-op (never an error, never a jax init).
-* **timeline split** (:func:`device_time_split`) — the honest
-  compute/transfer/idle decomposition of a captured run, derived from
-  the *existing* ``plan/dispatch``/``plan/h2d``/``plan/d2h`` spans (no
-  new seams): dispatch intervals minus their nested H2D time are
-  compute-issue, D2H drains are transfer, and whatever the wall clock
-  holds beyond both is host idle. This is what ``bench.py`` reports
-  next to rows/s, so "input-bound" claims are backed by attribution.
+* **host phase split** (:func:`host_phase_split`) — where the HOST's
+  time went, from the boundary-tier spans (``obs/spans.boundary_span``,
+  always recorded): coerce, upload, dispatch self time, the blocking
+  fetch that waits for the device, output assembly, the train loop's
+  step dispatch and lagged loss fetch, and the unspanned rest. Dispatch
+  is asynchronous, so none of these is device time: what the device did
+  comes from a profiler trace (``benchmark/trace_reduce.py``), and the
+  spans' epoch stamps (``SpanRecord.start_epoch_ns``) lay the two side
+  by side.
 
-The pillar is OFF by default and independent of the tracer flag:
+The attribution pillar (the first three pieces' recording side) is OFF
+by default and independent of the tracer flag; the split only reads:
 ``obs.enable(device=True)`` (or ``MMLSPARK_TPU_OBS_DEVICE=1``) turns it
 on along with ``jax.profiler`` device annotations. Disabled, the plan
 seam pays one extra attribute check per dispatched minibatch — inside
@@ -217,10 +220,21 @@ def poll_memory(reg: Any = None) -> dict:
     return out
 
 
-# span names the timeline split classifies (all pre-existing seams)
-_DISPATCH_SPANS = ("plan/dispatch",)
-_H2D_SPANS = ("plan/h2d",)
-_D2H_SPANS = ("plan/d2h",)
+# the host phases, in order of precedence where spans of different
+# threads overlap: an instant belongs to the first phase that covers it.
+# All are boundary-tier span names (docs/observability.md)
+_PHASES = (
+    ("h2d", ("plan/h2d",)),
+    ("dispatch", ("plan/dispatch",)),       # self time: h2d taken out
+    ("fetch_wait", ("plan/d2h",)),          # the wait for the device
+    ("coerce", ("transform/coerce",)),
+    ("assemble", ("transform/assemble",)),
+    ("loss_fetch", ("train/loss_fetch",)),  # the train loop's wait
+    ("step_dispatch", ("train/step",)),
+)
+# spans that only bound the wall (their self time is unspanned)
+_ROOTS = ("transform",)
+_PHASE_OF = {name: phase for phase, names in _PHASES for name in names}
 
 
 def _union(intervals: list) -> list:
@@ -258,54 +272,56 @@ def _subtract(base: list, cut: list) -> list:
     return out
 
 
-def device_time_split(records: list | None = None) -> dict | None:
-    """Compute/transfer/idle attribution of a captured run's plan spans.
+def host_phase_split(records: list | None = None,
+                     wall_s: float | None = None) -> dict | None:
+    """Where the host's time went, from the boundary spans of a run.
 
-    Host-side attribution over the UNION of span intervals — concurrent
-    serve lanes (dp>1) emit overlapping ``plan/dispatch`` spans, and a
-    naive per-span duration sum would report compute > wall and
-    fractions > 1. Attribution precedence inside the occupied union:
-    ``plan/h2d`` is transfer, ``plan/dispatch`` time not spent in its
-    nested h2d is compute-issue, ``plan/d2h`` time outside both is the
-    blocking device→host drains, and ``idle`` is the wall clock no plan
-    span covers — the time the host spent between device work (packing,
-    queue waits, python). Single-threaded captures decompose exactly as
-    a per-span sum would. ``None`` when the capture holds no plan
-    spans. Returns milliseconds plus fractions of wall (which now
-    always sum to 1)."""
+    Attribution is over the UNION of span intervals — concurrent serve
+    lanes (dp>1) emit overlapping ``plan/dispatch`` spans, and a naive
+    per-span duration sum would report more than the wall. Where spans
+    of different phases overlap the earlier entry of ``_PHASES`` wins:
+    ``plan/h2d`` is ``h2d``, ``plan/dispatch`` time outside its nested
+    h2d is ``dispatch`` (issuing the async call and the fetch),
+    ``plan/d2h`` outside both is ``fetch_wait`` (the host blocked until
+    the device produced a minibatch), and so on down the table. A
+    single-threaded capture decomposes exactly as a per-span sum would.
+
+    Returns ``<phase>_s`` (seconds) and ``<phase>_share`` (of the wall)
+    for every phase and for ``unspanned``, the wall no phase covers: a
+    ``transform`` root's self time and whatever the caller did between
+    calls. The wall is ``wall_s`` when given (a benchmark's window, which
+    begins before the first span and ends after the last), else the
+    covered wall from the first span's start to the last span's end.
+    ``None`` when ``records`` (default: the ring) hold no such span.
+    This is HOST time: dispatch is asynchronous, so no field says what
+    the device was doing."""
     from mmlspark_tpu.obs.events import SpanRecord
 
-    by_kind: dict[str, list] = {"dispatch": [], "h2d": [], "d2h": []}
+    by_phase: dict[str, list] = {phase: [] for phase, _ in _PHASES}
+    lo = hi = None
     if records is None:
         records = _rt.spans()
     for r in records:
-        if not isinstance(r, SpanRecord) or r.cat != "plan":
+        if not isinstance(r, SpanRecord):
             continue
-        if r.name in _DISPATCH_SPANS:
-            by_kind["dispatch"].append((r.start_ns, r.end_ns))
-        elif r.name in _H2D_SPANS:
-            by_kind["h2d"].append((r.start_ns, r.end_ns))
-        elif r.name in _D2H_SPANS:
-            by_kind["d2h"].append((r.start_ns, r.end_ns))
-    all_iv = by_kind["dispatch"] + by_kind["h2d"] + by_kind["d2h"]
-    if not all_iv:
+        phase = _PHASE_OF.get(r.name)
+        if phase is None and r.name not in _ROOTS:
+            continue
+        if phase is not None:
+            by_phase[phase].append((r.start_ns, r.end_ns))
+        lo = r.start_ns if lo is None else min(lo, r.start_ns)
+        hi = r.end_ns if hi is None else max(hi, r.end_ns)
+    if lo is None:
         return None
-    u_h2d = _union(by_kind["h2d"])
-    u_disp = _union(by_kind["dispatch"])
-    u_d2h = _union(by_kind["d2h"])
-    wall = max(e for _, e in all_iv) - min(s for s, _ in all_iv)
-    h2d = _measure(u_h2d)
-    compute = _measure(_subtract(u_disp, u_h2d))
-    d2h = _measure(_subtract(_subtract(u_d2h, u_disp), u_h2d))
-    idle = max(wall - (compute + h2d + d2h), 0.0)
-    out = {
-        "wall_ms": round(wall / 1e6, 3),
-        "compute_ms": round(compute / 1e6, 3),
-        "h2d_ms": round(h2d / 1e6, 3),
-        "d2h_ms": round(d2h / 1e6, 3),
-        "idle_ms": round(idle / 1e6, 3),
-    }
+    wall = (hi - lo) / 1e9 if wall_s is None else float(wall_s)
+    out = {"wall_s": wall}
+    claimed: list = []
+    for phase, _names in _PHASES:
+        own = _subtract(_union(by_phase[phase]), claimed)
+        out[f"{phase}_s"] = _measure(own) / 1e9
+        claimed = _union(claimed + own)
+    out["unspanned_s"] = max(wall - _measure(claimed) / 1e9, 0.0)
     if wall > 0:
-        for key in ("compute", "h2d", "d2h", "idle"):
-            out[f"{key}_fraction"] = round(out[f"{key}_ms"] * 1e6 / wall, 4)
+        for key in [k for k in out if k != "wall_s"]:
+            out[key[:-2] + "_share"] = out[key] / wall
     return out

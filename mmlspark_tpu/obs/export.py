@@ -96,7 +96,9 @@ def chrome_trace(records: list | None = None) -> dict:
     Replica-labeled serve spans render one lane per replica
     (:func:`_record_lane`), and request trace ids render as flow
     events (:func:`_flow_events`) so one request's journey draws as
-    arrows across lanes."""
+    arrows across lanes. ``ts`` stays on the ``perf_counter`` clock;
+    ``otherData.epoch_offset_ns`` is what to add to ``ts * 1000`` for
+    Unix-epoch nanoseconds (``obs/runtime.to_epoch_ns``)."""
     if records is None:
         records = _rt.spans()
     pid = os.getpid()
@@ -136,7 +138,8 @@ def chrome_trace(records: list | None = None) -> dict:
             "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
             "args": {"name": tname},
         })
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
+    return {"traceEvents": events, "displayTimeUnit": "ms",
+            "otherData": {"epoch_offset_ns": _rt.to_epoch_ns(0)}}
 
 
 def _flow_events(flows: dict[int, list[tuple[SpanRecord, int]]],

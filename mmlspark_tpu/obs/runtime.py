@@ -10,6 +10,15 @@ allocation, no lock (the ``< 2%`` disabled-overhead gate in
 
 Enable programmatically (``obs.enable()``), or from the environment with
 ``MMLSPARK_TPU_OBS=1`` (read once at import through ``core.config``).
+The boundary tier (``obs/spans.boundary_span``) records into the same
+ring regardless of the flag.
+
+Spans are stamped with ``time.perf_counter_ns``; ONE anchor pair taken
+here at import (:data:`CLOCK_ANCHOR`) places them on the Unix epoch
+(:func:`to_epoch_ns`). A ``jax.profiler`` trace stamps its planes
+relative to the session and the session's start on that same epoch (the
+``profile_start_time`` stat of its ``Task Environment`` plane), so the two
+can be laid side by side (PERF.md has the offset measured on the chip).
 
 The **compile-cache hook** lives here too: reading an XLA program count
 off a jitted callable's own compile cache was serve-local in PR 4
@@ -22,12 +31,36 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from collections import deque
 from typing import Any
 
 from mmlspark_tpu.core import config
 from mmlspark_tpu.obs.events import EventRecord, SpanRecord
 from mmlspark_tpu.obs.lockwitness import named_lock
+
+
+def _clock_anchor() -> tuple[int, int]:
+    """``(perf_counter_ns, time_ns)`` of one instant: the epoch read sits
+    between two monotonic reads and is paired with their midpoint."""
+    p0 = time.perf_counter_ns()
+    wall = time.time_ns()
+    return (p0 + time.perf_counter_ns()) // 2, wall
+
+
+# the process's one perf→epoch anchor; a span's epoch time is its
+# perf_counter stamp shifted by the pair's difference. On Linux both
+# clocks follow the same NTP slew and part only when the wall clock is
+# stepped, so the pair holds to microseconds over a run (PERF.md: 2-5 us
+# on the chip machine); after a step every later epoch stamp is off by it
+CLOCK_ANCHOR = _clock_anchor()
+
+
+def to_epoch_ns(perf_ns: int) -> int:
+    """A ``time.perf_counter_ns`` stamp of this process in Unix-epoch
+    nanoseconds."""
+    return perf_ns - CLOCK_ANCHOR[0] + CLOCK_ANCHOR[1]
+
 
 DEFAULT_BUFFER = 65536
 # distinct request traces retained for grouping (obs/context.py) before

@@ -21,6 +21,15 @@ With ``enable(device_annotations=True)`` each span also enters
 ``jax.profiler.TraceAnnotation`` (via ``utils/profiling.annotate``), so
 an XProf/Perfetto device capture shows the same names on its host track,
 interleaved with the device ops dispatched under them.
+
+The **boundary tier** (:func:`boundary_span`) is the one exception to
+"off unless enabled": a fixed, small set of spans at the layer
+boundaries of ``transform`` and the fit loops (docs/observability.md
+lists them) records into the same ring whether or not the tracer is on
+— like ``Trainer.input_stats`` it is what an operator always has. Each
+costs two clock reads and one slotted record carrying at most the
+integers the seam already holds; labels and device annotations stay
+gated with the tracer.
 """
 
 from __future__ import annotations
@@ -64,15 +73,23 @@ def _annotation(name: str):
 
 
 class _Span:
-    __slots__ = ("name", "cat", "labels", "links", "_t0", "_span_id",
+    # rows/nbytes/minibatches are public: a boundary seam that learns a
+    # count only inside the span (transform's minibatches) sets it there
+    __slots__ = ("name", "cat", "labels", "links", "rows", "nbytes",
+                 "minibatches", "_boundary", "_root", "_t0", "_span_id",
                  "_parent", "_depth", "_trace", "_annot")
 
     def __init__(self, name: str, cat: str, labels: dict | None,
-                 links: tuple | None = None):
+                 links: tuple | None = None, boundary: bool = False,
+                 rows: int | None = None, nbytes: int | None = None):
         self.name = name
         self.cat = cat
         self.labels = labels
         self.links = links
+        self.rows = rows
+        self.nbytes = nbytes
+        self.minibatches = None
+        self._boundary = boundary
 
     def __enter__(self) -> "_Span":
         stack = getattr(_tls, "stack", None)
@@ -82,6 +99,14 @@ class _Span:
         self._parent = stack[-1] if stack else None
         self._depth = len(stack)
         stack.append(self._span_id)
+        self._root = None
+        if self._boundary:
+            # the outermost boundary span open on this thread names the
+            # call every boundary span under it belongs to
+            root = getattr(_tls, "root", None)
+            if root is None:
+                root = _tls.root = self._span_id
+            self._root = root
         # the thread's active request context (obs/context.bind): spans
         # recorded while a trace is bound belong to that request
         self._trace = _ctx.current()
@@ -101,11 +126,14 @@ class _Span:
         stack = _tls.stack
         if stack and stack[-1] == self._span_id:
             stack.pop()
+        if self._root == self._span_id:
+            _tls.root = None
         th = threading.current_thread()
         _rt.record(SpanRecord(self.name, self.cat, self._t0, dur,
                               th.ident or 0, th.name, self._span_id,
                               self._parent, self._depth, self.labels,
-                              self._trace, self.links))
+                              self._trace, self.links, self._root,
+                              self.rows, self.nbytes, self.minibatches))
         return False
 
 
@@ -119,6 +147,17 @@ def span(name: str, cat: str = "host", labels: dict | None = None,
     if not _rt._enabled:
         return _NULL
     return _Span(name, cat, labels, links)
+
+
+def boundary_span(name: str, cat: str = "host", labels: dict | None = None,
+                  rows: int | None = None,
+                  nbytes: int | None = None) -> _Span:
+    """A span of the boundary tier: recorded whether or not the tracer
+    is enabled (module docstring). Only the listed layer boundaries use
+    it; everything else goes through :func:`span` and stays one flag
+    check when off. Call sites build ``labels`` only when the tracer is
+    on, as they do for :func:`span`."""
+    return _Span(name, cat, labels, None, True, rows, nbytes)
 
 
 def event(name: str, cat: str = "host",

@@ -29,6 +29,7 @@ from mmlspark_tpu.obs import flight as _obs_flight
 from mmlspark_tpu.obs import runtime as _obs_rt
 from mmlspark_tpu.obs.anomaly import NonFiniteSentinel, StragglerDetector
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
+from mmlspark_tpu.obs.spans import boundary_span as _obs_boundary
 from mmlspark_tpu.obs.spans import span as _obs_span
 from mmlspark_tpu.parallel import mesh as mesh_lib
 from mmlspark_tpu.train import preprocess as preprocess_lib
@@ -664,6 +665,20 @@ class Trainer:
         if _obs_rt._enabled:
             _obs_registry().histogram("train.loss").observe(float(value))
 
+    def _fetch_loss(self, sentinel: NonFiniteSentinel,
+                    pending: tuple) -> None:
+        """Resolve one lagged ``(step, device loss scalar)`` log point.
+        The ``float()`` is the fit loops' only explicit host sync, hence
+        a boundary span. It rarely waits: the scalar is a log interval
+        old, and the runtime already holds the host back inside the
+        step's dispatch once its limit of computations is in flight
+        (about 34 steps on a v5e, PERF.md). Only the fetch that closes a
+        fit waits long: the device is that far behind the last step."""
+        step, loss = pending
+        with _obs_boundary("train/loss_fetch", "train"):
+            value = float(loss)
+        self._note_loss(sentinel.check(step, value))
+
     def fit_arrays(self, x: np.ndarray, y: np.ndarray) -> "Trainer":
         """Train on host arrays.
 
@@ -805,11 +820,13 @@ class Trainer:
                        len(x)):
                 for gs, i, (dx, dy, dw) in loader:
                     # the span times step DISPATCH (async issue), not
-                    # device compute — the honest host-side number; the
-                    # wait surfaces in the loader's wait span instead
+                    # device compute; on a device-bound job the dispatch
+                    # itself blocks once the runtime's limit of
+                    # computations is in flight, so back-pressure shows
+                    # here (input starvation in the loader's wait span)
                     t_step = time.perf_counter() if _obs_rt._enabled \
                         else None
-                    with _obs_span("train/step", "train"):
+                    with _obs_boundary("train/step", "train"):
                         self.state, metrics = self.step_masked(
                             self.state, dx, dy, dw)
                     if _obs_flight._rec is not None:
@@ -821,15 +838,13 @@ class Trainer:
                                 (time.perf_counter() - t_step) * 1e3)
                     if i % cfg.log_every == 0:
                         if pending is not None:
-                            self._note_loss(sentinel.check(
-                                pending[0], float(pending[1])))  # lint-jax: allow(JX105) — one-step-lagged fetch
+                            self._fetch_loss(sentinel, pending)
                         pending = (gs, metrics["loss"])
                     if (ckpt is not None and cfg.checkpoint_every > 0
                             and gs % cfg.checkpoint_every == 0):
                         self.save_checkpoint()
             if pending is not None:
-                self._note_loss(sentinel.check(pending[0],
-                                               float(pending[1])))
+                self._fetch_loss(sentinel, pending)
                 pending = None
         except BaseException as e:
             # the post-mortem happens AT the failure point, before any
@@ -1048,7 +1063,7 @@ class Trainer:
                 for gs, (dx, dy, dw) in loader:
                     t_step = time.perf_counter() if _obs_rt._enabled \
                         else None
-                    with _obs_span("train/step", "train"):
+                    with _obs_boundary("train/step", "train"):
                         self.state, metrics = self.step_masked(
                             self.state, dx, dy, dw)
                     if _obs_flight._rec is not None:
@@ -1061,8 +1076,7 @@ class Trainer:
                             straggler.observe(dur_ms)
                     if (gs - 1) % cfg.log_every == 0:
                         if pending is not None:
-                            self._note_loss(sentinel.check(
-                                pending[0], float(pending[1])))  # lint-jax: allow(JX105) — one-step-lagged fetch
+                            self._fetch_loss(sentinel, pending)
                         pending = (gs, metrics["loss"])
                     if (ckpt is not None and cfg.checkpoint_every > 0
                             and gs % cfg.checkpoint_every == 0):
@@ -1075,8 +1089,7 @@ class Trainer:
                     # checkpoint barrier across processes
                     loader.note_dispatched()
             if pending is not None:
-                self._note_loss(sentinel.check(pending[0],
-                                               float(pending[1])))
+                self._fetch_loss(sentinel, pending)
                 pending = None
         except BaseException as e:
             _obs_flight.on_crash(e, context="Trainer.fit_stream")

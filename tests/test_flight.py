@@ -17,8 +17,8 @@ The contracts under test:
 * the straggler detector names the artificially-delayed host from the
   gathered per-host step-time vector;
 * device attribution populates ``plan.segment.*`` cost/memory gauges per
-  fused segment and decomposes captured plan spans into an honest
-  compute/transfer/idle split.
+  fused segment, and ``host_phase_split`` decomposes captured plan spans
+  into host phases that sum to the wall.
 """
 
 import glob
@@ -473,7 +473,7 @@ def test_segment_gauges_and_compile_attribution():
     assert not obs_device.enabled()
 
 
-def test_device_split_decomposes_plan_spans():
+def test_host_split_decomposes_plan_spans():
     obs.enable()
     bundle = mlp_bundle(6)
     jm = JaxModel(model=bundle, input_col="x", output_col="scores",
@@ -482,25 +482,26 @@ def test_device_split_decomposes_plan_spans():
     table = DataTable({"x": list(rng.normal(size=(24, 6))
                                  .astype(np.float32))})
     jm.transform(table)
-    split = obs.device_time_split()
+    split = obs.host_phase_split()
     assert split is not None
-    parts = (split["compute_ms"] + split["h2d_ms"] + split["d2h_ms"]
-             + split["idle_ms"])
-    assert parts == pytest.approx(split["wall_ms"], rel=0.02)
-    fr = (split["compute_fraction"] + split["h2d_fraction"]
-          + split["d2h_fraction"] + split["idle_fraction"])
+    assert not any("compute" in k or "idle" in k for k in split)
+    parts = sum(v for k, v in split.items()
+                if k.endswith("_s") and k != "wall_s")
+    assert parts == pytest.approx(split["wall_s"], rel=0.02)
+    fr = sum(v for k, v in split.items() if k.endswith("_share"))
     assert fr == pytest.approx(1.0, abs=0.02)
     assert all(split[k] >= 0 for k in split)
-    # no plan spans → no split (never a division by zero)
+    assert split["h2d_s"] > 0 and split["coerce_s"] > 0
+    # no boundary spans → no split (never a division by zero)
     obs.clear()
-    assert obs.device_time_split() is None
-    assert obs.device_time_split(records=[]) is None
+    assert obs.host_phase_split() is None
+    assert obs.host_phase_split(records=[]) is None
 
 
-def test_device_split_is_sane_for_concurrent_serve_lanes():
+def test_host_split_is_sane_for_concurrent_serve_lanes():
     """Regression: dp>1 serve lanes emit OVERLAPPING plan/dispatch
-    spans; a per-span duration sum reported compute > wall and
-    fractions > 1. The split now measures the union of intervals."""
+    spans; a per-span duration sum reported more than the wall and
+    shares > 1. The split measures the union of intervals."""
     from mmlspark_tpu.obs.events import SpanRecord
 
     def span(name, start_ms, dur_ms, tid):
@@ -511,18 +512,18 @@ def test_device_split_is_sane_for_concurrent_serve_lanes():
     # 4 lanes dispatching [0, 10] ms concurrently, then one 2 ms drain
     records = [span("plan/dispatch", 0, 10, t) for t in range(4)]
     records.append(span("plan/d2h", 10, 2, 0))
-    split = obs.device_time_split(records)
-    assert split["wall_ms"] == pytest.approx(12.0)
-    assert split["compute_ms"] == pytest.approx(10.0)  # union, not 40
-    assert split["d2h_ms"] == pytest.approx(2.0)
-    total_fraction = sum(split[k] for k in split if k.endswith("_fraction"))
-    assert total_fraction == pytest.approx(1.0, abs=0.01)
-    # h2d nested in dispatch still subtracts from compute, once
+    split = obs.host_phase_split(records)
+    assert split["wall_s"] == pytest.approx(0.012)
+    assert split["dispatch_s"] == pytest.approx(0.010)  # union, not 40
+    assert split["fetch_wait_s"] == pytest.approx(0.002)
+    total_share = sum(split[k] for k in split if k.endswith("_share"))
+    assert total_share == pytest.approx(1.0, abs=0.01)
+    # h2d nested in dispatch still subtracts from dispatch, once
     records = [span("plan/dispatch", 0, 10, t) for t in range(2)]
     records += [span("plan/h2d", 0, 3, t) for t in range(2)]
-    split = obs.device_time_split(records)
-    assert split["h2d_ms"] == pytest.approx(3.0)
-    assert split["compute_ms"] == pytest.approx(7.0)
+    split = obs.host_phase_split(records)
+    assert split["h2d_s"] == pytest.approx(0.003)
+    assert split["dispatch_s"] == pytest.approx(0.007)
 
 
 def test_poll_memory_never_initializes_a_backend():
