@@ -38,7 +38,7 @@ from mmlspark_tpu.core import config
 from mmlspark_tpu.core.logging_utils import get_logger, timed
 from mmlspark_tpu.core.schema import is_image_column
 from mmlspark_tpu.core.stage import ArrayMeta, DeviceOp, DeviceStage
-from mmlspark_tpu.data.table import DataTable
+from mmlspark_tpu.data.table import DataTable, copied_nbytes
 from mmlspark_tpu.obs import device as _obs_dev
 from mmlspark_tpu.obs import runtime as _obs_rt
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
@@ -786,8 +786,11 @@ def _run_segment(seg: _Segment, table: DataTable,
     ``transform`` root a call, ``transform/coerce`` and
     ``transform/assemble`` under it beside the dispatch seams' own."""
     with _obs_boundary("transform", "plan", rows=len(table)) as root:
-        with _obs_boundary("transform/coerce", "plan"):
+        with _obs_boundary("transform/coerce", "plan") as coerce:
             coerced = _coerce_entry(table, seg.entry_col, seg.entry_meta)
+            if coerced is not None:  # rows coerced, bytes copied for them
+                coerce.rows = len(table)
+                coerce.nbytes = copied_nbytes(coerced[0])
         if coerced is None:
             root.rows = 0  # declined: the host path scores these rows
             return None

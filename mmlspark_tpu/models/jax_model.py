@@ -8,8 +8,10 @@ evaluates minibatches, and merges outputs back row-wise
 
 * the model is a :class:`ModelBundle` (flax module + pytree) — no broadcast
   or per-task clone needed; jit-compiled functions are pure and cached,
-* input coercion is one vectorized host copy (``column_matrix`` /
-  image stacking) instead of per-element JNI sets,
+* input coercion is at most one vectorized host copy (``column_matrix`` /
+  image stacking) instead of per-element JNI sets, and none where the
+  column's rows already lie in one matrix (``column_matrix`` hands back a
+  read-only view of it),
 * the minibatch iterator pads the tail batch to a fixed shape so XLA
   compiles exactly one program per (batch, input) shape,
 * dispatch is asynchronous: host marshalling of batch *i+1* overlaps device
@@ -44,7 +46,7 @@ from mmlspark_tpu.core.schema import is_image_column
 from mmlspark_tpu.core.stage import (
     ArrayMeta, DeviceOp, DeviceStage, HasInputCol, HasOutputCol, Transformer,
 )
-from mmlspark_tpu.data.table import DataTable
+from mmlspark_tpu.data.table import DataTable, copied_nbytes
 from mmlspark_tpu.models.bundle import ModelBundle, PREPROCESSORS
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
 from mmlspark_tpu.obs.spans import boundary_span as _obs_boundary
@@ -67,7 +69,9 @@ def coerce_input_matrix(table: DataTable, column: str,
 
     Accepts: image-struct columns (stacked HWC), vector columns (reshaped to
     the model spec), scalar numeric columns. The dtype-coercion analog of
-    CNTKModel.scala:228-245, vectorized.
+    CNTKModel.scala:228-245, vectorized. A vector column that is already
+    one matrix comes back as a read-only view of it
+    (:meth:`DataTable.column_matrix`): upload from it, do not write to it.
     """
     col = table[column]
     if is_image_column(table, column):
@@ -243,9 +247,11 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
             return table.with_column(self.output_col, [])
         with timed(f"JaxModel[{bundle.name}:{node}]", _log, len(table)), \
                 _obs_boundary("transform", "plan", rows=len(table)) as root:
-            with _obs_boundary("transform/coerce", "plan"):
+            with _obs_boundary("transform/coerce", "plan",
+                               rows=len(table)) as coerce:
                 batch = coerce_input_matrix(table, self.input_col,
                                             bundle.input_spec)
+                coerce.nbytes = copied_nbytes(batch)
             fn, dev_params, data, dp = self._compiled_apply(bundle, node)
             # minibatch must divide over the data axes (shared sizing)
             size = dp_rounded_minibatch(size, dp, len(batch))
