@@ -24,9 +24,12 @@ Kernel discipline:
   (``jax.experimental.pallas.tpu.force_tpu_interpret_mode()``, what
   tier-1 does), and without it a non-TPU backend refuses loudly;
 * ``impl: auto | xla | pallas`` selects the backend (auto = kernel on
-  TPU, reference elsewhere). A tile past the VMEM budget under ``auto``
-  takes the reference and says so (a warning and the
-  ``ops.pallas.vmem_fallback`` counter); under ``pallas`` it raises.
+  TPU, reference elsewhere). ``flash_attention`` whose whole (batch,
+  head) tile is past the VMEM budget takes the tiled kernel (queries
+  tiled too, causal tiles above the diagonal skipped, operands in the
+  type they came in); ``attention_block_update`` past the budget under
+  ``auto`` takes the reference and says so (a warning and the
+  ``ops.pallas.vmem_fallback`` counter), under ``pallas`` it raises.
 
 What reaches the kernel is shaped for the compiler: ``Tq`` is padded to
 the f32 sublane tile and ``Tk`` to a whole number of ``block_k`` stripes
@@ -79,6 +82,18 @@ def _qk_t(q, ks, xp):
                                preferred_element_type=jnp.float32)
 
 
+def _p_v(p, vs, xp):
+    """``p [Tq, Tk] · vs [Tk, D] → [Tq, D]`` float32. The weights take the
+    values' type for the product (a no-op for the float32 tiles every
+    whole-tile caller hands over; bf16 values keep the MXU on bf16
+    operands with float32 accumulation)."""
+    if xp is np:
+        return np.dot(p, vs)
+    return jax.lax.dot_general(p.astype(vs.dtype), vs,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
     """THE shared body: one K/V block's flash-attention update for one
     (batch, head) tile.
@@ -95,12 +110,13 @@ def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
     # guard -inf - -inf (rows with every key masked so far)
     corr = xp.where(xp.isfinite(m), xp.exp(m - m_new), np.float32(0))
     p = xp.exp(xp.where(xp.isfinite(scores), scores - m_new, -xp.inf))
-    acc = acc * corr + xp.dot(p, vs)
+    acc = acc * corr + _p_v(p, vs, xp)
     denom = denom * corr + xp.sum(p, axis=-1, keepdims=True)
     return m_new, denom, acc
 
 
-def _flash_tile(q, stripe, tk: int, scale, xp, block_k: int):
+def _flash_tile(q, stripe, tk: int, scale, xp, block_k: int,
+                dv: int | None = None):
     """Full attention for one (batch, head) tile via the online-softmax
     block loop: ``q`` ``[Tq, D]`` f32 against ``tk`` keys → ``[Tq, D]``
     f32. ``stripe(start, stop)`` yields one K stripe as ``(ks, vs,
@@ -115,7 +131,7 @@ def _flash_tile(q, stripe, tk: int, scale, xp, block_k: int):
     tq, d = q.shape
     m = xp.full((tq, 1), -xp.inf, np.float32)
     denom = xp.zeros((tq, 1), np.float32)
-    acc = xp.zeros((tq, d), np.float32)
+    acc = xp.zeros((tq, d if dv is None else dv), np.float32)
     for start in range(0, tk, block_k):
         ks, vs, keep = stripe(start, min(start + block_k, tk))
         m, denom, acc = _online_update(q, ks, vs, keep, m, denom, acc,
@@ -173,7 +189,7 @@ def flash_attention_reference(q, k, v, mask3, scale,
         return _flash_tile(q2.astype(jnp.float32),
                            _sliced(k2.astype(jnp.float32),
                                    v2.astype(jnp.float32), keep2 != 0),
-                           k2.shape[0], s, jnp, block_k)
+                           k2.shape[0], s, jnp, block_k, v2.shape[1])
 
     over_h = jax.vmap(tile, in_axes=(0, 0, 0, None))
     return jax.vmap(over_h)(q, k, v, mask3)
@@ -224,17 +240,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, *,
                               block_k)
 
 
-def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    # shape the operands for the compiler: Tq to whole f32 sublane
-    # tiles, Tk to whole block_k stripes. Padded keys are masked out of
-    # every denominator; padded query rows are sliced away below
-    pad_q = -tq % _SUBLANES
-    pad_k = -tk % block_k
+def _padded_operands(q, k, v, kv_mask, q_multiple: int, k_multiple: int):
+    """Shape the operands for the compiler: ``Tq`` padded to a multiple of
+    ``q_multiple``, ``Tk`` to one of ``k_multiple``, and the ``[B, Tk]``
+    int32 key-validity row. Padded keys are masked out of every
+    denominator; padded query rows are for the caller to slice away."""
+    b, tq, tk = q.shape[0], q.shape[2], k.shape[2]
+    pad_q = -tq % q_multiple
+    pad_k = -tk % k_multiple
     kv_row = (jnp.ones((b, tk), jnp.int32) if kv_mask is None
               else jnp.asarray(kv_mask, bool).astype(jnp.int32))
     if pad_q:
@@ -243,7 +256,17 @@ def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         kv_row = jnp.pad(kv_row, ((0, 0), (0, pad_k)))
-    tq_p, tk_p = tq + pad_q, tk + pad_k
+    return q, k, v, kv_row
+
+
+def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, tq, d = q.shape
+    # Tq to whole f32 sublane tiles, Tk to whole block_k stripes
+    q, k, v, kv_row = _padded_operands(q, k, v, kv_mask, _SUBLANES, block_k)
+    tq_p, tk_p = q.shape[2], k.shape[2]
 
     def tile(i, j):
         return (i, j, 0, 0)
@@ -265,7 +288,101 @@ def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
         out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d), jnp.float32),
         name="flash_attention",
     )(q, k, v, kv_row.reshape(b, 1, tk_p))
-    return out[:, :, :tq] if pad_q else out
+    return out[:, :, :tq] if tq_p != tq else out
+
+
+# ---- the tiled kernel: sequences whose (batch, head) tile outgrows VMEM ----
+
+# query and key tile of the tiled kernel: one online update per tile pair
+TILE_Q = 512
+TILE_K = 512
+
+
+def _tiled_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, m_ref, d_ref, a_ref,
+                  *, scale: np.float32, causal: bool, bq: int, bk: int):
+    # grid (batch, head, query tile, key tile), the key tile innermost:
+    # the carry of the online softmax lives in scratch across key tiles.
+    # Operands stay in the type they came in (bf16 on the MXU, float32
+    # accumulation); the softmax is float32
+    import jax.experimental.pallas as pl
+
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        d_ref[...] = jnp.zeros(d_ref.shape, jnp.float32)
+        a_ref[...] = jnp.zeros(a_ref.shape, jnp.float32)
+
+    def _update():
+        keep = jnp.broadcast_to(kv_ref[0], (bq, bk)) != 0
+        if causal:
+            row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            keep = keep & (col + kj * bk <= row + qi * bq)
+        m, denom, acc = _online_update(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], keep, m_ref[...],
+            d_ref[...], a_ref[...], scale, jnp)
+        m_ref[...] = m
+        d_ref[...] = denom
+        a_ref[...] = acc
+
+    if causal:
+        # a key tile wholly above the diagonal does no work (and is not
+        # fetched: its block index repeats the last one needed)
+        pl.when(kj * bk <= qi * bq + bq - 1)(_update)
+    else:
+        _update()
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _finish():
+        o_ref[0, 0] = a_ref[...] / jnp.maximum(d_ref[...], _DENOM_FLOOR)
+
+
+def _tiled_call(q, k, v, kv_mask, causal: bool, scale):
+    """Flash attention with the queries tiled too: ``[B, H, Tq, D]`` against
+    ``[B, H, Tk, D]`` keys and ``[B, H, Tk, Dv]`` values, any length. The
+    score matrix never exists beyond one ``TILE_Q x TILE_K`` tile in VMEM;
+    under ``causal`` the tiles above the diagonal are skipped."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, tq, d = q.shape
+    tk, dv = k.shape[2], v.shape[3]
+    bq = min(TILE_Q, tq + (-tq % 16))
+    bk = min(TILE_K, tk + (-tk % 128))
+    q, k, v, kv_row = _padded_operands(q, k, v, kv_mask, bq, bk)
+    tq_p, tk_p = q.shape[2], k.shape[2]
+
+    def key_tile(i, j):
+        return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
+
+    kern = functools.partial(_tiled_kernel, scale=np.float32(scale),
+                             causal=causal, bq=bq, bk=bk)
+    out = pl.pallas_call(
+        kern,
+        grid=(b, h, tq_p // bq, tk_p // bk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, i, j: (b_, h_, key_tile(i, j), 0)),
+            pl.BlockSpec((1, 1, bk, dv),
+                         lambda b_, h_, i, j: (b_, h_, key_tile(i, j), 0)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda b_, h_, i, j: (b_, 0, key_tile(i, j))),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bq, dv),
+                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, tq_p, dv), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        name="flash_attention_tiled",
+    )(q, k, v, kv_row.reshape(b, 1, tk_p))
+    return out[:, :, :tq] if tq_p != tq else out
 
 
 def _fits_vmem(tq: int, tk: int, d: int, block_k: int,
@@ -322,9 +439,11 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
     b, h, tq, d = q.shape
     tk = k.shape[2]
     s = _resolve_scale(scale, d)
-    if _takes_kernel(impl, "flash_attention",
-                     _fits_vmem(tq, tk, d, block_k), (tq, tk, d)):
-        return _flash_call(q, k, v, kv_mask, causal, s, block_k)
+    if resolve_impl(impl) == "pallas":
+        if _fits_vmem(tq, tk, d, block_k) and v.shape[3] == d:
+            return _flash_call(q, k, v, kv_mask, causal, s, block_k)
+        # the whole (batch, head) tile outgrows VMEM: tile the queries too
+        return _tiled_call(q, k, v, kv_mask, causal, s)
     return flash_attention_reference(
         q, k, v, _mask3(b, tq, tk, kv_mask, causal), s, block_k)
 

@@ -44,6 +44,18 @@ from typing import Any
 
 import numpy as np
 
+EXPERT_IMPLS = ("auto", "ragged", "gmm")
+
+# the Pallas grouped product's tiles: rows of the sorted pairs, and the
+# elements of a weight tile (the contraction whole up to GMM_WHOLE_K, else
+# half of that). Read on one v5e chip at [32768, 4096] x [192, 4096, 2048]
+# with ~256 rows a group: 256 rows beat 128 and 512 (a row tile is computed
+# whole for every group that touches it), and these weight tiles beat the
+# smaller ones (PERF.md section 6, PR 28)
+GMM_ROWS = 256
+GMM_WEIGHT_TILE = 2 ** 21
+GMM_WHOLE_K = 2048
+
 
 def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int,
                     dtype=None) -> dict:
@@ -245,3 +257,111 @@ def moe_dense(params: dict, x: Any, token_mask: Any = None
 def moe_reference(params: dict, x: Any) -> Any:
     """Back-compat oracle wrapper: just the outputs of :func:`moe_dense`."""
     return moe_dense(params, x)[0]
+
+
+# ---- the dropless top-k layer of one expert-parallel share ----
+
+def route_topk(x: Any, router: Any, top_k: int, norm_topk: bool = True,
+               scaling: float = 1.0) -> tuple[Any, Any]:
+    """``(picks [N, k] int32, weights [N, k] float32)``: softmax over the
+    router's whole width in float32, the ``top_k`` largest, the weights
+    normalised over the picks (``norm_topk``) and times ``scaling``."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return picks.astype(jnp.int32), weights * scaling
+
+
+def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
+    """``lhs [M, K]`` against ``rhs [G, K, N]``: row ``i`` meets the matrix
+    of the group it lies in (groups are consecutive row ranges of
+    ``group_sizes``); accumulated in float32, stored as ``out_dtype``
+    (float32 unless given). Rows past the last group are unspecified: the
+    caller masks them."""
+    import jax
+    import jax.numpy as jnp
+
+    if impl == "auto":
+        impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
+    out_dtype = jnp.float32 if out_dtype is None else out_dtype
+    if impl == "ragged":
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes,
+            preferred_element_type=jnp.float32).astype(out_dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    # the kernel's dynamic grid visits only the tiles that hold rows of a
+    # group, so the work follows the pairs really routed here
+    (m, k), n = lhs.shape, rhs.shape[2]
+    tk = k if k <= GMM_WHOLE_K else GMM_WHOLE_K // 2
+    tiling = (min(GMM_ROWS, m), tk, min(n, GMM_WEIGHT_TILE // tk))
+    return gmm(lhs, rhs, group_sizes, out_dtype, tiling)
+
+
+def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
+                 first_expert: int = 0, norm_topk: bool = True,
+                 scaling: float = 1.0, impl: str = "auto",
+                 layer: Any = None) -> tuple[Any, Any]:
+    """The routed part of a top-k expert layer on the share that holds
+    experts ``[first_expert, first_expert + held)`` of the router's width.
+
+    ``x`` ``[N, d]``; ``router`` ``[d, E]``; ``experts`` holds the held
+    experts' gated-SiLU stacks ``gate``/``up`` ``[held, d, f]`` and ``down``
+    ``[held, f, d]`` — or, with ``layer`` (an index, which may be traced),
+    the stacks of ALL layers with a leading layer axis: the grouped product
+    then reads layer ``layer``'s matrices where they lie (its groups are
+    that layer's; every other layer's are empty), where slicing the layer
+    out first would copy its weights every step, as a scan over stacked
+    weights does for an operand of a kernel. Every token routes over all
+    ``E``; the (token, expert)
+    pairs that land on a held expert are sorted by expert and run through
+    one grouped matrix product per stack; a pick of an absent expert adds
+    nothing here (its chip adds it in the deployment). No capacity: no
+    token is dropped at any load — the sorted buffer has room for every
+    pair, and the grouped product does the work of the held ones only.
+
+    Returns ``(y [N, d] float32, picks [N, k])``: ``y = sum over held picks
+    of w_k E_k(x)``, the weights normalised over all ``top_k`` picks.
+    This is what expert parallelism over ``ep`` asks of one shard; the
+    exchange that would bring other shards' tokens here is not part of it.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    if impl not in EXPERT_IMPLS:
+        raise ValueError(f"unknown expert impl {impl!r}; one of "
+                         f"{EXPERT_IMPLS}")
+    n, d = x.shape
+    held = experts["gate"].shape[-3]
+    picks, weights = route_topk(x, router, top_k, norm_topk, scaling)
+    local = picks - first_expert
+    group = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    # pairs of held experts first, by expert; the absent ones last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32),
+                    axis=0)[:held]
+    if layer is not None:
+        layers = experts["gate"].shape[0]
+        sizes = jnp.zeros((layers, held), jnp.int32).at[layer].set(
+            sizes).reshape(-1)
+        experts = {k: v.reshape((layers * held,) + v.shape[2:])
+                   for k, v in experts.items()}
+    xs = jnp.take(x, order // top_k, axis=0)
+    dtype = x.dtype
+    gate = _grouped_dot(xs, experts["gate"], sizes, impl)
+    up = _grouped_dot(xs, experts["up"], sizes, impl)
+    act = (jax.nn.silu(gate) * up).astype(dtype)
+    ys = _grouped_dot(act, experts["down"], sizes, impl, dtype)
+    live = jnp.arange(n * top_k) < jnp.sum(sizes)
+    ys = jnp.where(live[:, None], ys, jnp.zeros((), dtype))
+    # back to (token, pick) order; an absent pick's row is zero
+    back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+        jnp.arange(n * top_k, dtype=jnp.int32))
+    pairs = jnp.take(ys, back, axis=0).reshape(n, top_k, d)
+    y = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), weights)
+    return y, picks

@@ -1,0 +1,411 @@
+"""The latent-attention expert LM family (``models/lm.py``), its dropless
+expert layer (``parallel/moe.moe_dropless``) and the tiled attention kernel,
+each against the plain reference ``benchmark/reference/mistral4.py`` on
+seeded weights, at tiny sizes on the CPU."""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from flax.traverse_util import unflatten_dict
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference import mistral4 as ref  # noqa: E402
+from mmlspark_tpu.models import lm  # noqa: E402
+from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
+from mmlspark_tpu.parallel import moe  # noqa: E402
+from mmlspark_tpu.parallel.ring_attention import attention_reference  # noqa: E402
+
+ROPE = dict(beta_fast=32, beta_slow=1, factor=128, llama_4_scaling_beta=0.1,
+            mscale=1, mscale_all_dim=1, original_max_position_embeddings=8192,
+            rope_theta=10000, rope_type="yarn", type="yarn")
+
+
+def tiny(**over) -> dict:
+    cfg = dict(
+        family="mistral4", vocab_size=256, hidden_size=64,
+        num_hidden_layers=3, num_attention_heads=4, q_lora_rank=32,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=16, moe_intermediate_size=32, n_routed_experts=16,
+        router_width=16, first_expert=0, num_experts_per_tok=4,
+        n_shared_experts=1, norm_topk_prob=True, routed_scaling_factor=1.0,
+        rms_norm_eps=1e-6, param_dtype="bfloat16", compute_dtype="float32",
+        rope_parameters=dict(ROPE))
+    cfg.update(over)
+    return cfg
+
+
+def program_tree(params: dict) -> dict:
+    """The reference's ``make_params`` in the program's tree: the layers
+    stacked on a leading axis."""
+    flat = dict(params["outer"])
+    for k in params["layers"][0]:
+        # the routed experts' stacks sit beside the scanned blocks
+        path = k[len("moe/"):] if k.startswith("moe/experts/") \
+            else "layers/" + k
+        flat[path] = jnp.stack([p[k] for p in params["layers"]])
+    return unflatten_dict({tuple(k.split("/")): v for k, v in flat.items()})
+
+
+def tokens_of(seed: int, shape, vocab: int = 256) -> np.ndarray:
+    return np.random.default_rng(seed).integers(0, vocab, size=shape)
+
+
+def reference_rows(params, tokens, cfg, **kw) -> dict:
+    rows = [ref.forward(params, jnp.asarray(t), cfg, **kw) for t in tokens]
+    return {k: np.stack([np.asarray(r[k]) for r in rows]) for k in rows[0]}
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = tiny()
+    return cfg, ref.make_params(cfg, jax.random.PRNGKey(7))
+
+
+def apply(cfg, params, tokens, node, **over):
+    module = lm.from_config(cfg, **over)
+    return np.asarray(module.apply({"params": program_tree(params)},
+                                   jnp.asarray(tokens, jnp.float32),
+                                   output=node))
+
+
+# ---- rotary frequencies, interleaving, the position scale ----
+
+def test_yarn_frequencies_at_the_published_sizes():
+    got = lm.yarn_inv_freq(64, ROPE)
+    plain = 10000.0 ** (-np.arange(0, 64, 2) / 64)
+    # rotations 32 and 1 over 8192 positions put the ramp at pairs 12..25
+    np.testing.assert_allclose(got[:13], plain[:13], rtol=1e-12)
+    np.testing.assert_allclose(got[25:], plain[25:] / 128, rtol=1e-12)
+    assert np.all(np.diff(got) < 0)
+    mid = (plain[18] / 128) * (6 / 13) + plain[18] * (7 / 13)
+    assert got[18] == pytest.approx(mid, rel=1e-12)
+    np.testing.assert_allclose(
+        got, ref.yarn_inv_freq(tiny(qk_rope_head_dim=64)), rtol=1e-12)
+
+
+def test_rope_rotates_interleaved_pairs():
+    cfg = tiny()
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 6, 3, 8)),
+                    jnp.float32)
+    pos = jnp.arange(6) + 5
+    cos, sin = lm.rope_tables(pos, 8, ROPE)
+    got = np.asarray(lm.apply_rope_interleaved(x, cos, sin))
+    want = np.asarray(ref.rope_interleaved(x[0], pos, cfg))
+    np.testing.assert_allclose(got[0], want, rtol=1e-6, atol=1e-6)
+    # a rotation of the pairs (0,1), (2,3), ...: each pair keeps its norm,
+    # and pair 0 turns by exactly position x inv_freq_0 = position radians
+    pairs = got.reshape(1, 6, 3, 4, 2)
+    np.testing.assert_allclose(
+        np.linalg.norm(pairs, axis=-1),
+        np.linalg.norm(np.asarray(x).reshape(1, 6, 3, 4, 2), axis=-1),
+        rtol=1e-5)
+    a, b = np.asarray(x)[0, 2, 0, :2]
+    p = 7.0
+    np.testing.assert_allclose(
+        got[0, 2, 0, :2], [a * np.cos(p) - b * np.sin(p),
+                           a * np.sin(p) + b * np.cos(p)], rtol=1e-5)
+
+
+def test_the_query_scale_past_the_original_window(seeded):
+    # original_max_position_embeddings 8: positions 8.. scale the queries
+    # by 1 + 0.1 ln(1 + floor(p / 8)); with it left out the logits differ
+    rope = dict(ROPE, original_max_position_embeddings=8)
+    cfg = tiny(rope_parameters=rope)
+    params = seeded[1]
+    tokens = tokens_of(3, (2, 32))
+    with jax.default_matmul_precision("highest"):
+        got = apply(cfg, params, tokens, "logits", param_dtype=jnp.float32)
+        want = reference_rows(params, tokens, cfg)["logits"]
+        flat = reference_rows(
+            params, tokens, tiny(rope_parameters=dict(
+                rope, llama_4_scaling_beta=0.0)))["logits"]
+    np.testing.assert_allclose(got, want, atol=3e-5)
+    assert np.abs(want[:, :8] - flat[:, :8]).max() < 1e-5
+    assert np.abs(want[:, 8:] - flat[:, 8:]).max() > 1e-3
+
+
+# ---- the blocks ----
+
+def test_latent_attention_block_matches_the_reference(seeded):
+    cfg, params = seeded
+    p = params["layers"][1]
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 24, 64)),
+                    jnp.float32)
+    module = lm.LatentAttention(
+        lm.from_config(cfg, param_dtype=jnp.float32).cfg)
+    tree = unflatten_dict({tuple(k.split("/")[1:]): v for k, v in p.items()
+                           if k.startswith("mla/")})
+    with jax.default_matmul_precision("highest"):
+        got = module.apply({"params": tree}, x, jnp.arange(24))
+        want = jnp.stack([ref.mla(p, row, jnp.arange(24), cfg) for row in x])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+
+
+def moe_parts(p: dict) -> tuple:
+    experts = {k: p[f"moe/experts/{k}"] for k in ("gate", "up", "down")}
+    return p["moe/router/kernel"], experts
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_expert_layer_matches_the_reference(seeded, impl, request):
+    if impl == "gmm":       # the Pallas grouped product, interpreted
+        request.getfixturevalue("pallas_interpret")
+    cfg, params = seeded
+    p = params["layers"][0]
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(40, 64)),
+                    jnp.float32)
+    router, experts = moe_parts(p)
+    with jax.default_matmul_precision("highest"):
+        got, picks = moe.moe_dropless(x, router, experts, top_k=4,
+                                      impl=impl)
+        routed, _, _ = ref.moe(p, x, cfg, parts=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
+                               atol=2e-6)
+    assert picks.shape == (40, 4)
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_a_layer_of_stacked_experts_equals_that_layer_alone(seeded, impl,
+                                                            request):
+    # the model hands moe_dropless every layer's stacks and a layer index
+    # (read in place, no slice copied out): the same as the layer sliced
+    if impl == "gmm":
+        request.getfixturevalue("pallas_interpret")
+    cfg, params = seeded
+    x = jnp.asarray(np.random.default_rng(6).normal(size=(24, 64)),
+                    jnp.float32)
+    router = params["layers"][1]["moe/router/kernel"]
+    stacks = {k: jnp.stack([p[f"moe/experts/{k}"][4:8]
+                            for p in params["layers"]])
+              for k in ("gate", "up", "down")}
+    with jax.default_matmul_precision("highest"):
+        alone, _ = moe.moe_dropless(
+            x, router, {k: v[1] for k, v in stacks.items()}, top_k=4,
+            first_expert=4, impl=impl)
+        stacked, _ = jax.jit(lambda i: moe.moe_dropless(
+            x, router, stacks, top_k=4, first_expert=4, impl=impl,
+            layer=i))(jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(stacked), np.asarray(alone),
+                               atol=1e-6)
+    with pytest.raises(ValueError, match="unknown expert impl"):
+        moe.moe_dropless(x, router, stacks, top_k=4, impl="dense")
+
+
+def test_every_token_on_one_expert_loses_none(seeded):
+    # a router that sends every token to the same four experts: a layer
+    # with a capacity would drop most of them; this one drops none
+    cfg, params = seeded
+    p = dict(params["layers"][0])
+    router = np.zeros((64, 16), np.float32)
+    router[:, [2, 5, 6, 11]] = 4.0
+    x = jnp.abs(jnp.asarray(np.random.default_rng(4).normal(size=(96, 64)),
+                            jnp.float32)) + 0.1
+    p["moe/router/kernel"] = jnp.asarray(router)
+    _, experts = moe_parts(p)
+    with jax.default_matmul_precision("highest"):
+        got, picks = moe.moe_dropless(x, p["moe/router/kernel"], experts,
+                                      top_k=4)
+        routed, _, _ = ref.moe(p, x, cfg, parts=True)
+    assert set(np.asarray(picks).ravel().tolist()) == {2, 5, 6, 11}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
+                               atol=2e-6)
+    assert np.abs(np.asarray(got)).min(axis=1).max() > 0     # none is zero
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(seeded):
+    """The routed parts that the four shares give, with attention, the
+    router and the shared expert counted once, equal the uncut reference
+    layer; a share's program and its reference agree part by part."""
+    cfg, params = seeded
+    p = params["layers"][2]
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(48, 64)),
+                    jnp.float32)
+    pos = jnp.arange(48)
+    with jax.default_matmul_precision("highest"):
+        whole, _ = ref.layer(p, x, pos, cfg)
+        h = x + ref.mla(p, ref.rms_norm(x, p["input_norm/scale"], 1e-6),
+                        pos, cfg)
+        hn = ref.rms_norm(h, p["post_norm/scale"], 1e-6)
+        total = jnp.zeros_like(x)
+        for first in (0, 4, 8, 12):
+            share = {k: (v[first:first + 4] if k.startswith("moe/experts/")
+                         else v) for k, v in p.items()}
+            share_cfg = tiny(n_routed_experts=4, first_expert=first)
+            router, experts = moe_parts(share)
+            got, _ = moe.moe_dropless(hn, router, experts, top_k=4,
+                                      first_expert=first)
+            want, shared, _ = ref.moe(share, hn, share_cfg, parts=True)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=2e-6)
+            total = total + got
+    np.testing.assert_allclose(np.asarray(h + total + shared),
+                               np.asarray(whole), atol=5e-6)
+
+
+# ---- the whole model ----
+
+def test_logits_in_float32_match_the_reference_tightly(seeded):
+    cfg, params = seeded
+    tokens = tokens_of(11, (3, 32))
+    with jax.default_matmul_precision("highest"):
+        got = apply(cfg, params, tokens, "logits", param_dtype=jnp.float32)
+        want = reference_rows(params, tokens, cfg)
+    # float32 on both sides: what is left is the order of the sums
+    np.testing.assert_allclose(got, want["logits"], atol=3e-5)
+    feats = apply(cfg, params, tokens, "features", param_dtype=jnp.float32)
+    np.testing.assert_allclose(feats, want["features"], atol=1e-5)
+
+
+def test_logits_in_bfloat16_match_on_cleanly_routed_tokens(seeded):
+    cfg, params = seeded
+    tokens = tokens_of(12, (4, 32))
+    want = reference_rows(params, tokens, cfg)
+    got = apply(cfg, params, tokens, "logits", dtype=jnp.bfloat16)
+    # bf16 operands (8 bits of mantissa) through 3 layers: under 1 % of the
+    # logits' scale in the rms (read 0.4-0.9 % over seeds). A token whose
+    # 4th and 5th router probabilities are nearer than rounding may pick
+    # another expert, rightly (read: up to 21 % of the scale there), so the
+    # worst logit is judged on tokens whose margin is at least 3e-3; those
+    # still see a wrongly-picking neighbour through attention, which at 32
+    # keys is a large share of a key (read 1.9-6.3 %), hence 10 %
+    scale = np.abs(want["logits"]).max()
+    err = np.abs(got - want["logits"])
+    assert np.sqrt(np.mean(err ** 2)) < 0.02 * scale
+    clean = want["margin"] >= 3e-3
+    assert clean.mean() > 0.3
+    assert err[clean].max() < 0.1 * scale
+
+
+def test_token_logprob_chunked_equals_unchunked(seeded):
+    cfg, params = seeded
+    tokens = tokens_of(13, (2, 32))
+    with jax.default_matmul_precision("highest"):
+        whole = apply(cfg, params, tokens, "token_logprob",
+                      param_dtype=jnp.float32, logprob_chunk=32)
+        chunked = apply(cfg, params, tokens, "token_logprob",
+                        param_dtype=jnp.float32, logprob_chunk=8)
+        want = reference_rows(params, tokens, cfg)["token_logprob"]
+    np.testing.assert_allclose(chunked, whole, atol=1e-5)
+    np.testing.assert_allclose(whole, want, atol=3e-5)
+    assert (whole[:, 0] == 0).all() and (whole[:, 1:] < 0).all()
+
+
+def test_expert_load_counts_the_picks_on_held_experts(seeded):
+    cfg, params = seeded
+    share_cfg = tiny(n_routed_experts=4, first_expert=8)
+    share = {"outer": params["outer"], "layers": [
+        {k: (v[8:12] if k.startswith("moe/experts/") else v)
+         for k, v in p.items()} for p in params["layers"]]}
+    tokens = tokens_of(14, (3, 32))
+    with jax.default_matmul_precision("highest"):
+        load = apply(share_cfg, share, tokens, "expert_load",
+                     param_dtype=jnp.float32).reshape(3, 3, 4)
+        # the reference's picks, layer by layer, on the same partial states
+        want = np.zeros((3, 3, 4))
+        for r, row in enumerate(tokens):
+            x = share["outer"]["embed/embedding"][row]
+            for i, p in enumerate(share["layers"]):
+                h = x + ref.mla(p, ref.rms_norm(x, p["input_norm/scale"],
+                                                1e-6), jnp.arange(32),
+                                share_cfg)
+                picks, _, _ = ref.route(
+                    p, ref.rms_norm(h, p["post_norm/scale"], 1e-6),
+                    share_cfg, lambda a: a)
+                for e in range(4):
+                    want[r, i, e] = int((np.asarray(picks) == 8 + e).sum())
+                x, _ = ref.layer(p, x, jnp.arange(32), share_cfg)
+    np.testing.assert_array_equal(load, want)
+    from mmlspark_tpu.obs.metrics import registry
+    before = registry().value("moe.held_pairs") or 0
+    out = lm.publish_expert_load(load.sum(axis=0), 3 * 32 * 3)
+    assert out["moe.held_pairs"] == int(want.sum())
+    assert out["moe.expert_load_max"] == int(want.sum(axis=0).max())
+    assert registry().value("moe.held_pairs") == before + want.sum()
+    assert registry().value("moe.expert_load_max") == want.sum(axis=0).max()
+
+
+def test_a_token_table_through_transform_equals_the_module(seeded):
+    from mmlspark_tpu.data.table import DataTable
+    from mmlspark_tpu.models.bundle import ModelBundle
+    from mmlspark_tpu.models.jax_model import JaxModel
+
+    cfg, params = seeded
+    module = lm.from_config(cfg, dtype=jnp.bfloat16, logprob_chunk=8)
+    tree = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16) if a.ndim >= 2 else a,
+        program_tree(params))
+    bundle = ModelBundle(module=module, params=tree, input_spec=(32,),
+                         output_names=module.OUTPUT_NAMES, name="tiny_lm")
+    tokens = tokens_of(15, (7, 32)).astype(np.int32)
+    table = DataTable({"tokens": tokens})
+    out = JaxModel(model=bundle, input_col="tokens", output_col="lp",
+                   minibatch_size=4, output_node="token_logprob",
+                   mesh_spec={"dp": 1}).transform(table)
+    direct = np.asarray(module.apply({"params": tree},
+                                     jnp.asarray(tokens, jnp.float32),
+                                     output="token_logprob"))
+    got = np.stack(list(out["lp"]))
+    assert got.shape == (7, 32) and got.dtype == np.float32
+    # the same program at another batch size: sums in another order
+    np.testing.assert_allclose(got, direct, atol=2e-2)
+    with pytest.raises(ValueError, match="unknown output node"):
+        bundle.resolve_output("expert_mass")
+
+
+# ---- the tiled attention kernel ----
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("causal", [True, False])
+def test_tiled_attention_past_the_old_vmem_bound(causal):
+    r = np.random.default_rng(21)
+    b, h, t, d = 1, 2, 2100, 16
+    assert not fa._fits_vmem(t, t, d, fa.DEFAULT_BLOCK_K)
+    q, k, v = (jnp.asarray(r.normal(size=(b, h, t, d)), jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(r.random((b, t)) > 0.1)
+    got = fa.flash_attention(q, k, v, kv_mask=mask, causal=causal,
+                             impl="pallas")
+    want = attention_reference(q.transpose(0, 2, 1, 3),
+                               k.transpose(0, 2, 1, 3),
+                               v.transpose(0, 2, 1, 3), causal=causal,
+                               kv_mask=mask).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_a_tile_that_fits_keeps_the_whole_tile_kernel(monkeypatch):
+    # flash_attention's behaviour at the sizes that fit must not change
+    def refuse(*a, **k):
+        raise AssertionError("the tiled kernel ran at a size that fits")
+    monkeypatch.setattr(fa, "_tiled_call", refuse)
+    r = np.random.default_rng(22)
+    q, k, v = (jnp.asarray(r.normal(size=(1, 2, 48, 8)), jnp.float32)
+               for _ in range(3))
+    got = fa.flash_attention(q, k, v, causal=True, impl="pallas")
+    want = fa.flash_attention(q, k, v, causal=True, impl="xla")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_tiled_attention_takes_bf16_operands_and_wider_values():
+    r = np.random.default_rng(23)
+    q = jnp.asarray(r.normal(size=(1, 1, 2048, 32)), jnp.bfloat16)
+    k = jnp.asarray(r.normal(size=(1, 1, 2048, 32)), jnp.bfloat16)
+    v = jnp.asarray(r.normal(size=(1, 1, 2048, 16)), jnp.bfloat16)
+    got = fa.flash_attention(q, k, v, causal=True, impl="pallas")
+    want = fa.flash_attention(q, k, v, causal=True, impl="xla")
+    assert got.shape == (1, 1, 2048, 16) and got.dtype == jnp.float32
+    # the weights are rounded to bf16 for the p.v product: 2^-9 relative
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-2)

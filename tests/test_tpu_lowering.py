@@ -36,6 +36,7 @@ import chip_smoke  # noqa: E402 — the shapes under test are the smoke's own
 
 from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
+from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
 
 S = jax.ShapeDtypeStruct
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -66,6 +67,21 @@ KERNEL_CASES = {
             q, k, v, keep, m, d, a, 0.125, impl="pallas"),
         (S((2, 8, 512, 64), F32),) * 3 + (S((2, 512, 512), jnp.bool_),)
         + (S((2, 8, 512, 1), F32),) * 2 + (S((2, 8, 512, 64), F32),)),
+    # the language-model cell's window: past the whole-tile VMEM bound, so
+    # the tiled kernel (queries tiled too, causal tiles skipped)
+    "flash_attention_tiled_lm_t4096": (
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           impl="pallas"),
+        (S((2, 32, 4096, 128), BF16),) * 3),
+    # the dropless expert layer's grouped products, reading one layer of
+    # the stacked expert weights in place
+    "moe_dropless_grouped_products": (
+        lambda x, r, g, u, d: moe_dropless(
+            x, r, {"gate": g, "up": u, "down": d}, top_k=4, impl="gmm",
+            layer=1)[0],
+        (S((8192, 4096), BF16), S((4096, 128), F32),
+         S((2, 32, 4096, 2048), BF16), S((2, 32, 4096, 2048), BF16),
+         S((2, 32, 2048, 4096), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
