@@ -30,6 +30,9 @@ them — exact below 2^24 — or any integer type). Output nodes:
 * ``features``: the final-norm hidden state averaged over the row;
 * ``expert_load``: ``[B, layers * held]``, the row's picks that landed on
   each held expert of each layer (what :func:`publish_expert_load` sums);
+* ``moe_bucket``: ``[B, layers]`` int32, the rung of the expert layer's
+  row-count ladder that the row's step took in each layer (0 = the
+  smallest; what :func:`publish_bucket_steps` counts);
 * ``token_logprob``: ``[B, L]`` float32, ``out[0] = 0``, ``out[t] = log
   softmax(logits[t-1])[token[t]]`` over the held vocabulary, computed in
   sequence chunks so that ``[L, V]`` never exists whole;
@@ -295,7 +298,7 @@ class MoE(nn.Module):
         flat = x.reshape(b * n, d).astype(c.dtype)
         router = Kernel((d, c.routed_width), jnp.float32, name="router")()
         with jax.named_scope("lm/moe/experts"):
-            routed, picks = moe_dropless(
+            routed, picks, bucket = moe_dropless(
                 flat, router, experts, top_k=c.num_experts_per_tok,
                 first_expert=c.first_expert, norm_topk=c.norm_topk_prob,
                 scaling=c.routed_scaling_factor, layer=layer)
@@ -303,13 +306,17 @@ class MoE(nn.Module):
             local = picks.reshape(b, -1) - c.first_expert
             load = jnp.sum(jax.nn.one_hot(local, c.n_routed_experts,
                                           dtype=jnp.int32), axis=1)
-        y = routed.reshape(b, n, d)
+        # the shared expert on the flat tokens too: a reshape between the
+        # routed part and this sum is moved into the expert layer's branches
+        # by the compiler, where it keeps the combine's float32 converts
+        # out of its fusion (four [N, d] float32 buffers a step)
+        y = routed
         if c.n_shared_experts:
             with jax.named_scope("lm/moe/shared"):
                 y = y + SharedExpert(
                     c.n_shared_experts * c.moe_intermediate_size, c.dtype,
-                    c.param_dtype, name="shared")(x)
-        return y, load
+                    c.param_dtype, name="shared")(flat)
+        return y.reshape(b, n, d), load, bucket
 
 
 class Block(nn.Module):
@@ -322,10 +329,10 @@ class Block(nn.Module):
             h = x.astype(jnp.float32) + LatentAttention(c, name="mla")(
                 RMSNorm(c.rms_norm_eps, name="input_norm")(x), positions)
         h = h.astype(c.dtype)
-        y, load = MoE(c, name="moe")(
+        y, load, bucket = MoE(c, name="moe")(
             RMSNorm(c.rms_norm_eps, name="post_norm")(h), layer, experts)
         self.sow("intermediates", "moe_load", load)
-        return (h.astype(jnp.float32) + y).astype(c.dtype), load
+        return (h.astype(jnp.float32) + y).astype(c.dtype), (load, bucket)
 
 
 class LatentMoELM(nn.Module):
@@ -333,7 +340,8 @@ class LatentMoELM(nn.Module):
 
     cfg: LMConfig
 
-    OUTPUT_NAMES = ("features", "expert_load", "token_logprob", "logits")
+    OUTPUT_NAMES = ("features", "expert_load", "moe_bucket", "token_logprob",
+                    "logits")
 
     @nn.compact
     def __call__(self, x, output: str = "logits"):
@@ -356,11 +364,14 @@ class LatentMoELM(nn.Module):
             split_rngs={"params": True},
             in_axes=(nn.broadcast, 0, nn.broadcast),
             length=c.num_hidden_layers)
-        h, load = layers(c, name="layers")(
+        h, (load, bucket) = layers(c, name="layers")(
             h, jnp.arange(n), jnp.arange(c.num_hidden_layers), experts)
         if output == "expert_load":
             # [layers, B, held] -> a row's picks on each held expert
             return load.transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
+        if output == "moe_bucket":
+            # [layers] -> the step's rung on each of its rows
+            return jnp.broadcast_to(bucket[None, :], (b, bucket.shape[0]))
         h = RMSNorm(c.rms_norm_eps, name="final_norm")(h)
         if output == "features":
             return jnp.mean(h, axis=1)
@@ -428,4 +439,20 @@ def publish_expert_load(load, tokens: int) -> dict:
     reg.counter("moe.tokens").add(out["moe.tokens"])
     reg.counter("moe.held_pairs").add(out["moe.held_pairs"])
     reg.gauge("moe.expert_load_max").set(out["moe.expert_load_max"])
+    return out
+
+
+def publish_bucket_steps(bucket, rows_per_step: int) -> dict:
+    """Publish which rungs the expert layer's steps took — ``bucket``
+    ``[rows, layers]``, the ``moe_bucket`` node's column over a table
+    scored ``rows_per_step`` rows a step (every row of a step carries the
+    step's rung) — as the counters ``moe.bucket_steps`` (layer-steps run)
+    and ``moe.bucket_steps_first`` (those that took the smallest rung) of
+    ``obs.registry()``; returns the same two numbers."""
+    steps = np.asarray(bucket)[::rows_per_step]
+    out = {"moe.bucket_steps": int(steps.size),
+           "moe.bucket_steps_first": int((steps == 0).sum())}
+    reg = _obs_registry()
+    for name, value in out.items():
+        reg.counter(name).add(value)
     return out

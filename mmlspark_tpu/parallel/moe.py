@@ -40,6 +40,7 @@ count exchange this layout performs.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import numpy as np
@@ -303,10 +304,37 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
     return gmm(lhs, rhs, group_sizes, out_dtype, tiling)
 
 
+def bucket_ladder(pairs: int, held: int, width: int) -> tuple[int, ...]:
+    """The static row counts :func:`moe_dropless` may give its sorted
+    buffer, from shapes alone: with ``pairs = tokens * top_k`` and ``held``
+    of the router's ``width`` experts here, about 1.25x and 2x the
+    expected ``pairs * held / width`` and ``pairs`` itself, each rounded up
+    to whole row tiles (``GMM_ROWS``) and clipped to ``pairs``, duplicates
+    dropped. Increasing; the last rung is ``pairs``, so every load fits
+    one; a single rung when every expert is held or ``pairs`` is under a
+    row tile."""
+    def rung(num: int, den: int) -> int:
+        tiles = -(-num // (den * GMM_ROWS))
+        return min(pairs, tiles * GMM_ROWS)
+    return tuple(sorted({rung(5 * pairs * held, 4 * width),
+                         rung(2 * pairs * held, width), pairs}))
+
+
+def _piece(rows: int, most: int) -> int:
+    """The rows of the largest equal whole-tile pieces of a ``rows``-row
+    buffer that are at most ``most`` rows each (``rows`` itself where it
+    is no whole number of row tiles)."""
+    tiles, rest = divmod(rows, GMM_ROWS)
+    if rest or rows <= most:
+        return rows
+    return next(rows // k for k in range(2, tiles + 1)
+                if tiles % k == 0 and rows // k <= most)
+
+
 def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                  first_expert: int = 0, norm_topk: bool = True,
                  scaling: float = 1.0, impl: str = "auto",
-                 layer: Any = None) -> tuple[Any, Any]:
+                 layer: Any = None) -> tuple[Any, Any, Any]:
     """The routed part of a top-k expert layer on the share that holds
     experts ``[first_expert, first_expert + held)`` of the router's width.
 
@@ -319,14 +347,27 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     out first would copy its weights every step, as a scan over stacked
     weights does for an operand of a kernel. Every token routes over all
     ``E``; the (token, expert)
-    pairs that land on a held expert are sorted by expert and run through
-    one grouped matrix product per stack; a pick of an absent expert adds
-    nothing here (its chip adds it in the deployment). No capacity: no
-    token is dropped at any load — the sorted buffer has room for every
-    pair, and the grouped product does the work of the held ones only.
+    pairs that land on a held expert are sorted by expert (the absent ones
+    last) and run through one grouped matrix product per stack; a pick of an
+    absent expert adds nothing here (its chip adds it in the deployment).
 
-    Returns ``(y [N, d] float32, picks [N, k])``: ``y = sum over held picks
-    of w_k E_k(x)``, the weights normalised over all ``top_k`` picks.
+    The sorted buffer, and everything between the sort and the combine, has
+    the row count of a **rung** of :func:`bucket_ladder`: the first that
+    holds this call's count of held pairs, chosen on the device
+    (``lax.switch``, one branch per rung). The rows of a rung are a prefix
+    of the sorted order, so they hold every held pair; each token then
+    gathers its held picks' rows, pick by pick, and adds them weighted in
+    float32 (an absent pick is selected away; the rows past the count are
+    never read). No capacity: a call whose count passes a rung takes the
+    next, and the last rung has room for every pair, so no token is dropped
+    at any load; a rung above the first is worked in equal pieces no larger
+    than the first, so the rare larger buffers need no more scratch than
+    the usual one. With one rung (every expert held, or fewer pairs than a
+    row tile) the program has no conditional.
+
+    Returns ``(y [N, d] float32, picks [N, k], bucket () int32)``: ``y =
+    sum over held picks of w_k E_k(x)``, the weights normalised over all
+    ``top_k`` picks; ``bucket`` is the index of the rung taken.
     This is what expert parallelism over ``ep`` asks of one shard; the
     exchange that would bring other shards' tokens here is not part of it.
     """
@@ -345,23 +386,56 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32),
                     axis=0)[:held]
+    count = jnp.sum(sizes)
     if layer is not None:
         layers = experts["gate"].shape[0]
         sizes = jnp.zeros((layers, held), jnp.int32).at[layer].set(
             sizes).reshape(-1)
         experts = {k: v.reshape((layers * held,) + v.shape[2:])
                    for k, v in experts.items()}
-    xs = jnp.take(x, order // top_k, axis=0)
     dtype = x.dtype
-    gate = _grouped_dot(xs, experts["gate"], sizes, impl)
-    up = _grouped_dot(xs, experts["up"], sizes, impl)
-    act = (jax.nn.silu(gate) * up).astype(dtype)
-    ys = _grouped_dot(act, experts["down"], sizes, impl, dtype)
-    live = jnp.arange(n * top_k) < jnp.sum(sizes)
-    ys = jnp.where(live[:, None], ys, jnp.zeros((), dtype))
-    # back to (token, pick) order; an absent pick's row is zero
-    back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
-        jnp.arange(n * top_k, dtype=jnp.int32))
-    pairs = jnp.take(ys, back, axis=0).reshape(n, top_k, d)
-    y = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), weights)
-    return y, picks
+    # the sorted row of each (token, pick); an absent pick reads row 0 and
+    # is selected away
+    present = (group < held).reshape(n, top_k)
+    back = jnp.where(present, jnp.argsort(order).reshape(n, top_k), 0)
+    ends = jnp.cumsum(sizes)
+
+    def products(lo, rows: int):
+        """The expert outputs of the sorted pairs ``[lo, lo + rows)``, whose
+        groups are the parts of the sorted groups that lie in that range."""
+        part = (jnp.clip(ends, lo, lo + rows)
+                - jnp.clip(ends - sizes, lo, lo + rows))
+        pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        xs = jnp.take(x, pair // top_k, axis=0, mode="clip")
+        gate = _grouped_dot(xs, experts["gate"], part, impl)
+        up = _grouped_dot(xs, experts["up"], part, impl)
+        act = (jax.nn.silu(gate) * up).astype(dtype)
+        return _grouped_dot(act, experts["down"], part, impl, dtype)
+
+    def on_rows(rows: int, piece: int):
+        """The layer over the first ``rows`` sorted pairs, ``piece`` rows
+        at a time."""
+        if piece == rows:
+            ys = products(0, rows)
+        else:
+            ys = jax.lax.map(lambda lo: products(lo, piece),
+                             jnp.arange(0, rows, piece)).reshape(rows, d)
+        # a held pair's row lies under the count, so no row past it
+        # (unspecified) is read
+        y = jnp.zeros((n, d), jnp.float32)
+        for j in range(top_k):
+            row = jnp.take(ys, back[:, j], axis=0, mode="clip")
+            y = y + jnp.where(present[:, j, None],
+                              row.astype(jnp.float32) * weights[:, j, None],
+                              0.0)
+        return y
+
+    ladder = bucket_ladder(n * top_k, held, router.shape[1])
+    # a rung above the first runs in pieces no larger than the first, so the
+    # rare larger buffers need no more scratch than the usual one
+    branches = [functools.partial(on_rows, rows, _piece(rows, ladder[0]))
+                for rows in ladder]
+    if len(ladder) == 1:
+        return branches[0](), picks, jnp.zeros((), jnp.int32)
+    bucket = jnp.sum(count > jnp.asarray(ladder[:-1])).astype(jnp.int32)
+    return jax.lax.switch(bucket, branches), picks, bucket

@@ -165,8 +165,8 @@ def test_expert_layer_matches_the_reference(seeded, impl, request):
                     jnp.float32)
     router, experts = moe_parts(p)
     with jax.default_matmul_precision("highest"):
-        got, picks = moe.moe_dropless(x, router, experts, top_k=4,
-                                      impl=impl)
+        got, picks, _ = moe.moe_dropless(x, router, experts, top_k=4,
+                                         impl=impl)
         routed, _, _ = ref.moe(p, x, cfg, parts=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
                                atol=2e-6)
@@ -188,10 +188,10 @@ def test_a_layer_of_stacked_experts_equals_that_layer_alone(seeded, impl,
                             for p in params["layers"]])
               for k in ("gate", "up", "down")}
     with jax.default_matmul_precision("highest"):
-        alone, _ = moe.moe_dropless(
+        alone, _, _ = moe.moe_dropless(
             x, router, {k: v[1] for k, v in stacks.items()}, top_k=4,
             first_expert=4, impl=impl)
-        stacked, _ = jax.jit(lambda i: moe.moe_dropless(
+        stacked, _, _ = jax.jit(lambda i: moe.moe_dropless(
             x, router, stacks, top_k=4, first_expert=4, impl=impl,
             layer=i))(jnp.int32(1))
     np.testing.assert_allclose(np.asarray(stacked), np.asarray(alone),
@@ -212,13 +212,151 @@ def test_every_token_on_one_expert_loses_none(seeded):
     p["moe/router/kernel"] = jnp.asarray(router)
     _, experts = moe_parts(p)
     with jax.default_matmul_precision("highest"):
-        got, picks = moe.moe_dropless(x, p["moe/router/kernel"], experts,
-                                      top_k=4)
+        got, picks, _ = moe.moe_dropless(x, p["moe/router/kernel"],
+                                         experts, top_k=4)
         routed, _, _ = ref.moe(p, x, cfg, parts=True)
     assert set(np.asarray(picks).ravel().tolist()) == {2, 5, 6, 11}
     np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
                                atol=2e-6)
     assert np.abs(np.asarray(got)).min(axis=1).max() > 0     # none is zero
+
+
+# the row-count ladder: 512 tokens x 4 picks on 4 of 16 experts expect 512
+# held pairs, so the rungs are 768, 1024 and all 2048
+LADDER_FIRST = 4
+ROUTERS = {
+    # the seeded router: ~512 held pairs, the first rung
+    "balanced": (0, {}),
+    # every token picks two held experts and never the other two: 1024
+    # held pairs, the middle rung filled to its last row
+    "skewed": (1, {4: 4.0, 5: 4.0, 6: -4.0, 7: -4.0}),
+    # every pick of every token is a held expert: 2048 = N k, the last rung
+    "all_held": (2, {4: 4.0, 5: 4.0, 6: 4.0, 7: 4.0}),
+}
+
+
+def held_share(layer: dict, first: int, count: int = 4) -> dict:
+    """A layer's weights with only experts ``[first, first + count)``."""
+    return {k: (v[first:first + count] if k.startswith("moe/experts/")
+                else v) for k, v in layer.items()}
+
+
+def ladder_case(seeded, name):
+    """``(p, cfg, x, router, experts)`` of the share that holds experts
+    4..7 of layer 0, under the named router; where it has constant columns
+    the tokens are positive, so that such a column decides its expert for
+    every token."""
+    _, params = seeded
+    p = held_share(params["layers"][0], LADDER_FIRST)
+    router = np.array(p["moe/router/kernel"], np.float32)
+    for column, value in ROUTERS[name][1].items():
+        router[:, column] = value
+    p["moe/router/kernel"] = jnp.asarray(router)
+    x = jnp.asarray(np.random.default_rng(8).normal(size=(512, 64)),
+                    jnp.float32)
+    if ROUTERS[name][1]:
+        x = jnp.abs(x) + 0.1
+    cfg = tiny(n_routed_experts=4, first_expert=LADDER_FIRST)
+    return (p, cfg, x) + moe_parts(p)
+
+
+@pytest.mark.parametrize("name", sorted(ROUTERS))
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_each_rung_of_the_ladder_matches_the_reference(seeded, impl, name,
+                                                       request):
+    if impl == "gmm":
+        request.getfixturevalue("pallas_interpret")
+    p, cfg, x, router, experts = ladder_case(seeded, name)
+    assert moe.bucket_ladder(512 * 4, 4, 16) == (768, 1024, 2048)
+    with jax.default_matmul_precision("highest"):
+        got, picks, bucket = jax.jit(lambda a: moe.moe_dropless(
+            a, router, experts, top_k=4, first_expert=LADDER_FIRST,
+            impl=impl))(x)
+        routed, _, _ = ref.moe(p, x, cfg, parts=True)
+    held = int(((np.asarray(picks) >= 4) & (np.asarray(picks) < 8)).sum())
+    want_bucket = ROUTERS[name][0]
+    assert int(bucket) == want_bucket
+    assert (768, 1024, 2048)[want_bucket] >= held
+    assert want_bucket == 0 or held > (768, 1024)[want_bucket - 1]
+    # no pair is left out at any load: the later rungs answer like the first
+    np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("pairs,held,width,want", [
+    (8192 * 4, 32, 128, (10240, 16384, 32768)),   # the benchmark's cell
+    (512 * 4, 4, 16, (768, 1024, 2048)),
+    (8192 * 4, 8, 128, (2560, 4096, 32768)),      # 1/16 of the experts
+    (1000, 4, 16, (512, 1000)),     # both small rungs round to two tiles
+    (40 * 4, 4, 16, (160,)),        # under a row tile: today's one buffer
+    (8192 * 4, 128, 128, (32768,)),               # every expert held
+    (8192 * 4, 96, 128, (30720, 32768)),          # 2x the share: clipped
+])
+def test_the_ladder_comes_from_the_shapes(pairs, held, width, want):
+    ladder = moe.bucket_ladder(pairs, held, width)
+    assert ladder == want
+    assert ladder[-1] == pairs and list(ladder) == sorted(set(ladder))
+    assert all(r % moe.GMM_ROWS == 0 for r in ladder[:-1])
+    assert len(ladder) <= 3
+    if len(ladder) > 1:             # room over the expected load
+        assert ladder[0] >= 1.25 * pairs * held / width
+    # a larger rung is worked in equal whole-tile pieces within the first
+    for rows in ladder:
+        piece = moe._piece(rows, ladder[0])
+        assert rows % piece == 0
+        assert piece == rows or (piece <= ladder[0]
+                                 and piece % moe.GMM_ROWS == 0)
+    assert moe._piece(ladder[0], ladder[0]) == ladder[0]
+
+
+def test_a_single_rung_leaves_no_conditional_in_the_program(seeded):
+    _, params = seeded
+    router, experts = moe_parts(params["layers"][0])
+
+    def text(n, first, count):
+        held = {k: v[first:first + count] for k, v in experts.items()}
+        return jax.jit(lambda a: moe.moe_dropless(
+            a, router, held, top_k=4, first_expert=first,
+            impl="ragged")).lower(
+                jax.ShapeDtypeStruct((n, 64), jnp.float32)).as_text()
+    assert "case" not in text(512, 0, 16)       # every expert held
+    assert "case" not in text(40, 4, 4)         # pairs under a row tile
+    assert "case" in text(512, 4, 4)
+
+
+def test_the_bucket_node_and_its_counters(seeded):
+    from mmlspark_tpu.obs.metrics import registry
+
+    cfg, params = seeded
+    # 4 of 16 experts held; 64 tokens x 4 picks a row, 8 rows a step: 2048
+    # pairs a step, the ladder of the cases above
+    share_cfg = tiny(n_routed_experts=4, first_expert=LADDER_FIRST)
+    share = {"outer": params["outer"], "layers": [
+        held_share(p, LADDER_FIRST) for p in params["layers"]]}
+    # layer 1 sends every pick to a held expert: its steps take the last rung
+    router = np.array(share["layers"][1]["moe/router/kernel"], np.float32)
+    router[:, 4:8] = 50.0 * np.abs(router[:, 4:8]).max()
+    share["layers"][1] = dict(share["layers"][1],
+                              **{"moe/router/kernel": jnp.asarray(router)})
+    tokens = tokens_of(16, (8, 64))
+    bucket = apply(share_cfg, share, tokens, "moe_bucket")
+    assert bucket.shape == (8, 3) and bucket.dtype == np.int32
+    assert (bucket == bucket[0]).all()          # one step: one rung a layer
+    load = apply(share_cfg, share, tokens, "expert_load").reshape(8, 3, 4)
+    count = load.sum(axis=(0, 2))               # held pairs a layer-step
+    ladder = np.array(moe.bucket_ladder(8 * 64 * 4, 4, 16))
+    np.testing.assert_array_equal(
+        bucket[0], [int(np.argmax(ladder >= c)) for c in count])
+    assert bucket[0, 0] == 0
+    before = {k: registry().value(k) or 0
+              for k in ("moe.bucket_steps", "moe.bucket_steps_first")}
+    # scored two steps of 4 rows: every row of a step carries its rung
+    out = lm.publish_bucket_steps(bucket, rows_per_step=4)
+    assert out == {"moe.bucket_steps": 6,
+                   "moe.bucket_steps_first": 2 * int((bucket[0] == 0).sum())}
+    assert out["moe.bucket_steps_first"] < out["moe.bucket_steps"]
+    for k, v in out.items():
+        assert registry().value(k) == before[k] + v
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(seeded):
@@ -241,8 +379,8 @@ def test_the_four_shares_add_up_to_the_uncut_layer(seeded):
                          else v) for k, v in p.items()}
             share_cfg = tiny(n_routed_experts=4, first_expert=first)
             router, experts = moe_parts(share)
-            got, _ = moe.moe_dropless(hn, router, experts, top_k=4,
-                                      first_expert=first)
+            got, _, _ = moe.moe_dropless(hn, router, experts, top_k=4,
+                                         first_expert=first)
             want, shared, _ = ref.moe(share, hn, share_cfg, parts=True)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=2e-6)
@@ -359,6 +497,49 @@ def test_a_token_table_through_transform_equals_the_module(seeded):
     np.testing.assert_allclose(got, direct, atol=2e-2)
     with pytest.raises(ValueError, match="unknown output node"):
         bundle.resolve_output("expert_mass")
+
+
+def test_a_padded_tail_step_takes_a_later_rung_and_answers_alike(seeded):
+    """A short ``transform`` call pads its tail step with rows of id 0:
+    every token of such a row routes alike, so the step's held pairs pass
+    the first rung. No pair is left out: the tail row answers as it does
+    alone (one row is under a row tile: the plain program)."""
+    from mmlspark_tpu.data.table import DataTable
+    from mmlspark_tpu.models.bundle import ModelBundle
+    from mmlspark_tpu.models.jax_model import JaxModel
+
+    _, params = seeded
+    first = 12
+    cfg = tiny(n_routed_experts=4, first_expert=first)
+    share = {"outer": params["outer"],
+             "layers": [held_share(p, first) for p in params["layers"]]}
+    module = lm.from_config(cfg, param_dtype=jnp.float32)
+    tree = program_tree(share)
+    bundle = ModelBundle(module=module, params=tree, input_spec=(64,),
+                         output_names=module.OUTPUT_NAMES, name="tiny_lm")
+    tokens = tokens_of(17, (9, 64)).astype(np.int32)   # 8 + a tail of 1
+
+    def column(node):
+        out = JaxModel(model=bundle, input_col="tokens", output_col="out",
+                       minibatch_size=8, output_node=node,
+                       mesh_spec={"dp": 1}).transform(
+                           DataTable({"tokens": tokens}))
+        return np.stack(list(out["out"]))
+
+    with jax.default_matmul_precision("highest"):
+        bucket = column("moe_bucket")
+        got = column("token_logprob")
+        alone = np.asarray(module.apply(
+            {"params": tree}, jnp.asarray(tokens[8:], jnp.float32),
+            output="token_logprob"))
+        whole = np.asarray(module.apply(
+            {"params": tree}, jnp.asarray(tokens[:8], jnp.float32),
+            output="token_logprob"))
+    assert moe.bucket_ladder(8 * 64 * 4, 4, 16) == (768, 1024, 2048)
+    assert (bucket[:8] == 0).all()              # the full step: first rung
+    assert bucket[8].max() > 0                  # the padded one: a later one
+    np.testing.assert_allclose(got[8:], alone, atol=3e-5)
+    np.testing.assert_allclose(got[:8], whole, atol=3e-5)
 
 
 # ---- the tiled attention kernel ----

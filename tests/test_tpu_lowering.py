@@ -49,6 +49,15 @@ def lower_for_tpu(fn, *specs) -> str:
 
 # ---- every kernel, at the smoke's shapes ----
 
+def _moe_layers(x, router, gate, up, down):
+    def layer(h, i):
+        y, _, bucket = moe_dropless(
+            h, router, {"gate": gate, "up": up, "down": down}, top_k=4,
+            impl="gmm", layer=i)
+        return (h + y).astype(h.dtype), bucket
+    return jax.lax.scan(layer, x, jnp.arange(gate.shape[0]))
+
+
 KERNEL_CASES = {
     "decode_attention": (
         lambda q, k, v, m: fa.decode_attention(q, k, v, kv_mask=m,
@@ -82,6 +91,14 @@ KERNEL_CASES = {
         (S((8192, 4096), BF16), S((4096, 128), F32),
          S((2, 32, 4096, 2048), BF16), S((2, 32, 4096, 2048), BF16),
          S((2, 32, 2048, 4096), BF16))),
+    # the same layer as the model runs it: under the layer scan, where its
+    # row-count ladder (10240, 16384, 32768 at these shapes) puts the three
+    # grouped products of each rung in a branch of a conditional
+    "moe_dropless_ladder_under_the_layer_scan": (
+        _moe_layers,
+        (S((8192, 4096), BF16), S((4096, 128), F32),
+         S((2, 32, 4096, 2048), BF16), S((2, 32, 4096, 2048), BF16),
+         S((2, 32, 2048, 4096), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -99,6 +116,12 @@ def test_kernel_lowers_for_tpu(name):
     text = lower_for_tpu(fn, *specs)
     # the kernel itself is in the program — not its XLA reference
     assert "tpu_custom_call" in text
+    if name == "moe_dropless_ladder_under_the_layer_scan":
+        # the rungs' grouped products, two kernels a row count (gate and
+        # up have one signature and share a function; the last rung runs in
+        # pieces of the middle one and shares its kernels)
+        assert "stablehlo.case" in text and "stablehlo.while" in text
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
 
 
 def test_no_kernel_wrapper_chooses_interpret_mode():
