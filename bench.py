@@ -834,7 +834,6 @@ def main() -> int:
     # plus raw batched-inference throughput (notebook-301 scoring path)
     bridge_p50 = None
     infer_ips = None
-    infer_compute_ips = None
     table = None
     jm = None
     try:
@@ -861,26 +860,6 @@ def main() -> int:
     except Exception as e:
         failed.append("inference")
         infer_ips = f"error: {e}"
-
-    try:
-        if jm is None or table is None:
-            raise RuntimeError("inference setup failed")
-        # compute-only companion number: the same compiled forward with the
-        # batch already device-resident — separates the host→device share
-        # of infer_ips from the compute share.
-        # (Its own try: a failure here must label THIS metric, not clobber
-        # an already-measured infer_ips.)
-        fn, dev_params, data, _dp = jm._compiled_apply(
-            jm.model, jm._resolve_node(jm.model))
-        mb = 1024
-        imgs_c = rng.integers(0, 255, size=(mb, 32, 32, 3)).astype(np.uint8)
-        dev_batch = jax.device_put(imgs_c, data)
-        fn(dev_params, dev_batch).block_until_ready()
-        cdt = _bench_loop(lambda: fn(dev_params, dev_batch))
-        infer_compute_ips = round(mb / cdt / n_dev, 1)
-    except Exception as e:
-        failed.append("inference_compute")
-        infer_compute_ips = f"error: {e}"
 
     bridge_decomp: dict | None = None
     bridge_rows_s = None
@@ -1228,7 +1207,6 @@ def main() -> int:
         "bridge_p50_score_ms": (bridge_decomp or {}).get("score_ms"),
         "bridge_rows_per_s": bridge_rows_s,
         "inference_images_per_s_per_chip": infer_ips,
-        "inference_compute_images_per_s_per_chip": infer_compute_ips,
         "pipeline_rows_per_s": pipe_rows_s,
         "pipeline_rows_per_s_unfused": pipe_rows_s_unfused,
         "pipeline_crossings": pipe_crossings,

@@ -384,9 +384,13 @@ def phase_serve(devices) -> dict:
     rng = np.random.default_rng(1)
     rows = rng.integers(0, 256, size=(16, IMAGE * IMAGE * 3)
                         ).astype(np.uint8)
-    jm = JaxModel(model=get_model("ResNet50_Infer", input_size=IMAGE),
-                  input_col="image", output_col="scores")
-    offline = jm.transform(DataTable({"image": list(rows)}))
+    bundle = get_model("ResNet50_Infer", input_size=IMAGE)
+    jm = JaxModel(model=bundle, input_col="image", output_col="scores")
+    # the reference comes from a stage of its own: an offline call's program
+    # lands in its stage's compiled-segment store, and the served stage's
+    # store (what compiled_programs counts) is to hold the ladder alone
+    offline = JaxModel(model=bundle, input_col="image", output_col="scores"
+                       ).transform(DataTable({"image": list(rows)}))
     reference = np.stack(offline["scores"]).astype(np.float32)
     check(bool(np.isfinite(reference).all()),
           "offline transform produced non-finite scores")

@@ -183,9 +183,9 @@ def standalone_crossings(stage: Any, schema: Any, n_rows: int | None
                          ) -> int | None:
     """Crossing rounds a stage costs when it runs OUTSIDE a fused segment
     (the host walk). Most host stages cost zero; a lone ``JaxModel`` runs
-    its own minibatch pipeline, and an ``ImageFeaturizer`` executes its
-    internal resize→forward plan. Returns None when the stage does device
-    work but the count is not predictable."""
+    as a segment of one through the planner, and an ``ImageFeaturizer``
+    executes its internal resize→forward plan. Returns None when the stage
+    does device work but the count is not predictable."""
     from mmlspark_tpu.models.image_featurizer import ImageFeaturizer
     from mmlspark_tpu.models.jax_model import JaxModel
 
@@ -200,10 +200,13 @@ def standalone_crossings(stage: Any, schema: Any, n_rows: int | None
             return 0
         if n_rows is None:
             return None
-        from mmlspark_tpu.core import config, plan
-        size = int(stage.minibatch_size
-                   or config.get("default_minibatch_size"))
-        size = plan.dp_rounded_minibatch(
-            size, plan.mesh_dp(stage._mesh()), n_rows)
-        return -(-n_rows // size)
+        from mmlspark_tpu.core import plan
+        from mmlspark_tpu.core.stage import ArrayMeta
+
+        # the segment of one its transform runs (the block as coerced has
+        # the model's own spec; the crossings do not depend on its dtype)
+        spec = ArrayMeta(tuple(stage.model.input_spec), "float32")
+        seg = plan.collect_segment([stage], 0, lambda _col: spec,
+                                   min_stages=1)
+        return plan.predict_segment_minibatches(seg, n_rows)
     return 0

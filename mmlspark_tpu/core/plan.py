@@ -8,14 +8,16 @@ maximal runs of :class:`~mmlspark_tpu.core.stage.DeviceStage`-capable
 stages and compiles each run into ONE jitted composite: a single H2D
 upload per minibatch, one fused XLA program, and one async-windowed D2H
 fetch round (the ``copy_to_host_async``/``max_inflight`` software pipeline
-lifted out of ``JaxModel.transform`` into :func:`pipeline_minibatches`).
+of :func:`pipeline_minibatches`).
 
 Fallback rules (also documented in docs/device_stages.md):
 
 * a stage that is not a ``DeviceStage``, or whose ``device_fn`` declines
   the incoming :class:`~mmlspark_tpu.core.stage.ArrayMeta`, runs on host;
-* a segment needs ≥ 2 consecutive device-capable stages — a lone device
-  stage keeps its own (already-optimized) ``transform`` path;
+* in a stage list a segment needs ≥ 2 consecutive device-capable stages; a
+  lone device stage is handed to its own ``transform``: for a model
+  (``JaxModel``) this executor again, on a segment of one
+  (:func:`run_entered_segment`); for any other stage the host;
 * entry coercion is strict: rows must be non-missing and share one
   shape/dtype, else the whole segment falls back to the host path;
 * every column a fused run writes is materialized from the same composite
@@ -136,8 +138,8 @@ def count_crossings():
     """Count H2D uploads and D2H fetch rounds issued by the minibatch
     pipeline — the observability hook behind tools/perf_smoke.py and the
     bench's crossing metrics. Patches this module's ``_upload`` /
-    ``_issue_fetch`` seams, so it sees JaxModel's own path and fused
-    segments alike. Not thread-safe; use from single-threaded callers."""
+    ``_issue_fetch`` seams, which every segment, a lone model's included,
+    crosses. Not thread-safe; use from single-threaded callers."""
     global _upload, _issue_fetch
     counter = CrossingCounter()
     orig_upload, orig_fetch = _upload, _issue_fetch
@@ -412,11 +414,10 @@ def collect_segment(stages: list, i: int,
     data). ``explain``, when given, collects human-readable reasons the
     segment broke or never formed — the device-plan audit's trace.
 
-    ``min_stages`` defaults to 2 (a lone device stage keeps its own
-    already-optimized ``transform`` path in batch execution); the serving
-    entry (:func:`dispatch_segment` via :func:`transform_async`) passes 1,
-    because there the win is the *asynchronous single-batch dispatch*, which
-    a lone model stage benefits from just as much as a fused run.
+    ``min_stages`` defaults to 2 (in batch execution a lone device stage
+    is handed to its own ``transform``); ``JaxModel.transform`` and the
+    serving entry (:func:`dispatch_segment` via :func:`transform_async`)
+    pass 1: a lone model is a segment of one through the same executor.
 
     ``mesh`` overrides the segment's inference mesh — the sharded-serving
     entry passes a replica's sub-mesh (DP-replica fan-out) or a
@@ -489,16 +490,11 @@ def collect_segment(stages: list, i: int,
     if len(seg_stages) < max(1, int(min_stages)):
         if len(seg_stages) == 1:
             note(f"stage {i} ({type(s0).__name__}) is a lone device stage "
-                 "(a segment needs >= 2): it keeps its own transform path")
+                 "(a segment needs >= 2): it is handed to its own transform")
         return None
     return _Segment(i, seg_stages, entry_col, entry_meta, metas_in,
                     out_cols, emitters, out_metas, mesh=mesh,
                     shard_params=shard_params, precision=precision)
-
-
-def _collect_segment(stages: list, i: int, table: DataTable
-                     ) -> _Segment | None:
-    return collect_segment(stages, i, lambda col: _entry_meta(table, col))
 
 
 def describe_plan(stages: list, table: DataTable) -> list[tuple[str, list]]:
@@ -509,7 +505,8 @@ def describe_plan(stages: list, table: DataTable) -> list[tuple[str, list]]:
     out: list[tuple[str, list]] = []
     i = 0
     while i < len(stages):
-        seg = _collect_segment(stages, i, table)
+        seg = collect_segment(stages, i,
+                              lambda col: _entry_meta(table, col))
         if seg is None:
             out.append(("host", [stages[i]]))
             i += 1
@@ -521,17 +518,13 @@ def describe_plan(stages: list, table: DataTable) -> list[tuple[str, list]]:
 
 # ---- compilation + execution ----
 
-def _segment_tokens(seg: _Segment) -> tuple:
-    return tuple(s.device_cache_token() for s in seg.stages)
-
-
 def _segment_mesh(seg: _Segment):
     """The fused run's inference mesh: an explicit per-segment override
     (sharded serving pins each replica's sub-mesh here) wins, then the
     first explicit ``mesh_spec`` among the segment's stages, else DP over
-    every local device — the same default JaxModel uses standalone, so
-    routing a pipeline through the planner never narrows its data
-    parallelism."""
+    every local device (multi-host scoring = each host runs its own
+    partition stream, the Spark-executor analog — so local devices, not
+    the global mesh)."""
     import jax
 
     from mmlspark_tpu.parallel import mesh as mesh_lib
@@ -621,17 +614,20 @@ def segment_composite(seg: "_Segment", mesh: Any) -> tuple:
 
     in_cols = [s.device_input_col() for s in seg.stages]
     out_cols_per_stage = [s.device_output_col() for s in seg.stages]
+    # closed over in place of ``seg``, which holds the stages: a lone model
+    # is its own cache host and must not be kept alive by its own store
+    entry_col, out_cols, out_metas = seg.entry_col, seg.out_cols, seg.out_metas
     policy = seg.precision
     if policy is not None and not policy.active:
         policy = None
 
     if policy is None:
         def composite(all_params: tuple, x: Any) -> tuple:
-            vals = {seg.entry_col: x}
+            vals = {entry_col: x}
             for k, op in enumerate(ops):
                 vals[out_cols_per_stage[k]] = op.fn(all_params[k],
                                                     vals[in_cols[k]])
-            return tuple(vals[c] for c in seg.out_cols)
+            return tuple(vals[c] for c in out_cols)
 
         return composite, tuple(op.params for op in ops)
 
@@ -640,13 +636,13 @@ def segment_composite(seg: "_Segment", mesh: Any) -> tuple:
     stored = tuple(prec.quantize_params(op.params, policy) for op in ops)
 
     def composite(all_params: tuple, x: Any) -> tuple:
-        vals = {seg.entry_col: prec.cast_activation(x, policy)}
+        vals = {entry_col: prec.cast_activation(x, policy)}
         for k, op in enumerate(ops):
             p = prec.materialize(all_params[k], policy)
             vals[out_cols_per_stage[k]] = prec.cast_activation(
                 op.fn(p, vals[in_cols[k]]), policy)
-        return tuple(prec.cast_output(vals[c], seg.out_metas[c].dtype)
-                     for c in seg.out_cols)
+        return tuple(prec.cast_output(vals[c], out_metas[c].dtype)
+                     for c in out_cols)
 
     return composite, stored
 
@@ -739,17 +735,42 @@ def predict_segment_minibatches(seg: _Segment, n_rows: int) -> int:
 # compiled segments kept per cache_host; LRU-capped so streaming sources
 # with many distinct entry shapes cannot pin an unbounded number of
 # device-resident param copies (each evicted entry releases its device
-# tree — the bound _compiled_apply enforces by refreshing in place)
+# tree)
 _PLAN_CACHE_MAX = 8
+
+
+def _reuploaded(seg: _Segment, entry: tuple, tokens: tuple) -> tuple | None:
+    """The entry of a segment whose tokens moved, when only the parameter
+    objects its stages hold did (a checkpoint scored every N steps): the
+    composite takes the parameters as an argument, so the program stays and
+    the new trees go onto the old leaves' shardings; the old device tree is
+    dropped with the entry this replaces. ``None`` (recompile) when a program
+    token moved too, or the trees differ in structure, shapes or dtypes."""
+    import jax
+
+    _tokens, (fn, dev_params, target, dp), pinned = entry
+    if pinned[2] != tuple(s.device_program_token() for s in seg.stages):
+        return None
+    _composite, params = segment_composite(seg, _segment_mesh(seg))
+    new, treedef = jax.tree_util.tree_flatten(params)
+    old = jax.tree_util.tree_leaves(dev_params)
+    if treedef != jax.tree_util.tree_structure(dev_params) or [
+            (a.shape, a.dtype) for a in map(jax.typeof, new)] != [
+            (o.shape, o.dtype) for o in old]:
+        return None
+    dev_params = jax.device_put(params, jax.tree_util.tree_unflatten(
+        treedef, [o.sharding for o in old]))
+    return tokens, (fn, dev_params, target, dp), pinned
 
 
 def _cached_segment(seg: _Segment, cache_host: Any) -> tuple:
     """(jitted composite, device params, target, dp) for ``seg``, through
     ``cache_host``'s LRU-capped compiled-segment cache when one is given.
-    Shared by batch execution (:func:`_run_segment`) and the serving
+    Shared by batch execution (:func:`run_entered_segment`) and the serving
     dispatch entry (:func:`dispatch_segment`), so an online server and
-    offline ``transform`` calls on the same model reuse ONE jitted
-    composite and one device-resident param upload."""
+    offline ``transform`` calls on the same model reuse ONE jitted composite
+    and one device-resident param upload; the lock keeps concurrent first
+    calls (the bridge's 2-worker overlap) from doing either twice."""
     if cache_host is None:
         return _compile_segment(seg)
     key = (tuple(id(s) for s in seg.stages), seg.entry_col, seg.entry_meta,
@@ -762,39 +783,49 @@ def _cached_segment(seg: _Segment, cache_host: Any) -> tuple:
     lock = cache_host.__dict__.setdefault("_plan_lock", threading.Lock())
     with lock:
         store = cache_host.__dict__.setdefault("_plan_cache", {})
-        entry = store.get(key)
-        tokens = _segment_tokens(seg)
+        # popped and put back: LRU order = insertion order
+        entry = store.pop(key, None)
+        tokens = tuple(s.device_cache_token() for s in seg.stages)
         if entry is not None and entry[0] != tokens:
-            entry = None  # stage config changed: recompile
+            # a stage changed: its parameters alone, or its program
+            entry = _reuploaded(seg, entry, tokens)
         if entry is None:
             # pin the stage objects (and the shard_params override) so
-            # their id()-based key components cannot be reused
+            # their id()-based key components cannot be reused; the host
+            # itself outlives its store, and must not be held by it
             entry = (tokens, _compile_segment(seg),
-                     (tuple(seg.stages), seg.shard_params))
-        else:
-            del store[key]  # re-insert: LRU order = insertion order
+                     (tuple(s for s in seg.stages if s is not cache_host),
+                      seg.shard_params,
+                      tuple(s.device_program_token() for s in seg.stages)))
         store[key] = entry
         while len(store) > _PLAN_CACHE_MAX:
             store.pop(next(iter(store)))
     return entry[1]
 
 
-def _run_segment(seg: _Segment, table: DataTable,
-                 cache_host: Any) -> DataTable | None:
-    """Execute a fused segment; None if entry coercion fails (host path).
-    Carries the same boundary spans as ``JaxModel.transform``: one
-    ``transform`` root a call, ``transform/coerce`` and
-    ``transform/assemble`` under it beside the dispatch seams' own."""
+def run_entered_segment(table: DataTable, enter: Callable[[], tuple | None],
+                        cache_host: Any, dispatch: Callable
+                        ) -> DataTable | None:
+    """One batch ``transform`` call through a device segment: the ONE place
+    that opens the call's boundary spans (the ``transform`` root with its
+    ``rows`` / ``minibatches``, ``transform/coerce`` with the rows and bytes
+    it copied, ``transform/assemble`` around the emit) and counts
+    ``transform.rows``. ``enter()`` coerces the entry column and answers
+    ``(segment, batch, ctx)``, or ``None`` to decline (the caller's host
+    path scores the rows): a fused run's declining :func:`_coerce_entry`, or
+    ``JaxModel.transform``'s raising ``coerce_input_matrix`` with the segment
+    of one built on its result. ``dispatch`` is the caller's binding of
+    :func:`pipeline_minibatches` (a seam fault-injecting tests replace)."""
     with _obs_boundary("transform", "plan", rows=len(table)) as root:
         with _obs_boundary("transform/coerce", "plan") as coerce:
-            coerced = _coerce_entry(table, seg.entry_col, seg.entry_meta)
-            if coerced is not None:  # rows coerced, bytes copied for them
+            entered = enter()
+            if entered is not None:  # rows coerced, bytes copied for them
                 coerce.rows = len(table)
-                coerce.nbytes = copied_nbytes(coerced[0])
-        if coerced is None:
+                coerce.nbytes = copied_nbytes(entered[1])
+        if entered is None:
             root.rows = 0  # declined: the host path scores these rows
             return None
-        batch, ctx = coerced
+        seg, batch, ctx = entered
         size, max_inflight = _segment_minibatch(seg)
         fn, dev_params, target, dp = _cached_segment(seg, cache_host)
 
@@ -804,8 +835,8 @@ def _run_segment(seg: _Segment, table: DataTable,
 
         names = "→".join(type(s).__name__ for s in seg.stages)
         with timed(f"FusedSegment[{names}]", _log, len(table)):
-            outs = pipeline_minibatches(fn, dev_params, batch, size, target,
-                                        max_inflight, label=names)
+            outs = dispatch(fn, dev_params, batch, size, target,
+                            max_inflight, label=names)
         with _obs_boundary("transform/assemble", "plan"):
             for col, values in zip(seg.out_cols, outs):
                 emitter = seg.stages[seg.emitters[col]]
@@ -813,6 +844,17 @@ def _run_segment(seg: _Segment, table: DataTable,
                                             seg.out_metas[col], ctx)
     _obs_registry().counter("transform.rows").add(len(table))
     return table
+
+
+def _run_segment(seg: _Segment, table: DataTable,
+                 cache_host: Any) -> DataTable | None:
+    """Execute a fused segment; None if entry coercion fails (host path)."""
+    def enter() -> tuple | None:
+        coerced = _coerce_entry(table, seg.entry_col, seg.entry_meta)
+        return None if coerced is None else (seg, *coerced)
+
+    return run_entered_segment(table, enter, cache_host,
+                               pipeline_minibatches)
 
 
 # ---- single-batch serving entry (the online model server's dispatch) ----
@@ -961,7 +1003,8 @@ def execute_stages(stages: list, table: DataTable,
     while i < len(stages):
         seg = None
         if len(table):
-            seg = _collect_segment(stages, i, table)
+            seg = collect_segment(stages, i,
+                                  lambda col: _entry_meta(table, col))
         if seg is not None:
             fused = _run_segment(seg, table, cache_host)
             if fused is not None:

@@ -220,6 +220,38 @@ class ArrayMeta:
     is_image: bool = False
 
 
+class TracedMeta:
+    """An :class:`ArrayMeta` that costs a trace of the model to learn,
+    resolved on its first read (a later stage of the run, an emitter that
+    uses it): a run that ends in the model, as a lone ``JaxModel.transform``
+    does, never pays the trace. Compares and hashes as what it resolves to."""
+
+    __slots__ = ("_resolve", "_meta")
+
+    def __init__(self, resolve: Callable[[], ArrayMeta]):
+        self._resolve = resolve
+        self._meta: ArrayMeta | None = None
+
+    def resolved(self) -> ArrayMeta:
+        if self._meta is None:
+            self._meta = self._resolve()
+        return self._meta
+
+    shape = property(lambda self: self.resolved().shape)
+    dtype = property(lambda self: self.resolved().dtype)
+    is_image = property(lambda self: self.resolved().is_image)
+
+    def __eq__(self, other: Any) -> bool:
+        return self.resolved() == (other.resolved() if isinstance(
+            other, TracedMeta) else other)
+
+    def __hash__(self) -> int:
+        return hash(self.resolved())
+
+    def __repr__(self) -> str:
+        return repr(self.resolved())
+
+
 @dataclasses.dataclass
 class DeviceOp:
     """A stage's columnwise device computation.
@@ -233,7 +265,7 @@ class DeviceOp:
     """
 
     fn: Callable
-    out_meta: ArrayMeta
+    out_meta: ArrayMeta | TracedMeta
     params: Any = ()
 
 
@@ -278,6 +310,14 @@ class DeviceStage:
         vals = self._simple_param_values() if hasattr(
             self, "_simple_param_values") else {}
         return tuple(sorted((k, repr(v)) for k, v in vals.items()))
+
+    def device_program_token(self) -> Any:
+        """:meth:`device_cache_token` less the identity of the parameter
+        *objects* ``device_fn`` hands over. When only the rest of the
+        cache token moved, the planner keeps the compiled program and
+        uploads the new parameters onto the old ones' shardings. The
+        default suits a stage whose parameters are never reassigned."""
+        return self.device_cache_token()
 
     def device_fingerprint(self) -> Any:
         """A STABLE content identity for the persistent AOT compile

@@ -136,12 +136,16 @@ class ImageFeaturizer(Transformer, DeviceStage, HasInputCol, HasOutputCol):
     #      column, and a fused op that skipped that would diverge from the
     #      stage-by-stage result. ----
 
-    def device_cache_token(self):
+    def device_program_token(self):
         bundle = self.model
         return (None if bundle is None else
-                (id(bundle.module), id(bundle.params), bundle.preprocess),
+                (id(bundle.module), bundle.preprocess),
                 self.input_col, self.output_col,
                 self.cut_output_layers, self.minibatch_size)
+
+    def device_cache_token(self):
+        return (self.device_program_token(),
+                id(getattr(self.model, "params", None)))
 
     def device_fingerprint(self):
         """Stable content identity for the persistent AOT compile cache

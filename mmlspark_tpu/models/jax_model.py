@@ -7,23 +7,24 @@ evaluates minibatches, and merges outputs back row-wise
 (CNTKModel.scala:51-114). The TPU-native redesign:
 
 * the model is a :class:`ModelBundle` (flax module + pytree) — no broadcast
-  or per-task clone needed; jit-compiled functions are pure and cached,
+  or per-task clone needed. ``transform`` owns the validation and coercion
+  of the input column and nothing else: the block runs as a segment of one
+  stage through the planner's executor
+  (:func:`mmlspark_tpu.core.plan.run_entered_segment`), which compiles,
+  places, caches and feeds a lone model's forward as it does a fused run's,
 * input coercion is at most one vectorized host copy (``column_matrix`` /
   image stacking) instead of per-element JNI sets, and none where the
   column's rows already lie in one matrix (``column_matrix`` hands back a
   read-only view of it),
-* the minibatch iterator pads the tail batch to a fixed shape so XLA
-  compiles exactly one program per (batch, input) shape,
-* dispatch is asynchronous: host marshalling of batch *i+1* overlaps device
-  compute of batch *i* (JAX's async dispatch replaces the reference's
-  re-batching iterator pipelining),
-* inference is **data-parallel over the device mesh**: params live
-  device-resident (transferred once, replicated) and each minibatch is
-  committed batch-sharded over the ``dp``/``fsdp`` axes, so scoring keeps
-  every chip busy — the reference's primary parallelism (Spark-partition DP
-  inference, CNTKModel.scala:248-256) mapped to one host feeding a mesh,
-* outputs are fetched in a single device→host transfer per transform call
-  (no per-minibatch sync),
+* what the executor (``core/plan.py``) does for every segment, it does
+  here: fixed-shape minibatches with a padded tail (one XLA program per
+  shape), asynchronous dispatch (host marshalling of batch *i+1* overlaps
+  device compute of batch *i*), and **data parallelism over the device
+  mesh**: params live device-resident (transferred once, replicated) and
+  each minibatch is committed batch-sharded over the ``dp``/``fsdp`` axes
+  — the reference's Spark-partition DP inference (CNTKModel.scala:248-256)
+  mapped to one host feeding a mesh; outputs come back through a bounded
+  window of async fetches (no per-minibatch sync),
 * output-node selection by name or index matches CNTK node selection
   (CNTKModel.scala:98-108).
 """
@@ -34,25 +35,20 @@ from typing import Any, Iterator
 
 import numpy as np
 
-from mmlspark_tpu.core import config
-from mmlspark_tpu.core.logging_utils import get_logger, timed
 from mmlspark_tpu.core.params import Param
 # minibatches lives in core.plan (shared with fused pipeline segments);
 # re-exported here for the bridge and existing callers
 from mmlspark_tpu.core.plan import (  # noqa: F401
-    dp_rounded_minibatch, mesh_dp, minibatches, pipeline_minibatches,
+    collect_segment, dp_rounded_minibatch, mesh_dp, minibatches,
+    pipeline_minibatches, run_entered_segment,
 )
 from mmlspark_tpu.core.schema import is_image_column
 from mmlspark_tpu.core.stage import (
-    ArrayMeta, DeviceOp, DeviceStage, HasInputCol, HasOutputCol, Transformer,
+    ArrayMeta, DeviceOp, DeviceStage, HasInputCol, HasOutputCol, TracedMeta,
+    Transformer,
 )
-from mmlspark_tpu.data.table import DataTable, copied_nbytes
+from mmlspark_tpu.data.table import DataTable
 from mmlspark_tpu.models.bundle import ModelBundle, PREPROCESSORS
-from mmlspark_tpu.obs.metrics import registry as _obs_registry
-from mmlspark_tpu.obs.spans import boundary_span as _obs_boundary
-from mmlspark_tpu.parallel import mesh as mesh_lib
-
-_log = get_logger(__name__)
 
 
 def _source_dtype(col: np.ndarray, sample: Any) -> Any:
@@ -131,12 +127,11 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
             "Minimum 2: a window of 1 would serialize fetch with compute")
 
     def __getstate__(self):
-        # jitted closures, device arrays, and locks don't pickle; drop on
-        # serialize
+        # the planner's compiled-segment cache (jitted closures, device
+        # arrays, a lock) doesn't pickle; drop on serialize
         d = self.__dict__.copy()
-        d.pop("_jit_cache", None)
-        d.pop("_mesh_cache", None)
-        d.pop("_jit_lock", None)
+        d.pop("_plan_cache", None)
+        d.pop("_plan_lock", None)
         return d
 
     def set_model_location(self, path: str) -> "JaxModel":
@@ -155,119 +150,24 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
             return bundle.resolve_output(self.output_node_index)
         return bundle.resolve_output(None)
 
-    def _mesh(self):
-        """The DP inference mesh over this host's devices (multi-host scoring
-        = each host runs its own partition stream, the Spark-executor
-        analog — so local devices, not the global mesh)."""
-        import jax
-
-        if self.__dict__.get("_mesh_cache") is None:
-            spec = self.mesh_spec or mesh_lib.MeshSpec(dp=-1)
-            self.__dict__["_mesh_cache"] = mesh_lib.make_mesh(
-                spec, jax.local_devices())
-        return self.__dict__["_mesh_cache"]
-
-    def _compiled_apply(self, bundle: ModelBundle, node: str):
-        """(jitted fn, device params, batch sharding, data extent) — cached
-        so repeated transform() calls reuse one compiled program AND one
-        host→device param transfer (the broadcast-once analog).
-
-        One entry per (module identity, preprocess, node): the entry pins
-        the module + params objects it was built from, and a params
-        reassignment refreshes the device copy in place — no id-reuse false
-        hits, no unbounded growth of stale device trees. The lock keeps
-        concurrent first calls (the bridge's default 2-worker overlap)
-        from double-compiling and double-uploading the param tree."""
-        import jax
-
-        lock = self.__dict__.get("_jit_lock")
-        if lock is None:
-            import threading
-            lock = self.__dict__.setdefault("_jit_lock", threading.Lock())
-        with lock:
-            return self._compiled_apply_locked(bundle, node, jax)
-
-    def _compiled_apply_locked(self, bundle: ModelBundle, node: str, jax):
-        cache = self.__dict__.setdefault("_jit_cache", {})
-        key = (id(bundle.module), bundle.preprocess, node)
-        entry = cache.get(key)
-        if entry is not None:
-            fn, dev_params, data, dp, pinned = entry
-            if pinned[0] is bundle.module and pinned[1] is bundle.params:
-                return fn, dev_params, data, dp
-            if pinned[0] is bundle.module:
-                # params swapped (e.g. after a training round): reuse the
-                # compiled program, re-upload the new tree onto the old
-                # copy's sharding; the old device copy is dropped here
-                # instead of pinned forever
-                leaves = jax.tree_util.tree_leaves(dev_params)
-                target = leaves[0].sharding if leaves else None
-                dev_params = jax.device_put(bundle.params, target)
-                cache[key] = (fn, dev_params, data, dp,
-                              (bundle.module, bundle.params))
-                return fn, dev_params, data, dp
-
-        mesh = self._mesh()
-        pre = PREPROCESSORS.get(bundle.preprocess) if bundle.preprocess else None
-
-        def fwd(params, x):
-            import jax.numpy as jnp
-            if x.dtype == jnp.uint8:  # uint8 ships thin, computes as f32
-                x = x.astype(jnp.float32)
-            if pre is not None:
-                x = pre(x)
-            return bundle.module.apply({"params": params}, x, output=node)
-
-        if mesh.devices.size == 1:
-            # single-device path: plain placement and a plain jit instead
-            # of a one-shard NamedSharding (the fork train/loop.py and
-            # core/plan.py share; unmeasured on the chip — ROADMAP Design 3)
-            dev = mesh.devices.reshape(-1)[0]
-            dev_params = jax.device_put(bundle.params, dev)
-            fn = jax.jit(fwd)
-            cache[key] = (fn, dev_params, dev, 1,
-                          (bundle.module, bundle.params))
-            return cache[key][:4]
-
-        repl = mesh_lib.replicated(mesh)
-        data = mesh_lib.batch_sharding(mesh)
-        dev_params = jax.device_put(bundle.params, repl)
-        fn = jax.jit(fwd, in_shardings=(repl, data), out_shardings=data)
-        cache[key] = (fn, dev_params, data, mesh_dp(mesh),
-                      (bundle.module, bundle.params))
-        return cache[key][:4]
-
     def transform(self, table: DataTable) -> DataTable:
         bundle: ModelBundle = self.model
         if bundle is None:
             raise ValueError("JaxModel: no model set")
-        node = self._resolve_node(bundle)
-        size = self.minibatch_size or config.get("default_minibatch_size")
+        self._resolve_node(bundle)  # an unknown node raises before any work
         if len(table) == 0:
             return table.with_column(self.output_col, [])
-        with timed(f"JaxModel[{bundle.name}:{node}]", _log, len(table)), \
-                _obs_boundary("transform", "plan", rows=len(table)) as root:
-            with _obs_boundary("transform/coerce", "plan",
-                               rows=len(table)) as coerce:
-                batch = coerce_input_matrix(table, self.input_col,
-                                            bundle.input_spec)
-                coerce.nbytes = copied_nbytes(batch)
-            fn, dev_params, data, dp = self._compiled_apply(bundle, node)
-            # minibatch must divide over the data axes (shared sizing)
-            size = dp_rounded_minibatch(size, dp, len(batch))
-            root.minibatches = -(-len(batch) // size)
-            # the three-stage upload/compute/fetch software pipeline with
-            # the max_inflight HBM bound, shared with fused pipeline
-            # segments (core.plan)
-            result = pipeline_minibatches(
-                fn, dev_params, batch, size, data,
-                int(self.max_inflight),
-                label=f"JaxModel[{bundle.name}:{node}]")[0]
-            with _obs_boundary("transform/assemble", "plan"):
-                out_col: Any = result if result.ndim == 1 else list(result)
-                out = table.with_column(self.output_col, out_col)
-        _obs_registry().counter("transform.rows").add(len(out))
-        return out
+
+        def enter() -> tuple:
+            batch = coerce_input_matrix(table, self.input_col,
+                                        bundle.input_spec)
+            # the segment is built on the block as coerced, so device_fn's
+            # reshape to input_spec is the identity
+            meta = ArrayMeta(batch.shape[1:], str(batch.dtype))
+            return collect_segment([self], 0, lambda _col: meta,
+                                   min_stages=1), batch, {}
+
+        return run_entered_segment(table, enter, self, pipeline_minibatches)
 
     # ---- static schema inference ----
 
@@ -333,32 +233,34 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
     # ---- DeviceStage protocol: lets the pipeline planner fuse this model
     #      with adjacent device stages into one compiled program ----
 
-    def device_cache_token(self) -> Any:
+    def device_program_token(self) -> Any:
         bundle = self.model
         return (None if bundle is None else
-                (id(bundle.module), id(bundle.params), bundle.preprocess),
+                (id(bundle.module), bundle.preprocess),
                 self.input_col, self.output_col,
                 self.output_node, self.output_node_index,
                 self.minibatch_size, repr(self.mesh_spec))
 
+    def device_cache_token(self) -> Any:
+        return (self.device_program_token(),
+                id(getattr(self.model, "params", None)))
+
     def device_fingerprint(self) -> Any:
         """Stable content identity for the persistent AOT compile cache
         (core/compile_cache.py): the bundle's weights digest replaces
-        the ``id()`` triple of :meth:`device_cache_token`, so two
+        the ``id()``s of :meth:`device_cache_token`, so two
         processes loading the same artifact key the same programs."""
         bundle = self.model
         if bundle is None:
             return None
         from mmlspark_tpu.core.compile_cache import bundle_digest
         return ("JaxModel", bundle_digest(bundle),
-                self.input_col, self.output_col,
-                self.output_node, self.output_node_index,
-                self.minibatch_size, repr(self.mesh_spec))
+                *self.device_program_token()[1:])
 
     def device_fn(self, meta: ArrayMeta) -> DeviceOp | None:
-        """The same forward ``JaxModel.transform`` compiles (uint8 ships
-        thin and upcasts on device, then the bundle's preprocess and the
-        selected output node) as a composable op. Declines on a per-row
+        """The model's forward (uint8 ships thin and upcasts on device,
+        then the bundle's preprocess and the selected output node) as a
+        composable op, the one every path compiles. Declines on a per-row
         size mismatch so the host path raises its canonical shape error."""
         bundle: ModelBundle = self.model
         if bundle is None:
@@ -379,20 +281,23 @@ class JaxModel(Transformer, DeviceStage, HasInputCol, HasOutputCol):
                 x = pre(x)
             return bundle.module.apply({"params": params}, x, output=node)
 
-        import jax
-        out = jax.eval_shape(
-            fwd, bundle.params,
-            jax.ShapeDtypeStruct((1,) + tuple(meta.shape),
-                                 np.dtype(meta.dtype)))
-        return DeviceOp(fwd, ArrayMeta(tuple(out.shape[1:]),
-                                       str(out.dtype)),
-                        params=bundle.params)
+        def traced() -> ArrayMeta:
+            import jax
+            out = jax.eval_shape(
+                fwd, bundle.params,
+                jax.ShapeDtypeStruct((1,) + tuple(meta.shape),
+                                     np.dtype(meta.dtype)))
+            return ArrayMeta(tuple(out.shape[1:]), str(out.dtype))
+
+        # the output layout costs a whole trace of the forward: left to
+        # whoever reads it, which a run that ends in this stage never does
+        return DeviceOp(fwd, TracedMeta(traced), params=bundle.params)
 
     def transform_stream(self, tables: Any) -> Iterator[DataTable]:
         """Score a stream of DataTable chunks with bounded memory.
 
         The compiled program and device-resident params are shared across
-        chunks (the jit cache), so streaming costs no recompiles or
+        chunks (this stage's compiled-segment cache): no recompiles or
         re-uploads — pair with ``data.readers.stream_images`` for
         ImageNet-shard-scale scoring without materializing the dataset.
         """

@@ -13,6 +13,7 @@ per (model, bucket) exists before the first request arrives.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 import threading
 from typing import Any, Iterable, Mapping
@@ -582,7 +583,10 @@ class ModelServer:
         padded = calib if bucket == n else calib.take(
             np.arange(bucket) % n)
         try:
-            ref = model.transform(calib)          # the f32 offline path
+            # the f32 offline path, on a shallow copy that owns no
+            # compiled-segment store: the reference program and its f32
+            # parameters go with it, not resident in the served model's
+            ref = copy.copy(model).transform(calib)
             got = batcher.probe(padded)           # the served program
         except BaseException as e:
             raise ModelLoadError(name, message=(

@@ -138,10 +138,10 @@ def test_jax_model_inference_is_mesh_sharded():
     jm.set(model=bundle)
     t = image_table(16)
     single = np.stack(list(jm.transform(t)["s"]))
-    # the cached compiled entry carries a replicated device param tree and a
-    # dp extent covering all local devices
-    node = jm._resolve_node(bundle)
-    fn, dev_params, data, dp = jm._compiled_apply(bundle, node)
+    # the planner's compiled entry for the segment of one carries a
+    # replicated device param tree and a dp extent covering all local devices
+    (entry,) = jm.__dict__["_plan_cache"].values()
+    fn, dev_params, data, dp = entry[1]
     assert dp == jax.local_device_count() == 8
     leaf = jax.tree_util.tree_leaves(dev_params)[0]
     assert len(leaf.sharding.device_set) == 8
@@ -152,12 +152,13 @@ def test_jax_model_inference_is_mesh_sharded():
     jm1 = JaxModel(input_col="image", output_col="s", minibatch_size=16,
                    mesh_spec={"dp": 1})
     jm1.set(model=bundle)
-    jm1.__dict__["_mesh_cache"] = None
-    import mmlspark_tpu.parallel.mesh as mesh_lib
-    jm1.__dict__["_mesh_cache"] = mesh_lib.make_mesh(
-        {"dp": 1}, jax.local_devices()[:1])
     one = np.stack(list(jm1.transform(t)["s"]))
     np.testing.assert_allclose(single, one, rtol=1e-4, atol=1e-4)
+    (entry1,) = jm1.__dict__["_plan_cache"].values()
+    _fn, params1, target1, dp1 = entry1[1]
+    assert dp1 == 1 and target1 == jax.local_devices()[0]
+    assert jax.tree_util.tree_leaves(params1)[0].sharding.device_set \
+        == {target1}
 
 
 def test_jax_model_tiny_table_pads_to_mesh():
@@ -240,27 +241,58 @@ def test_graft_entry_multichip():
 
 # ---- round-3 regression tests (ADVICE r2) ----
 
-def test_compiled_apply_no_stale_cache_on_params_reassign():
+def test_params_reassign_no_stale_cache_no_second_compile():
     """Reassigning bundle.params must not serve stale device weights, even
-    if CPython reuses the freed dict's id (ADVICE r2: the cache entry now
-    pins the keyed params object alive, so id-reuse is impossible)."""
+    if CPython reuses the freed dict's id (ADVICE r2: the cache entry pins
+    the stage it is keyed by, and a changed token is a re-upload), and must
+    neither compile again nor grow the store: the planner keeps the jitted
+    composite and puts the new tree onto the old leaves' shardings."""
+    import jax
+
+    from mmlspark_tpu import obs
+    from mmlspark_tpu.obs.runtime import compiled_programs, jit_cache_size
+
     bundle = small_cifar_bundle()
     jm = JaxModel(model=bundle, input_col="image", output_col="scores",
                   minibatch_size=4)
     t = image_table(4)
     out1 = np.stack(jm.transform(t)["scores"])
-    cache = jm.__dict__["_jit_cache"]
-    assert all(entry[-1][1] is bundle.params for entry in cache.values())
-    # mutate the model the way tools/build_model_repo does: new params tree
-    import jax
-    for _ in range(3):
-        bundle.params = jax.tree_util.tree_map(
-            lambda p: p * 0.0, bundle.params)
-        out2 = np.stack(jm.transform(t)["scores"])
+    cache = jm.__dict__["_plan_cache"]
+    (before,) = cache.values()
+    fn = before[1][0]
+    assert jit_cache_size(fn) == 1
+    obs.enable()
+    try:
+        compiles = obs.registry().counter("plan.segment_compiles")
+        compiled_before = compiles.value
+        # mutate the model the way tools/build_model_repo does: new params
+        for _ in range(3):
+            bundle.params = jax.tree_util.tree_map(
+                lambda p: p * 0.0, bundle.params)
+            out2 = np.stack(jm.transform(t)["scores"])
+        assert compiles.value == compiled_before
+    finally:
+        obs.disable()
     assert not np.allclose(out1, out2)  # zeroed weights → different scores
-    # repeated reassignment must not grow the cache (stale device trees
-    # would otherwise accumulate until OOM)
-    assert len(jm.__dict__["_jit_cache"]) == 1
+    np.testing.assert_array_equal(
+        out2, np.stack(JaxModel(model=bundle, input_col="image",
+                                output_col="scores", minibatch_size=4)
+                       .transform(t)["scores"]))
+    # repeated reassignment must not grow the store (stale device trees
+    # would otherwise accumulate until OOM) nor compile a second program
+    (after,) = cache.values()
+    assert after is not before and after[1][0] is fn
+    assert jit_cache_size(fn) == 1 and compiled_programs(jm) == 1
+    # the old device tree went with the entry it was in
+    old_leaf = jax.tree_util.tree_leaves(before[1][1])[0]
+    new_leaf = jax.tree_util.tree_leaves(after[1][1])[0]
+    assert new_leaf is not old_leaf
+    assert new_leaf.sharding == old_leaf.sharding
+    assert float(np.abs(np.asarray(new_leaf)).max()) == 0.0
+    # a new module is another program: that does compile
+    jm.set(model=small_cifar_bundle())
+    jm.transform(t)
+    assert next(iter(cache.values()))[1][0] is not fn and len(cache) == 1
 
 
 def test_coerce_heterogeneous_image_dtypes_fall_back_to_float32():
