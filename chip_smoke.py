@@ -23,9 +23,10 @@ compiler:
   new tokens each; prefill-then-decode logits checked against a plain
   float32 forward; the compiled decode program must contain the Pallas
   kernel;
-* **kernels** — ``decode_attention``, ``flash_attention`` (ViT-B tile and
-  causal T=1024), ``attention_block_update``, ``group_norm``, each against
-  its XLA reference.
+* **kernels** — ``decode_attention``, ``flash_attention`` (ViT-B tile,
+  causal T=1024, and the tiled kernel at a causal T=4096 and a masked
+  T=2100), ``attention_block_update``, ``group_norm``, each against its
+  XLA reference.
 
 With more than one device visible the multi-device branches switch on:
 the train phase also runs over the default ``dp`` mesh (one batch shard
@@ -652,6 +653,40 @@ def phase_kernels(devices) -> dict:
         lambda q, k, v, m: fa.flash_attention(q, k, v, kv_mask=m,
                                               causal=True, impl="xla"),
         args, KERNEL_ATOL, KERNEL_RTOL)
+
+    # flash, tiled: the language model's causal window of whole tiles with
+    # no key to mask (the blocks below the diagonal run with no mask),
+    # equal to the bit to the same call with an all-true key row (every
+    # block general); and a masked window of 2100, not a whole number of
+    # tiles
+    B, H, T, D = 2, 8, 4096, 128
+    args = tuple(_bf16_exact(rng, (B, H, T, D), bf16) for _ in range(3))
+    facts["flash_attention[tiled,causal,B2,H8,T4096,D128,bf16]"] = \
+        _kernel_case(
+            "flash_attention/tiled",
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                               impl="pallas"),
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                               impl="xla"),
+            args, KERNEL_ATOL, KERNEL_RTOL)
+    bare, rowed = (np.asarray(fa.flash_attention(
+        *args, kv_mask=m, causal=True, impl="pallas"))
+        for m in (None, jnp.ones((B, T), bool)))
+    check(bool((bare.view(np.uint32) == rowed.view(np.uint32)).all()),
+          "flash_attention/tiled: the mask-free tiles differ from the "
+          "masked arithmetic on an all-true mask")
+    T = 2100
+    mask = jnp.asarray(np.arange(T)[None, :] < np.asarray([[T], [1300]]))
+    args = tuple(_bf16_exact(rng, (B, H, T, D), bf16)
+                 for _ in range(3)) + (mask,)
+    facts["flash_attention[tiled,masked,B2,H8,T2100,D128,bf16]"] = \
+        _kernel_case(
+            "flash_attention/tiled_masked",
+            lambda q, k, v, m: fa.flash_attention(
+                q, k, v, kv_mask=m, causal=True, impl="pallas"),
+            lambda q, k, v, m: fa.flash_attention(
+                q, k, v, kv_mask=m, causal=True, impl="xla"),
+            args, KERNEL_ATOL, KERNEL_RTOL)
 
     # the ring-hop block update: a second hop over a live carry, with a
     # causal-and-padding style mask (some rows fully masked)

@@ -12,8 +12,8 @@ built from the configuration's own key names (``docs/lm.md``):
   shared by all heads, interleaved RoPE with YaRN frequencies, and the
   position scale ``1 + beta ln(1 + floor(p / original_max))`` on queries.
   The attention core is :func:`~mmlspark_tpu.ops.pallas.attention.
-  flash_attention` (tiled over queries at long windows, causal tiles
-  skipped; ``[B, H, L, L]`` never exists);
+  flash_attention` (tiled over queries at long windows, only the causal
+  triangle's tile pairs run; ``[B, H, L, L]`` never exists);
 * **experts**: softmax router over ``router_width`` in float32, top-k
   renormalised, gated-SiLU experts plus shared experts. The module holds
   the experts ``[first_expert, first_expert + n_routed_experts)`` — one

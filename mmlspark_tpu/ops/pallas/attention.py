@@ -26,10 +26,39 @@ Kernel discipline:
 * ``impl: auto | xla | pallas`` selects the backend (auto = kernel on
   TPU, reference elsewhere). ``flash_attention`` whose whole (batch,
   head) tile is past the VMEM budget takes the tiled kernel (queries
-  tiled too, causal tiles above the diagonal skipped, operands in the
-  type they came in); ``attention_block_update`` past the budget under
-  ``auto`` takes the reference and says so (a warning and the
-  ``ops.pallas.vmem_fallback`` counter), under ``pallas`` it raises.
+  tiled too, operands in the type they came in: below);
+  ``attention_block_update`` past the budget under ``auto`` takes the
+  reference and says so (a warning and the ``ops.pallas.vmem_fallback``
+  counter), under ``pallas`` it raises.
+
+The tiled kernel pays a (query tile, key block) pair only what its place
+needs, decided when the call is traced from ``causal``, whether any key
+can be masked (a ``kv_mask``, or ``Tk`` not a whole number of blocks) and
+the pair's indices; there is nothing to choose:
+
+* a grid step is a query tile against a **resident stretch** of keys and
+  values (the whole window where it fits half the VMEM budget, equal
+  stretches otherwise) and the key blocks are a loop inside it, the
+  softmax's carry a value of that loop. A carry read from ``[bq, 1]``
+  scratch every block is one lane of every register and is re-spread
+  every block (on a v5e 0.7 us of a 1.9 us update), so scratch holds it
+  only between the steps of a query tile with several stretches, and
+  there lane-dense;
+* an **interior** pair's mask is all true (no key row, and under
+  ``causal`` the key block wholly below the query tile's first row): the
+  shared body with ``keep=None``: no mask built, no select, no guard;
+* a **diagonal** pair (``causal``, no key row, equal tiles, on the
+  diagonal) builds ``col <= row`` from the tile's own iotas, the same
+  triangle for every such pair;
+* a **general** pair (a key row, or unequal tiles straddling the
+  diagonal) runs the masked, guarded update: key row, offset triangle;
+* under ``causal`` a pair wholly above the diagonal is never run, and a
+  stretch with none to run is not a grid step: the grid is ``(batch,
+  head, step)`` over prefetched tables (:func:`_tile_plan`) of each
+  step's query tile, stretch, and how many blocks it runs with no mask
+  and then under one. The pairs by kind and the steps of one (batch,
+  head) are counted at trace time (``ops.pallas.attention_tiles{kind=}``,
+  ``ops.pallas.attention_grid_steps``).
 
 What reaches the kernel is shaped for the compiler: ``Tq`` is padded to
 the f32 sublane tile and ``Tk`` to a whole number of ``block_k`` stripes
@@ -53,6 +82,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from mmlspark_tpu.obs.metrics import registry as _obs_registry
 from mmlspark_tpu.ops.pallas.budget import (
     VMEM_BUDGET, lane_pad, note_vmem_fallback,
 )
@@ -70,6 +100,7 @@ _DENOM_FLOOR = np.float32(1e-30)
 # the f32 sublane tile: kernel query rows are padded to a multiple of it
 # (a Tq=1 decode step becomes one aligned 8-row MXU pass)
 _SUBLANES = 8
+_LANES = 128
 
 
 def _qk_t(q, ks, xp):
@@ -102,14 +133,25 @@ def _online_update(q, ks, vs, keep, m, denom, acc, scale, xp):
     ``[Tq, Tk]`` bool, carry ``m``/``denom`` ``[Tq, 1]`` f32 and ``acc``
     ``[Tq, D]`` f32. Returns the updated ``(m, denom, acc)``. Also the
     per-hop local-block update of ``ring_attention`` (each ring step IS
-    one such update with the resident K/V block)."""
+    one such update with the resident K/V block).
+
+    ``keep=None`` is the caller's word that every key of the block is
+    kept (the tiled kernel's interior tiles): no select, and no guard,
+    since a row that keeps a key has a finite maximum. For finite scores
+    that is the masked arithmetic with its no-ops left out, equal to
+    the bit."""
     scores = _qk_t(q, ks, xp) * scale
-    scores = xp.where(keep, scores, -xp.inf)
+    if keep is not None:
+        scores = xp.where(keep, scores, -xp.inf)
     blk_max = xp.max(scores, axis=-1, keepdims=True)
     m_new = xp.maximum(m, blk_max)
-    # guard -inf - -inf (rows with every key masked so far)
-    corr = xp.where(xp.isfinite(m), xp.exp(m - m_new), np.float32(0))
-    p = xp.exp(xp.where(xp.isfinite(scores), scores - m_new, -xp.inf))
+    if keep is None:
+        corr = xp.exp(m - m_new)
+        p = xp.exp(scores - m_new)
+    else:
+        # guard -inf - -inf (rows with every key masked so far)
+        corr = xp.where(xp.isfinite(m), xp.exp(m - m_new), np.float32(0))
+        p = xp.exp(xp.where(xp.isfinite(scores), scores - m_new, -xp.inf))
     acc = acc * corr + _p_v(p, vs, xp)
     denom = denom * corr + xp.sum(p, axis=-1, keepdims=True)
     return m_new, denom, acc
@@ -293,57 +335,158 @@ def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
 
 # ---- the tiled kernel: sequences whose (batch, head) tile outgrows VMEM ----
 
-# query and key tile of the tiled kernel: one online update per tile pair
+# query tile and key block of the tiled kernel: one online update per pair
 TILE_Q = 512
 TILE_K = 512
 
+# what a (query tile, key block) pair's place asks of its update: an
+# interior pair's mask is all true (none is built), a diagonal pair's is the
+# one triangle every diagonal tile shares (the tile's own iotas), a general
+# pair honours a key row and offsets
+TILE_KINDS = ("interior", "diagonal", "general")
+TILES_COUNTER = "ops.pallas.attention_tiles"
+GRID_STEPS_COUNTER = "ops.pallas.attention_grid_steps"
 
-def _tiled_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, m_ref, d_ref, a_ref,
-                  *, scale: np.float32, causal: bool, bq: int, bk: int):
-    # grid (batch, head, query tile, key tile), the key tile innermost:
-    # the carry of the online softmax lives in scratch across key tiles.
-    # Operands stay in the type they came in (bf16 on the MXU, float32
-    # accumulation); the softmax is float32
+
+def _resident_blocks(blocks: int, bk: int, d: int, dv: int,
+                     itemsize: int) -> int:
+    """Key blocks of one resident stretch: the keys and values of a grid
+    step stay in VMEM and the blocks are a loop inside it, so a step costs
+    its fixed price once a stretch, not once a block. Half the budget goes
+    to the two double-buffered stretches (the score block's float32
+    temporaries and the query and output tiles have the rest); a window
+    past that is cut into equal stretches."""
+    most = max(1, (VMEM_BUDGET // 2)
+               // (2 * bk * (lane_pad(d) + lane_pad(dv)) * itemsize))
+    return -(-blocks // -(-blocks // most))
+
+
+def _tile_plan(nq: int, blocks: int, sub: int, bq: int, bk: int,
+               causal: bool, key_row: bool) -> np.ndarray:
+    """The grid steps of one (batch, head): int32 rows ``(query tile,
+    stretch, mask-free blocks, masked blocks, last step of its query
+    tile)``, one column a step, a query tile's steps consecutive. The
+    ``blocks`` key blocks lie in stretches of ``sub`` (the last may be
+    short). A step runs its stretch's leading ``mask-free`` blocks with no
+    mask, then ``masked`` more under one; the blocks after those lie wholly
+    above the causal diagonal and are not run, and a stretch with none to
+    run is not a step. ``key_row``: some key may be masked (a ``kv_mask``,
+    or padded keys), so no block is mask-free."""
+    steps = []
+    for i in range(nq):
+        mine = []
+        for j in range(-(-blocks // sub)):
+            free = masked = 0
+            for block in range(j * sub, min((j + 1) * sub, blocks)):
+                if causal and block * bk > i * bq + bq - 1:
+                    break
+                if key_row or (causal and block * bk + bk - 1 > i * bq):
+                    masked += 1
+                else:
+                    free += 1
+            if free + masked:
+                mine.append([i, j, free, masked, 0])
+        mine[-1][-1] = 1
+        steps += mine
+    return np.asarray(steps, np.int32).T
+
+
+def _tiled_kernel(qi_ref, kj_ref, free_ref, masked_ref, last_ref, q_ref,
+                  k_ref, v_ref, *rest, scale: np.float32, causal: bool,
+                  bq: int, bk: int, key_row: bool, masked_kind: str,
+                  one_masked: bool):
+    # grid (batch, head, step): a step is one query tile against one
+    # resident stretch of keys, its place and block counts prefetched
+    # (_tile_plan). The carry of the online softmax is a value across a
+    # step's blocks. Operands stay in the type they came in (bf16 on the
+    # MXU, float32 accumulation); the softmax is float32. ``rest``: the key
+    # row's ref where the call has one, the output, and the carry's scratch
+    # where a query tile has several steps
     import jax.experimental.pallas as pl
 
-    qi, kj = pl.program_id(2), pl.program_id(3)
+    kv_ref, (o_ref, *scratch) = (rest[0], rest[1:]) if key_row \
+        else (None, rest)
+    step = pl.program_id(2)
+    qi, kj, free = qi_ref[step], kj_ref[step], free_ref[step]
+    stretch = k_ref.shape[2]
 
-    @pl.when(kj == 0)
-    def _start():
-        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
-        d_ref[...] = jnp.zeros(d_ref.shape, jnp.float32)
-        a_ref[...] = jnp.zeros(a_ref.shape, jnp.float32)
+    if scratch:
+        # the row statistics cross grid steps lane-dense and come back
+        # through a lane reduction: loaded from a [bq, 1] ref they are one
+        # lane of every register, the loops below inherit that layout and
+        # re-spread them every block (0.7 us of a 1.9 us update on a v5e)
+        m_ref, d_ref, a_ref = scratch
 
-    def _update():
-        keep = jnp.broadcast_to(kv_ref[0], (bq, bk)) != 0
-        if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = keep & (col + kj * bk <= row + qi * bq)
-        m, denom, acc = _online_update(
-            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], keep, m_ref[...],
-            d_ref[...], a_ref[...], scale, jnp)
-        m_ref[...] = m
-        d_ref[...] = denom
-        a_ref[...] = acc
+        @pl.when(kj == 0)
+        def _start():
+            m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+            d_ref[...] = jnp.zeros(d_ref.shape, jnp.float32)
+            a_ref[...] = jnp.zeros(a_ref.shape, jnp.float32)
 
-    if causal:
-        # a key tile wholly above the diagonal does no work (and is not
-        # fetched: its block index repeats the last one needed)
-        pl.when(kj * bk <= qi * bq + bq - 1)(_update)
+        carry = (jnp.max(m_ref[...], axis=-1, keepdims=True),
+                 jnp.max(d_ref[...], axis=-1, keepdims=True), a_ref[...])
     else:
-        _update()
+        carry = (jnp.full((bq, 1), -jnp.inf, jnp.float32),
+                 jnp.zeros((bq, 1), jnp.float32),
+                 jnp.zeros((bq, v_ref.shape[3]), jnp.float32))
 
-    @pl.when(kj == pl.num_programs(3) - 1)
+    def below(row_start, col_start):
+        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        return col + col_start <= row + row_start
+
+    def mask(cols):
+        if masked_kind == "diagonal":
+            return below(0, 0)
+        keep = None
+        if key_row:
+            keep = jnp.broadcast_to(kv_ref[0, :, cols], (bq, bk)) != 0
+        if causal:
+            tri = below(qi * bq, kj * stretch + cols.start)
+            keep = tri if keep is None else keep & tri
+        return keep
+
+    def block(mask_of):
+        def body(c, carry):
+            cols = pl.ds(pl.multiple_of(c * bk, bk), bk)
+            return _online_update(q_ref[0, 0], k_ref[0, 0, cols, :],
+                                  v_ref[0, 0, cols, :], mask_of(cols),
+                                  *carry, scale, jnp)
+        return body
+
+    carry = jax.lax.fori_loop(0, free, block(lambda cols: None), carry)
+    if one_masked:
+        # every step has exactly one (a diagonal in each query tile's one
+        # step): no loop around it
+        carry = block(mask)(free, carry)
+    else:
+        carry = jax.lax.fori_loop(free, free + masked_ref[step], block(mask),
+                                  carry)
+
     def _finish():
-        o_ref[0, 0] = a_ref[...] / jnp.maximum(d_ref[...], _DENOM_FLOOR)
+        o_ref[0, 0] = carry[2] / jnp.maximum(carry[1], _DENOM_FLOOR)
+
+    if scratch:
+        m_ref[...] = jnp.broadcast_to(carry[0], m_ref.shape)
+        d_ref[...] = jnp.broadcast_to(carry[1], d_ref.shape)
+        a_ref[...] = carry[2]
+        pl.when(last_ref[step] == 1)(_finish)
+    else:
+        _finish()
 
 
 def _tiled_call(q, k, v, kv_mask, causal: bool, scale):
     """Flash attention with the queries tiled too: ``[B, H, Tq, D]`` against
     ``[B, H, Tk, D]`` keys and ``[B, H, Tk, Dv]`` values, any length. The
-    score matrix never exists beyond one ``TILE_Q x TILE_K`` tile in VMEM;
-    under ``causal`` the tiles above the diagonal are skipped."""
+    score matrix never exists beyond one ``TILE_Q x TILE_K`` block in VMEM.
+    A (query tile, key block) pair pays for what its place needs
+    (:func:`_tile_plan`, decided here from ``causal``, whether any key can
+    be masked, and the pair's indices): under ``causal`` the pairs above the
+    diagonal are never run, with no key to mask the pairs below it run the
+    update with no mask at all, and the key blocks of a query tile are a
+    loop inside one grid step over keys that stay in VMEM. The pairs of one
+    (batch, head) by kind and its grid steps are counted when the call is
+    traced."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -353,35 +496,52 @@ def _tiled_call(q, k, v, kv_mask, causal: bool, scale):
     bk = min(TILE_K, tk + (-tk % 128))
     q, k, v, kv_row = _padded_operands(q, k, v, kv_mask, bq, bk)
     tq_p, tk_p = q.shape[2], k.shape[2]
+    key_row = kv_mask is not None or tk_p != tk
+    sub = _resident_blocks(tk_p // bk, bk, d, dv, k.dtype.itemsize)
+    plan = _tile_plan(tq_p // bq, tk_p // bk, sub, bq, bk, causal, key_row)
+    masked_kind = ("diagonal" if causal and not key_row and bq == bk
+                   else "general")
+    steps = plan.shape[1]
+    for kind, n in (("interior", plan[2].sum()), (masked_kind, plan[3].sum())):
+        _obs_registry().counter(TILES_COUNTER, kind=kind).add(int(n))
+    _obs_registry().counter(GRID_STEPS_COUNTER).add(steps)
 
-    def key_tile(i, j):
-        return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
+    def query_tile(b_, h_, s, qi, *_):
+        return (b_, h_, qi[s], 0)
 
-    kern = functools.partial(_tiled_kernel, scale=np.float32(scale),
-                             causal=causal, bq=bq, bk=bk)
+    def key_stretch(b_, h_, s, qi, kj, *_):
+        return (b_, h_, kj[s], 0)
+
+    in_specs = [pl.BlockSpec((1, 1, bq, d), query_tile),
+                pl.BlockSpec((1, 1, sub * bk, d), key_stretch),
+                pl.BlockSpec((1, 1, sub * bk, dv), key_stretch)]
+    operands = [q, k, v]
+    if key_row:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, sub * bk), lambda b_, h_, s, qi, kj, *_: (b_, 0, kj[s])))
+        operands.append(kv_row.reshape(b, 1, tk_p))
+    kern = functools.partial(
+        _tiled_kernel, scale=np.float32(scale), causal=causal, bq=bq, bk=bk,
+        key_row=key_row, masked_kind=masked_kind,
+        one_masked=bool((plan[3] == 1).all()))
+    # a query tile with several steps carries its softmax in scratch
+    carried = [] if sub * bk >= tk_p else [
+        pltpu.VMEM((bq, _LANES), jnp.float32),
+        pltpu.VMEM((bq, _LANES), jnp.float32),
+        pltpu.VMEM((bq, dv), jnp.float32)]
     out = pl.pallas_call(
         kern,
-        grid=(b, h, tq_p // bq, tk_p // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_, key_tile(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, dv),
-                         lambda b_, h_, i, j: (b_, h_, key_tile(i, j), 0)),
-            pl.BlockSpec((1, 1, bk),
-                         lambda b_, h_, i, j: (b_, 0, key_tile(i, j))),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, dv),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(plan),
+            grid=(b, h, steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, dv), query_tile),
+            scratch_shapes=carried),
         out_shape=jax.ShapeDtypeStruct((b, h, tq_p, dv), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_attention_tiled",
-    )(q, k, v, kv_row.reshape(b, 1, tk_p))
+    )(*plan, *operands)
     return out[:, :, :tq] if tq_p != tq else out
 
 

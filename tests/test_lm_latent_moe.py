@@ -590,3 +590,187 @@ def test_tiled_attention_takes_bf16_operands_and_wider_values():
     # the weights are rounded to bf16 for the p.v product: 2^-9 relative
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-2)
+
+
+def _xla_attention(q, k, v, **kw):
+    return np.asarray(fa.flash_attention(q, k, v, impl="xla", **kw))
+
+
+def test_a_block_with_no_mask_is_the_masked_arithmetic_to_the_bit():
+    # the claim the interior tiles rest on, where each operation runs alone
+    # (numpy, and jax op by op): dropping the selects and the guard of an
+    # all-true block changes no bit, whether the carry is fresh or live
+    r = np.random.default_rng(27)
+    q, ks, vs = (r.normal(size=(48, 16)).astype(np.float32)
+                 for _ in range(3))
+    keep = np.ones((48, 48), bool)
+    fresh = (np.full((48, 1), -np.inf, np.float32),
+             np.zeros((48, 1), np.float32), np.zeros((48, 16), np.float32))
+    live = fa._online_update(q, ks[::-1], vs, keep, *fresh,
+                             np.float32(0.25), np)
+    for carry in (fresh, live):
+        want = fa._online_update(q, ks, vs, keep, *carry,
+                                 np.float32(0.25), np)
+        got = fa._online_update(q, ks, vs, None, *carry,
+                                np.float32(0.25), np)
+        with jax.disable_jit():
+            on_jax = [fa._online_update(
+                *(jnp.asarray(a) for a in (q, ks, vs)), mask,
+                *(jnp.asarray(a) for a in carry), np.float32(0.25), jnp)
+                for mask in (jnp.asarray(keep), None)]
+        for w, g, jw, jg in zip(want, got, *on_jax):
+            np.testing.assert_array_equal(w, g)
+            np.testing.assert_array_equal(np.asarray(jw), np.asarray(jg))
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("dtype,ulp,atol", [(jnp.float32, 2e-6, 2e-6),
+                                            (jnp.bfloat16, 2e-4, 1e-2)])
+def test_tiled_attention_whole_tiles_with_and_without_a_key_row(dtype, ulp,
+                                                                atol):
+    # no key row: interior tiles with no mask, the diagonal's local
+    # triangle; an all-true kv_mask: the general path. On the chip the two
+    # are equal to the bit (chip_smoke.py asserts it there); XLA's CPU
+    # backend, which runs the interpreted kernel, fuses the two forms
+    # differently and may differ in the last place of a weight, which bf16
+    # operands widen to one rounding of a p.v weight
+    r = np.random.default_rng(24)
+    q, k, v = (jnp.asarray(r.normal(size=(1, 2, 2048, 32)), dtype)
+               for _ in range(3))
+    bare = np.asarray(fa.flash_attention(q, k, v, causal=True,
+                                         impl="pallas"))
+    rowed = np.asarray(fa.flash_attention(
+        q, k, v, kv_mask=jnp.ones((1, 2048), bool), causal=True,
+        impl="pallas"))
+    np.testing.assert_allclose(bare, rowed, rtol=0, atol=ulp)
+    want = _xla_attention(q, k, v, causal=True)
+    for got in (bare, rowed):
+        assert got.dtype == np.float32 and np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=atol)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("causal", [True, False])
+def test_tiled_attention_keeps_its_guards_where_a_key_can_be_masked(causal):
+    # row 0 of the batch masks its whole first key tile (and more): under
+    # causal its first 640 queries keep no key at all and come back as
+    # exact zeros; the rest, whose first tile is wholly masked, are finite.
+    # Row 1 masks nothing
+    r = np.random.default_rng(25)
+    b, h, t, d = 2, 1, 1536, 16
+    q, k, v = (jnp.asarray(r.normal(size=(b, h, t, d)), jnp.float32)
+               for _ in range(3))
+    mask = np.ones((b, t), bool)
+    mask[0, :640] = False
+    got = np.asarray(fa.flash_attention(q, k, v, kv_mask=jnp.asarray(mask),
+                                        causal=causal, impl="pallas"))
+    assert np.isfinite(got).all()
+    if causal:
+        assert (got[0, :, :640] == 0).all()
+    assert (np.abs(got[0, :, 640:]).max(axis=-1) > 0).all()
+    np.testing.assert_allclose(
+        got, _xla_attention(q, k, v, kv_mask=jnp.asarray(mask),
+                            causal=causal), rtol=2e-5, atol=2e-6)
+
+
+def _tile_counts(fn, *specs):
+    from mmlspark_tpu.obs.metrics import registry
+
+    def read():
+        return [registry().value(fa.TILES_COUNTER, kind=kind) or 0
+                for kind in fa.TILE_KINDS] \
+            + [registry().value(fa.GRID_STEPS_COUNTER) or 0]
+    before = read()
+    jax.eval_shape(fn, *specs)
+    return [int(a - b) for a, b in zip(read(), before)]
+
+
+@pytest.mark.parametrize("case,want", [
+    # the language-model cell's layer-window: 36 of the 64 tile pairs, and
+    # a grid step a query tile (its keys stay in VMEM, the blocks a loop)
+    ("lm_window", [28, 8, 0, 8]),
+    # a key row makes every pair general; the pairs above the diagonal
+    # still are not run
+    ("kv_mask", [0, 0, 36, 8]),
+    # 2100 keys pad to 5 tiles: padded keys are a key row too
+    ("ragged", [0, 0, 15, 5]),
+    # not causal: the whole rectangle, and no mask to build
+    ("not_causal", [64, 0, 0, 8]),
+    ("not_causal_ragged", [0, 0, 25, 5]),
+    # 32 key blocks in float32 outgrow one resident stretch: five of 7, 7,
+    # 7, 7 and 4 blocks; a query tile's steps end at its diagonal's stretch
+    ("long_f32", [496, 32, 0, 90]),
+])
+def test_tiled_attention_counts_its_tiles_by_kind_when_traced(case, want):
+    s = jax.ShapeDtypeStruct
+    t = {"ragged": 2100, "not_causal_ragged": 2100,
+         "long_f32": 16384}.get(case, 4096)
+    dtype = jnp.float32 if case == "long_f32" else jnp.bfloat16
+    qkv = (s((2, 32, t, 128), dtype),) * 3
+    causal = "not_causal" not in case
+    if case == "kv_mask":
+        got = _tile_counts(
+            lambda q, k, v, m: fa.flash_attention(
+                q, k, v, kv_mask=m, causal=True, impl="pallas"),
+            *qkv, s((2, t), jnp.bool_))
+    else:
+        got = _tile_counts(
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=causal,
+                                               impl="pallas"), *qkv)
+    assert got == want
+
+
+def test_the_tile_plan_covers_the_causal_triangle_once():
+    # whatever the stretch, a query tile runs exactly the key blocks that
+    # hold a key at or below its last row, the mask-free ones first and only
+    # where every key is kept, and ends exactly once, on its last step.
+    # Unequal tiles (a short window: bq 112, bk 128) straddle the diagonal
+    # anywhere
+    for nq, blocks, sub, bq, bk in [(8, 8, 8, 512, 512), (8, 8, 3, 512, 512),
+                                    (3, 5, 2, 512, 512), (1, 1, 1, 112, 128),
+                                    (4, 2, 2, 256, 512), (5, 5, 1, 512, 512)]:
+        qi, kj, free, masked, last = fa._tile_plan(nq, blocks, sub, bq, bk,
+                                                   True, False)
+        assert last.sum() == nq and (np.diff(qi) >= 0).all()
+        assert (last[np.flatnonzero(np.diff(qi))] == 1).all() and last[-1]
+        ran = {}
+        for i, j, f, m in zip(qi, kj, free, masked):
+            assert f + m > 0 and j * sub + f + m <= blocks
+            for n, c in enumerate(range(j * sub, j * sub + f + m)):
+                assert (i, c) not in ran
+                ran[i, c] = n < f
+        for i in range(nq):
+            for c in range(blocks):
+                rows = np.arange(i * bq, (i + 1) * bq)[:, None]
+                keep = np.arange(c * bk, (c + 1) * bk)[None, :] <= rows
+                assert ((i, c) in ran) == keep.any()
+                if (i, c) in ran:
+                    assert ran[i, c] == keep.all()
+                    if bq == bk and not keep.all():
+                        assert (keep == np.tril(np.ones((bq, bk), bool))).all()
+    # a key row: nothing is mask-free; not causal: every block runs
+    _, _, free, masked, _ = fa._tile_plan(3, 5, 2, 512, 512, True, True)
+    assert free.sum() == 0 and masked.sum() == 1 + 2 + 3
+    _, _, free, masked, _ = fa._tile_plan(3, 5, 2, 512, 512, False, False)
+    assert free.sum() == 15 and masked.sum() == 0
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("masked", [False, True])
+def test_tiled_attention_across_several_resident_stretches(monkeypatch,
+                                                           masked):
+    # a VMEM budget that holds two key blocks a stretch: five blocks lie in
+    # stretches of 2, 2 and 1 (the last one short), the carry crosses them
+    monkeypatch.setattr(fa, "_resident_blocks", lambda blocks, *a: 2)
+    r = np.random.default_rng(26)
+    t = 2100 if masked else 2560
+    q, k, v = (jnp.asarray(r.normal(size=(1, 2, t, 16)), jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(r.random((1, t)) > 0.1) if masked else None
+    for causal in (True, False):
+        got = fa.flash_attention(q, k, v, kv_mask=mask, causal=causal,
+                                 impl="pallas")
+        np.testing.assert_allclose(
+            np.asarray(got), _xla_attention(q, k, v, kv_mask=mask,
+                                            causal=causal),
+            rtol=2e-5, atol=2e-6)

@@ -77,11 +77,19 @@ KERNEL_CASES = {
         (S((2, 8, 512, 64), F32),) * 3 + (S((2, 512, 512), jnp.bool_),)
         + (S((2, 8, 512, 1), F32),) * 2 + (S((2, 8, 512, 64), F32),)),
     # the language-model cell's window: past the whole-tile VMEM bound, so
-    # the tiled kernel (queries tiled too, causal tiles skipped)
+    # the tiled kernel (a grid step a query tile over keys resident in
+    # VMEM, its key blocks a loop: none above the diagonal, no mask below)
     "flash_attention_tiled_lm_t4096": (
         lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
                                            impl="pallas"),
         (S((2, 32, 4096, 128), BF16),) * 3),
+    # the same kernel where keys can be masked: a key row, a window that is
+    # not a whole number of tiles (2100 keys pad to five), every tile pair
+    # of the triangle general
+    "flash_attention_tiled_masked_t2100": (
+        lambda q, k, v, m: fa.flash_attention(q, k, v, kv_mask=m,
+                                              causal=True, impl="pallas"),
+        (S((2, 8, 2100, 128), BF16),) * 3 + (S((2, 2100), jnp.bool_),)),
     # the dropless expert layer's grouped products, reading one layer of
     # the stacked expert weights in place
     "moe_dropless_grouped_products": (
