@@ -69,15 +69,20 @@ def _near_one(key, shape, dtype):
             ).astype(dtype)
 
 
+def rms_norm(x, scale, eps: float):
+    """``x / rms(x) * scale`` over the last axis, statistics in float32."""
+    x = x.astype(jnp.float32)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale
+
+
 class RMSNorm(nn.Module):
     eps: float
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", _near_one, (x.shape[-1],), jnp.float32)
-        x = x.astype(jnp.float32)
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.eps) * scale
+        return rms_norm(x, scale, self.eps)
 
 
 class Linear(nn.Module):
@@ -409,19 +414,33 @@ def token_logprob(h, head, tokens, chunk: int):
     return jnp.concatenate([jnp.zeros((b, 1), jnp.float32), lp[:, :-1]], 1)
 
 
-def from_config(cfg: dict, **overrides) -> LatentMoELM:
-    """The module of a configuration dict under the published key names
-    (plus ``router_width`` / ``first_expert`` for a share and
-    ``compute_dtype`` / ``param_dtype``); ``overrides`` are further
-    :class:`LMConfig` fields."""
-    names = {f.name for f in dataclasses.fields(LMConfig)}
-    kw = {k: v for k, v in cfg.items()
-          if k in names and k not in ("rope_parameters", "param_dtype")}
-    kw["rope_parameters"] = tuple(sorted(cfg["rope_parameters"].items()))
+def config_fields(cls, cfg: dict, **overrides) -> dict:
+    """The fields of the config dataclass ``cls`` that the configuration
+    dict ``cfg`` gives under their own names, ``compute_dtype`` /
+    ``param_dtype`` as dtypes, lists as tuples; ``overrides`` last."""
+    names = {f.name for f in dataclasses.fields(cls)}
+    kw = {k: tuple(v) if isinstance(v, list) else v for k, v in cfg.items()
+          if k in names and k not in ("dtype", "param_dtype")
+          and not isinstance(v, dict)}
     kw["dtype"] = jnp.dtype(cfg.get("compute_dtype", "bfloat16"))
     kw["param_dtype"] = jnp.dtype(cfg.get("param_dtype", "bfloat16"))
     kw.update(overrides)
-    return LatentMoELM(LMConfig(**kw))
+    return kw
+
+
+def from_config(cfg: dict, **overrides) -> nn.Module:
+    """The module of a configuration dict under the published key names
+    (plus ``compute_dtype`` / ``param_dtype``): ``model_type: lfm2_moe``
+    gives :class:`~mmlspark_tpu.models.lm_conv.ConvMoELM`, anything else a
+    :class:`LatentMoELM` (with ``router_width`` / ``first_expert`` for a
+    share); ``overrides`` are further fields of the module's config."""
+    if cfg.get("model_type") == "lfm2_moe":
+        from mmlspark_tpu.models import lm_conv
+        return lm_conv.ConvMoELM(lm_conv.ConvLMConfig(
+            **config_fields(lm_conv.ConvLMConfig, cfg, **overrides)))
+    rope = tuple(sorted(cfg["rope_parameters"].items()))
+    return LatentMoELM(LMConfig(**config_fields(
+        LMConfig, cfg, **{"rope_parameters": rope, **overrides})))
 
 
 def publish_expert_load(load, tokens: int) -> dict:

@@ -60,6 +60,14 @@ the pair's indices; there is nothing to choose:
   head) are counted at trace time (``ops.pallas.attention_tiles{kind=}``,
   ``ops.pallas.attention_grid_steps``).
 
+**Grouped key/value heads.** ``flash_attention`` takes ``k`` and ``v`` with
+fewer heads than ``q`` (a divisor): query head ``h`` reads K/V head ``h //
+(H / H_kv)`` through both kernels' block index maps (:func:`_kv_head`), so
+no copy of K or V with the queries' head count is ever written, and the
+consecutive grid steps of one group name the same block, which is not
+fetched again. The group's size is a gauge set when a kernel call is traced
+(``ops.pallas.attention_kv_group``); the XLA reference repeats K and V.
+
 What reaches the kernel is shaped for the compiler: ``Tq`` is padded to
 the f32 sublane tile and ``Tk`` to a whole number of ``block_k`` stripes
 (padded keys are masked, padded query rows sliced away), the contraction
@@ -259,6 +267,17 @@ def flash_attention_host(q, k, v, mask3, scale,
 
 # ---- the Pallas kernels ----
 
+KV_GROUP_GAUGE = "ops.pallas.attention_kv_group"
+
+
+def _kv_head(head, group: int):
+    """The K/V head that query head ``head`` reads: grouped-query attention
+    is a block index map, never a repeated copy of K or V. Consecutive grid
+    steps of one group name the same block, which is then not fetched
+    again."""
+    return head if group == 1 else head // group
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, kv_ref, o_ref, *,
                   scale: np.float32, block_k: int, causal: bool):
     # one (batch, head) tile per program: refs arrive [1, 1, T, D] and
@@ -306,12 +325,16 @@ def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
+    group = h // k.shape[1]
     # Tq to whole f32 sublane tiles, Tk to whole block_k stripes
     q, k, v, kv_row = _padded_operands(q, k, v, kv_mask, _SUBLANES, block_k)
     tq_p, tk_p = q.shape[2], k.shape[2]
 
     def tile(i, j):
         return (i, j, 0, 0)
+
+    def kv_tile(i, j):
+        return (i, _kv_head(j, group), 0, 0)
 
     kern = functools.partial(_flash_kernel, scale=np.float32(scale),
                              block_k=block_k, causal=causal)
@@ -320,8 +343,8 @@ def _flash_call(q, k, v, kv_mask, causal: bool, scale, block_k: int):
         grid=(b, h),
         in_specs=[
             pl.BlockSpec((1, 1, tq_p, d), tile, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk_p, d), tile, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, tk_p, d), tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, tk_p, d), kv_tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, tk_p, d), kv_tile, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, tk_p), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
@@ -492,6 +515,7 @@ def _tiled_call(q, k, v, kv_mask, causal: bool, scale):
 
     b, h, tq, d = q.shape
     tk, dv = k.shape[2], v.shape[3]
+    group = h // k.shape[1]
     bq = min(TILE_Q, tq + (-tq % 16))
     bk = min(TILE_K, tk + (-tk % 128))
     q, k, v, kv_row = _padded_operands(q, k, v, kv_mask, bq, bk)
@@ -510,7 +534,7 @@ def _tiled_call(q, k, v, kv_mask, causal: bool, scale):
         return (b_, h_, qi[s], 0)
 
     def key_stretch(b_, h_, s, qi, kj, *_):
-        return (b_, h_, kj[s], 0)
+        return (b_, _kv_head(h_, group), kj[s], 0)
 
     in_specs = [pl.BlockSpec((1, 1, bq, d), query_tile),
                 pl.BlockSpec((1, 1, sub * bk, d), key_stretch),
@@ -592,18 +616,30 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
                     block_k: int = DEFAULT_BLOCK_K):
     """Fused attention over ``[B, H, T, D]`` operands (bhtd layout —
     what :class:`~mmlspark_tpu.models.vit.BhtdSelfAttention` computes
-    in). ``kv_mask``: ``[B, Tk]`` bool key-validity mask (True = real
-    key); ``causal`` adds the triangular constraint. Returns
-    ``[B, H, Tq, D]`` float32 (callers cast back to their compute
+    in). ``k`` and ``v`` may have fewer heads, ``[B, H_kv, Tk, D]`` with
+    ``H_kv`` dividing ``H`` (grouped-query attention): query head ``h``
+    meets K/V head ``h // (H / H_kv)``, read where it lies (the kernels'
+    block index maps; ``ops.pallas.attention_kv_group`` holds ``H / H_kv``
+    of the last call traced). ``kv_mask``: ``[B, Tk]`` bool key-validity
+    mask (True = real key); ``causal`` adds the triangular constraint.
+    Returns ``[B, H, Tq, D]`` float32 (callers cast back to their compute
     dtype); fully-masked query rows are exact zeros."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
+    if k.shape[1] != v.shape[1] or h % k.shape[1]:
+        raise ValueError(f"{h} query heads against {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads: K and V need the same "
+                         "head count, a divisor of the queries'")
+    group = h // k.shape[1]
     s = _resolve_scale(scale, d)
     if resolve_impl(impl) == "pallas":
+        _obs_registry().gauge(KV_GROUP_GAUGE).set(group)
         if _fits_vmem(tq, tk, d, block_k) and v.shape[3] == d:
             return _flash_call(q, k, v, kv_mask, causal, s, block_k)
         # the whole (batch, head) tile outgrows VMEM: tile the queries too
         return _tiled_call(q, k, v, kv_mask, causal, s)
+    if group > 1:        # the reference meets every query head with a copy
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     return flash_attention_reference(
         q, k, v, _mask3(b, tq, tk, kv_mask, causal), s, block_k)
 

@@ -49,10 +49,11 @@ EXPERT_IMPLS = ("auto", "ragged", "gmm")
 
 # the Pallas grouped product's tiles: rows of the sorted pairs, and the
 # elements of a weight tile (the contraction whole up to GMM_WHOLE_K, else
-# half of that). Read on one v5e chip at [32768, 4096] x [192, 4096, 2048]
-# with ~256 rows a group: 256 rows beat 128 and 512 (a row tile is computed
-# whole for every group that touches it), and these weight tiles beat the
-# smaller ones (PERF.md section 6, PR 28)
+# within half of that). Read on one v5e chip at [32768, 4096] x [192, 4096,
+# 2048] with ~256 rows a group: 256 rows beat 128 and 512 (a row tile is
+# computed whole for every group that touches it), and these weight tiles
+# beat the smaller ones (PERF.md section 6, PR 28). ``gmm_tiles`` derives
+# the tiles of any shape from them: whole lane rows that divide the axis
 GMM_ROWS = 256
 GMM_WEIGHT_TILE = 2 ** 21
 GMM_WHOLE_K = 2048
@@ -262,20 +263,61 @@ def moe_reference(params: dict, x: Any) -> Any:
 
 # ---- the dropless top-k layer of one expert-parallel share ----
 
+ROUTER_SCORES = ("softmax", "sigmoid")
+
+
 def route_topk(x: Any, router: Any, top_k: int, norm_topk: bool = True,
-               scaling: float = 1.0) -> tuple[Any, Any]:
-    """``(picks [N, k] int32, weights [N, k] float32)``: softmax over the
-    router's whole width in float32, the ``top_k`` largest, the weights
-    normalised over the picks (``norm_topk``) and times ``scaling``."""
+               scaling: float = 1.0, score: str = "softmax",
+               bias: Any = None, norm_eps: float = 0.0) -> tuple[Any, Any]:
+    """``(picks [N, k] int32, weights [N, k] float32)``: the router's
+    logits over its whole width in float32, scored by ``score`` (a softmax
+    over the width, or a sigmoid of each logit); the ``top_k`` largest of
+    ``score + bias`` (``bias`` ``[E]``: a selection bias, which moves the
+    picks and never the weights) are the picks; their weights are the
+    unbiased scores, normalised over the picks (``norm_topk``: divided by
+    their sum plus ``norm_eps``) and times ``scaling``."""
     import jax
     import jax.numpy as jnp
 
+    if score not in ROUTER_SCORES:
+        raise ValueError(f"unknown router score {score!r}; one of "
+                         f"{ROUTER_SCORES}")
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    weights, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if bias is None:
+        weights, picks = jax.lax.top_k(scores, top_k)
+    else:
+        _, picks = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(scores, picks, axis=-1)
     if norm_topk:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
     return picks.astype(jnp.int32), weights * scaling
+
+
+def _lane_tile(width: int, most: int) -> int:
+    """The tile of a grouped product's contraction or result axis of
+    ``width`` elements, at most ``most``: the axis whole where it fits;
+    else the largest whole number of 128-lane rows within ``most`` that
+    divides it (1792 = 14 x 128 under 1024: 896), so that no tile is part
+    empty; else the largest whole number of lane rows within ``most``."""
+    if width <= most:
+        return width
+    lanes = most // 128
+    return 128 * next((t for t in range(lanes, 0, -1)
+                       if width % (128 * t) == 0), lanes)
+
+
+def gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` of the Pallas grouped product ``[m, k] x [G, k,
+    n]``, from the shapes alone: ``GMM_ROWS`` rows; the contraction whole
+    up to ``GMM_WHOLE_K`` and within half of it past that; the result's
+    tile within what ``GMM_WEIGHT_TILE`` elements leave beside ``tk``; both
+    by :func:`_lane_tile`, so whole lane rows that divide their axis."""
+    tk = _lane_tile(k, GMM_WHOLE_K if k <= GMM_WHOLE_K else GMM_WHOLE_K // 2)
+    return min(GMM_ROWS, m), tk, _lane_tile(n, GMM_WEIGHT_TILE // tk)
 
 
 def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
@@ -299,9 +341,7 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
     # the kernel's dynamic grid visits only the tiles that hold rows of a
     # group, so the work follows the pairs really routed here
     (m, k), n = lhs.shape, rhs.shape[2]
-    tk = k if k <= GMM_WHOLE_K else GMM_WHOLE_K // 2
-    tiling = (min(GMM_ROWS, m), tk, min(n, GMM_WEIGHT_TILE // tk))
-    return gmm(lhs, rhs, group_sizes, out_dtype, tiling)
+    return gmm(lhs, rhs, group_sizes, out_dtype, gmm_tiles(m, k, n))
 
 
 def bucket_ladder(pairs: int, held: int, width: int) -> tuple[int, ...]:
@@ -334,7 +374,9 @@ def _piece(rows: int, most: int) -> int:
 def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                  first_expert: int = 0, norm_topk: bool = True,
                  scaling: float = 1.0, impl: str = "auto",
-                 layer: Any = None) -> tuple[Any, Any, Any]:
+                 layer: Any = None, score: str = "softmax",
+                 bias: Any = None, norm_eps: float = 0.0
+                 ) -> tuple[Any, Any, Any]:
     """The routed part of a top-k expert layer on the share that holds
     experts ``[first_expert, first_expert + held)`` of the router's width.
 
@@ -346,7 +388,8 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     that layer's; every other layer's are empty), where slicing the layer
     out first would copy its weights every step, as a scan over stacked
     weights does for an operand of a kernel. Every token routes over all
-    ``E``; the (token, expert)
+    ``E`` (``score``, ``bias`` and ``norm_eps`` as in :func:`route_topk`);
+    the (token, expert)
     pairs that land on a held expert are sorted by expert (the absent ones
     last) and run through one grouped matrix product per stack; a pick of an
     absent expert adds nothing here (its chip adds it in the deployment).
@@ -379,7 +422,8 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                          f"{EXPERT_IMPLS}")
     n, d = x.shape
     held = experts["gate"].shape[-3]
-    picks, weights = route_topk(x, router, top_k, norm_topk, scaling)
+    picks, weights = route_topk(x, router, top_k, norm_topk, scaling, score,
+                                bias, norm_eps)
     local = picks - first_expert
     group = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
     # pairs of held experts first, by expert; the absent ones last

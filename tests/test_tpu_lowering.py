@@ -107,6 +107,22 @@ KERNEL_CASES = {
         (S((8192, 4096), BF16), S((4096, 128), F32),
          S((2, 32, 4096, 2048), BF16), S((2, 32, 4096, 2048), BF16),
          S((2, 32, 2048, 4096), BF16))),
+    # the conv / grouped-query family's cell: 32 query heads over 8 shared
+    # key/value heads of 64 at 8192 positions (K and V are read by the
+    # kernel's index map, never repeated), and its three grouped products
+    # at d 2048, f 1792: a width that is 14 lane rows and no power of two
+    "flash_attention_tiled_grouped_heads_t8192": (
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           impl="pallas"),
+        (S((2, 32, 8192, 64), BF16), S((2, 8, 8192, 64), BF16),
+         S((2, 8, 8192, 64), BF16))),
+    "moe_dropless_grouped_products_f1792": (
+        lambda x, r, b, g, u, d: moe_dropless(
+            x, r, {"gate": g, "up": u, "down": d}, top_k=4, impl="gmm",
+            layer=1, score="sigmoid", bias=b, norm_eps=1e-6)[0],
+        (S((16384, 2048), BF16), S((2048, 32), F32), S((32,), F32),
+         S((2, 32, 2048, 1792), BF16), S((2, 32, 2048, 1792), BF16),
+         S((2, 32, 1792, 2048), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -130,6 +146,16 @@ def test_kernel_lowers_for_tpu(name):
         # pieces of the middle one and shares its kernels)
         assert "stablehlo.case" in text and "stablehlo.while" in text
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
+    if name == "moe_dropless_grouped_products_f1792":
+        # every expert held: one rung, so no conditional; gate and up share
+        # a kernel, down has its own
+        assert "stablehlo.case" not in text
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    if name == "flash_attention_tiled_grouped_heads_t8192":
+        # the kernel's K and V operands keep their 8 heads
+        assert "tensor<2x8x8192x64xbf16>" in text
+        assert "tensor<2x32x8192x64xbf16>, tensor<2x32x8192x64xbf16>, " \
+            "tensor<2x32x8192x64xbf16>" not in text
 
 
 def test_no_kernel_wrapper_chooses_interpret_mode():
