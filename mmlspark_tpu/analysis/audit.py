@@ -203,9 +203,13 @@ def standalone_crossings(stage: Any, schema: Any, n_rows: int | None
         from mmlspark_tpu.core import plan
         from mmlspark_tpu.core.stage import ArrayMeta
 
-        # the segment of one its transform runs (the block as coerced has
-        # the model's own spec; the crossings do not depend on its dtype)
-        spec = ArrayMeta(tuple(stage.model.input_spec), "float32")
+        # the segment of one its transform runs: the block as coerced has
+        # the model's own spec, and stays uint8 only where the column is
+        # (its bytes decide whether a minibatch crosses in pieces)
+        info = schema.get(stage.input_col)
+        spec = ArrayMeta(tuple(stage.model.input_spec),
+                         "uint8" if info is not None
+                         and info.dtype == "uint8" else "float32")
         seg = plan.collect_segment([stage], 0, lambda _col: spec,
                                    min_stages=1)
         return plan.predict_segment_minibatches(seg, n_rows)

@@ -913,7 +913,7 @@ def audit_plan_spmd(stages: list, meta_of: Callable,
         dp = plan.mesh_dp(seg_mesh)
         composite, params_tuple = plan_segment_composite(seg)
         size, _ = plan._segment_minibatch(seg)
-        mb_rows = plan.dp_rounded_minibatch(size, dp, n_rows or size)
+        mb_rows = plan.segment_entry_rows(seg, n_rows or size)
         entry = jax.ShapeDtypeStruct(
             (mb_rows,) + tuple(seg.entry_meta.shape),
             seg.entry_meta.dtype)

@@ -6,7 +6,8 @@ The host pipeline walks stages one at a time, so a device-heavy chain
 partitions a stage list into
 maximal runs of :class:`~mmlspark_tpu.core.stage.DeviceStage`-capable
 stages and compiles each run into ONE jitted composite: a single H2D
-upload per minibatch, one fused XLA program, and one async-windowed D2H
+upload per minibatch (per piece of one whose upload is long:
+:func:`piece_rows`), one fused XLA program, and one async-windowed D2H
 fetch round (the ``copy_to_host_async``/``max_inflight`` software pipeline
 of :func:`pipeline_minibatches`).
 
@@ -75,12 +76,13 @@ def minibatches(batch: np.ndarray, size: int
 #      minibatch pipeline goes through these two functions, so crossing
 #      counts are observable: tools/perf_smoke.py monkeypatches them, and
 #      the obs registry counts them — plan.h2d_uploads / plan.h2d_bytes /
-#      plan.d2h_bytes always (the boundary tier's counters), and with
+#      plan.d2h_bytes always (the boundary tier's counters, with
+#      plan.split_minibatches beside them in pipeline_minibatches), and with
 #      tracing on plan.d2h_fetches plus one plan.h2d_shapes series per
 #      distinct upload shape (the recompile observable) ----
 
 def _upload(chunk: np.ndarray, target: Any) -> Any:
-    """ONE host→device transfer of one minibatch."""
+    """ONE host→device transfer of one minibatch, or one piece of it."""
     import jax
     nbytes = int(getattr(chunk, "nbytes", 0))
     reg = _obs_registry()
@@ -255,9 +257,23 @@ def pipeline_minibatches(fn: Callable, dev_params: Any, batch: np.ndarray,
     ``fn`` may return one array or a tuple (a fused segment materializes
     every column its stages write). Returns one trimmed, concatenated host
     array per output.
+
+    A minibatch whose upload is long crosses in row pieces
+    (:func:`piece_rows`): the window is walked a piece at a time, each its
+    own upload → call → fetch round through the same seams, so the device
+    works on the first piece while the rest is on the link instead of
+    idling until a whole minibatch has landed. ``size`` stays the memory
+    bound it is: no piece is larger, and ``max_inflight`` still counts
+    minibatches' worth of outputs. Batch execution only: the serving entry
+    (:func:`dispatch_segment`) compiles no shape but its bucket ladder's.
     """
+    piece = piece_rows(size, batch[:1].nbytes, _target_dp(target))
+    if piece < size:
+        _obs_registry().counter("plan.split_minibatches").add(
+            -(-len(batch) // size))
     pieces, _shapes, drain_rest = _windowed_dispatch(
-        fn, dev_params, batch, size, target, max_inflight, label=label)
+        fn, dev_params, batch, piece, target,
+        max_inflight * (size // piece), label=label)
     drain_rest()
     with _obs_boundary("transform/assemble", "plan"):
         return _assemble_outputs(pieces)
@@ -717,19 +733,52 @@ def dp_rounded_minibatch(size: int, dp: int, n_rows: int) -> int:
     return -(-min(int(size), n_rows) // dp) * dp
 
 
+def _target_dp(target: Any) -> int:
+    """:func:`mesh_dp` of a transfer target of :func:`_compile_segment`: a
+    batch sharding over the segment's mesh, or one device."""
+    mesh = getattr(target, "mesh", None)
+    return 1 if mesh is None else mesh_dp(mesh)
+
+
+def piece_rows(size: int, row_nbytes: int, dp: int) -> int:
+    """Rows the device is handed at once of a ``size``-row minibatch of
+    ``row_nbytes`` a row: ``size`` itself while the minibatch is no more
+    than ``_PIECE_MAX_BYTES`` (it crosses whole), else halved until a piece
+    is, as long as the half is still a dp multiple. A power-of-two fraction
+    that tiles the minibatch: a call compiles one entry shape, the padded
+    tail rule of :func:`minibatches` is untouched, and every chip gets
+    rows. Shared with the pre-flight crossing predictor like
+    :func:`dp_rounded_minibatch`, so prediction cannot drift from
+    execution."""
+    rows = int(size)
+    while rows * row_nbytes > _PIECE_MAX_BYTES and rows % (2 * dp) == 0:
+        rows //= 2
+    return rows
+
+
+def segment_entry_rows(seg: _Segment, n_rows: int) -> int:
+    """Rows of the ONE entry shape a fused run of ``seg`` over ``n_rows``
+    rows uploads and compiles for: the dp-rounded minibatch, or the piece
+    of it where its bytes have it cross in pieces (:func:`piece_rows`)."""
+    size, _ = _segment_minibatch(seg)
+    dp = mesh_dp(_segment_mesh(seg))
+    meta = seg.entry_meta
+    row_nbytes = int(np.prod(meta.shape, dtype=np.int64)
+                     ) * np.dtype(meta.dtype).itemsize
+    return piece_rows(dp_rounded_minibatch(size, dp, n_rows), row_nbytes, dp)
+
+
 def predict_segment_minibatches(seg: _Segment, n_rows: int) -> int:
-    """How many fixed-shape minibatches a fused run of ``seg`` over
-    ``n_rows`` rows costs — one H2D upload and one async D2H fetch round
-    each. Same sizing arithmetic as :func:`_run_segment` via the shared
-    helpers, without compiling or transferring anything. Note: reading the
-    segment's mesh initializes the jax backend (device *enumeration*, not
-    execution) — pre-flight callers on shared hosts should pin
-    ``JAX_PLATFORMS=cpu``."""
+    """How many uploads a fused run of ``seg`` over ``n_rows`` rows costs:
+    one H2D upload and one async D2H fetch round a fixed-shape minibatch,
+    or a piece of one (:func:`segment_entry_rows`). Same sizing arithmetic
+    as :func:`_run_segment` via the shared helpers, without compiling or
+    transferring anything. Note: reading the segment's mesh initializes
+    the jax backend (device *enumeration*, not execution) — pre-flight
+    callers on shared hosts should pin ``JAX_PLATFORMS=cpu``."""
     if n_rows <= 0:
         return 0
-    size, _ = _segment_minibatch(seg)
-    size = dp_rounded_minibatch(size, mesh_dp(_segment_mesh(seg)), n_rows)
-    return -(-n_rows // size)
+    return -(-n_rows // segment_entry_rows(seg, n_rows))
 
 
 # compiled segments kept per cache_host; LRU-capped so streaming sources
@@ -737,6 +786,14 @@ def predict_segment_minibatches(seg: _Segment, n_rows: int) -> int:
 # device-resident param copies (each evicted entry releases its device
 # tree)
 _PLAN_CACHE_MAX = 8
+
+# a minibatch of more bytes than this crosses to the device in pieces of at
+# most this many (piece_rows). Measured on a v5e with ResNet-50 on 150,528-
+# byte rows (PERF.md section 6, PR 34): an upload of 18 MiB lands in 4 ms
+# and one of 294 MiB in 54 ms, through which the device idles at the head
+# of every call; and the composite's device time a row is least on pieces
+# this size (73 us at 128 rows, 84 at 256, 80 at 64, 93 at 2,048)
+_PIECE_MAX_BYTES = 32 << 20
 
 
 def _reuploaded(seg: _Segment, entry: tuple, tokens: tuple) -> tuple | None:

@@ -221,6 +221,8 @@ METRIC_HELP: dict[str, str] = {
                         "plan executor (one per fused-segment entry).",
     "plan.h2d_bytes": "Bytes shipped host-to-device at the plan's "
                       "upload seam.",
+    "plan.split_minibatches": "Minibatches of batch transform calls "
+                              "dispatched in row pieces (a long upload).",
     "plan.d2h_fetches": "Async device-to-host fetch rounds issued by "
                         "the plan executor.",
     "plan.d2h_bytes": "Bytes fetched device-to-host at the plan's "
