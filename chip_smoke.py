@@ -25,7 +25,8 @@ compiler:
   kernel;
 * **kernels** — ``decode_attention``, ``flash_attention`` (ViT-B tile,
   causal T=1024, and the tiled kernel at a causal T=4096 and a masked
-  T=2100), ``attention_block_update``, ``group_norm``, each against its
+  T=2100), ``attention_block_update``, ``group_norm``, and the selective
+  scan at one row of 16,384 positions x 5,120 channels, each against its
   XLA reference.
 
 With more than one device visible the multi-device branches switch on:
@@ -106,6 +107,8 @@ KERNEL_RTOL = 2e-2
 # GroupNorm emits bf16: one output rounding (2^-8 relative) on values up
 # to ~4 sigma, both sides accumulating statistics in f32.
 GN_ATOL = 6e-2
+# the selective scan's gated output in bfloat16, values up to ~20
+SCAN_ATOL = 5e-2
 
 
 class SmokeFailure(Exception):
@@ -612,6 +615,7 @@ def phase_kernels(devices) -> dict:
     from mmlspark_tpu.ops.group_norm import group_norm, group_norm_reference
     from mmlspark_tpu.ops.pallas import attention as fa
     from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
+    from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
 
     rng = np.random.default_rng(4)
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -723,6 +727,23 @@ def phase_kernels(devices) -> dict:
         lambda a, s, b: group_norm(a, s, b, G, relu=True),
         lambda a, s, b: group_norm_reference(a, s, b, G, relu=True),
         (x, gamma, beta), GN_ATOL, KERNEL_RTOL)
+
+    # the selective scan at one row of the state-space cell: 16,384
+    # positions in 64 chunks whose state is carried in VMEM, 5,120 channels,
+    # 16 states; step sizes and decay rates as the mixer hands them
+    L, C, NS = 16384, 5120, 16
+    delta = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                           (1, L, C))), f32)
+    a = -jnp.asarray(np.tile(np.arange(1, NS + 1, dtype=np.float32), (C, 1)))
+    args = (_bf16_exact(rng, (1, L, C), bf16), delta, a,
+            _bf16_exact(rng, (1, L, NS), f32), _bf16_exact(rng, (1, L, NS), f32),
+            1 + 0.1 * _bf16_exact(rng, (C,), f32),
+            _bf16_exact(rng, (1, L, C), bf16))
+    facts["selective_scan[L16384,C5120,N16,bf16]"] = _kernel_case(
+        "selective_scan",
+        lambda *o: selective_scan(*o, impl="pallas").astype(f32),
+        lambda *o: selective_scan(*o, impl="xla").astype(f32),
+        args, SCAN_ATOL, KERNEL_RTOL)
 
     # no wrapper, here or in any earlier phase, may have given way to its
     # reference over a VMEM estimate

@@ -428,19 +428,38 @@ def config_fields(cls, cfg: dict, **overrides) -> dict:
     return kw
 
 
-def from_config(cfg: dict, **overrides) -> nn.Module:
-    """The module of a configuration dict under the published key names
-    (plus ``compute_dtype`` / ``param_dtype``): ``model_type: lfm2_moe``
-    gives :class:`~mmlspark_tpu.models.lm_conv.ConvMoELM`, anything else a
-    :class:`LatentMoELM` (with ``router_width`` / ``first_expert`` for a
-    share); ``overrides`` are further fields of the module's config."""
-    if cfg.get("model_type") == "lfm2_moe":
-        from mmlspark_tpu.models import lm_conv
-        return lm_conv.ConvMoELM(lm_conv.ConvLMConfig(
-            **config_fields(lm_conv.ConvLMConfig, cfg, **overrides)))
+def _latent_lm(cfg: dict, overrides: dict) -> nn.Module:
     rope = tuple(sorted(cfg["rope_parameters"].items()))
     return LatentMoELM(LMConfig(**config_fields(
         LMConfig, cfg, **{"rope_parameters": rope, **overrides})))
+
+
+def _conv_lm(cfg: dict, overrides: dict) -> nn.Module:
+    from mmlspark_tpu.models import lm_conv
+    return lm_conv.ConvMoELM(lm_conv.ConvLMConfig(
+        **config_fields(lm_conv.ConvLMConfig, cfg, **overrides)))
+
+
+def _jamba_lm(cfg: dict, overrides: dict) -> nn.Module:
+    from mmlspark_tpu.models import lm_ssm
+    return lm_ssm.JambaLM(lm_ssm.JambaConfig(
+        **config_fields(lm_ssm.JambaConfig, cfg, **overrides)))
+
+
+# ``model_type`` -> the family's builder; a type that is not here is built
+# as a LatentMoELM (``mistral4`` / DeepSeek-V3-style keys)
+FAMILIES = {"lfm2_moe": _conv_lm, "jamba": _jamba_lm}
+
+
+def from_config(cfg: dict, **overrides) -> nn.Module:
+    """The module of a configuration dict under the published key names
+    (plus ``compute_dtype`` / ``param_dtype``), by its ``model_type``
+    (:data:`FAMILIES`): ``lfm2_moe`` gives a :class:`~mmlspark_tpu.models.
+    lm_conv.ConvMoELM`, ``jamba`` a :class:`~mmlspark_tpu.models.lm_ssm.
+    JambaLM`, anything else a :class:`LatentMoELM` (with ``router_width`` /
+    ``first_expert`` for a share); ``overrides`` are further fields of the
+    module's config."""
+    return FAMILIES.get(cfg.get("model_type"), _latent_lm)(cfg, overrides)
 
 
 def publish_expert_load(load, tokens: int) -> dict:

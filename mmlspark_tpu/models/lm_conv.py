@@ -169,41 +169,58 @@ def _dot(x, w, dtype):
                    preferred_element_type=jnp.float32)
 
 
+def causal_taps(z, taps, bias=None):
+    """The depthwise causal convolution of ``z`` ``[B, L, channels]`` with
+    ``taps`` ``[K, channels]`` (float32): ``out_t = sum_j taps[j] * z[t -
+    (K - 1) + j]`` (+ ``bias`` a channel), ``z`` zero before the row's
+    start. The one tap loop of both families: the gated short convolution
+    (3 taps, no bias) and the Mamba mixer's (4 taps, a bias, SiLU after)."""
+    n, lead = z.shape[1], taps.shape[0] - 1
+    padded = jnp.pad(z, ((0, 0), (lead, 0), (0, 0)))
+    mixed = taps[lead] * z
+    for j in range(lead):
+        mixed = mixed + taps[j] * padded[:, j:j + n]
+    return mixed if bias is None else mixed + bias
+
+
 def short_conv(p: dict, x, c: ConvLMConfig):
     """The gated short convolution on normed ``x`` ``[B, L, d]``."""
-    d, taps = c.hidden_size, p["taps"].astype(jnp.float32)
-    n = x.shape[1]
+    d = c.hidden_size
     with jax.named_scope("lm/conv/in"):
         bcu = _dot(x, p["in_proj"], c.dtype)
     with jax.named_scope("lm/conv/mix"):
         gate_b, gate_c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
-        z = gate_b * u
-        # tap j meets z[t - (taps - 1) + j]; before the row's start z is 0
-        lead = taps.shape[0] - 1
-        padded = jnp.pad(z, ((0, 0), (lead, 0), (0, 0)))
-        mixed = taps[lead] * z
-        for j in range(lead):
-            mixed = mixed + taps[j] * padded[:, j:j + n]
+        mixed = causal_taps(gate_b * u, p["taps"].astype(jnp.float32))
         y = (gate_c * mixed).astype(c.dtype)
     with jax.named_scope("lm/conv/out"):
         return _dot(y, p["out_proj"], c.dtype)
 
 
-def grouped_attention(p: dict, x, positions, c: ConvLMConfig):
-    """Grouped-query attention on normed ``x`` ``[B, L, d]``."""
+def grouped_attention(p: dict, x, positions, c):
+    """Causal attention of ``c.num_attention_heads`` query heads on
+    ``c.num_key_value_heads`` key/value heads, on normed ``x`` ``[B, L,
+    d]``. What it does beyond the projections and the core follows what it
+    is handed: per-head q/k RMSNorms where the tree ``p`` holds their
+    scales (``q_norm`` / ``k_norm``; eps ``c.norm_eps``), half-split RoPE
+    (``c.rope_theta``) where ``positions`` are given and no positional term
+    where they are ``None``. The core's scope is ``lm/mqa/attention`` on one
+    key/value head, ``lm/gqa/attention`` on more."""
     b, n, _ = x.shape
     h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     q = _dot(x, p["q"], c.dtype).reshape(b, n, h, hd)
     k = _dot(x, p["k"], c.dtype).reshape(b, n, hkv, hd)
     v = _dot(x, p["v"], c.dtype).reshape(b, n, hkv, hd)
-    cos, sin = rope_tables(positions, hd, c.rope_theta)
-    q = apply_rope_half(rms_norm(q, p["q_norm"], c.norm_eps), cos, sin)
-    k = apply_rope_half(rms_norm(k, p["k_norm"], c.norm_eps), cos, sin)
+    if "q_norm" in p:
+        q = rms_norm(q, p["q_norm"], c.norm_eps)
+        k = rms_norm(k, p["k_norm"], c.norm_eps)
+    if positions is not None:
+        cos, sin = rope_tables(positions, hd, c.rope_theta)
+        q, k = apply_rope_half(q, cos, sin), apply_rope_half(k, cos, sin)
 
     def bhtd(a):
         return a.astype(c.dtype).transpose(0, 2, 1, 3)
 
-    with jax.named_scope("lm/gqa/attention"):
+    with jax.named_scope(f"lm/{'mqa' if hkv == 1 else 'gqa'}/attention"):
         o = flash_attention(bhtd(q), bhtd(k), bhtd(v), causal=True,
                             scale=hd ** -0.5)
     return _dot(o.transpose(0, 2, 1, 3).reshape(b, n, h * hd), p["o"],

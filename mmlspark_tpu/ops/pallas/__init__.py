@@ -10,6 +10,12 @@ VMEM-resident formulation avoids HBM traffic the default lowering pays:
   and the q_len=1 decode step of ``serve/generate.py``, replacing three
   HBM materializations of the ``[B, H, Tq, Tk]`` score matrix.
 
+* :mod:`~mmlspark_tpu.ops.pallas.selective_scan` — the selective
+  state-space recurrence of ``models/lm_ssm.py`` in chunks of the
+  sequence, its ``[channels, states]`` state carried in VMEM instead of
+  two ``[L, channels, states]`` tensors in HBM (imported from its module:
+  the function has the module's name).
+
 (The fused GroupNorm kernel lives next to its reference in
 ``ops/group_norm.py``.)
 

@@ -36,6 +36,7 @@ import chip_smoke  # noqa: E402 — the shapes under test are the smoke's own
 
 from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
+from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
 from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
 
 S = jax.ShapeDtypeStruct
@@ -123,6 +124,15 @@ KERNEL_CASES = {
         (S((16384, 2048), BF16), S((2048, 32), F32), S((32,), F32),
          S((2, 32, 2048, 1792), BF16), S((2, 32, 2048, 1792), BF16),
          S((2, 32, 1792, 2048), BF16))),
+    # the state-space family's cell: one row of 16,384 positions, 5,120
+    # channels (five blocks of 1,024), 16 states; 64 chunks of 256 carry the
+    # state in VMEM scratch, B and C arrive as scalars in SMEM
+    "selective_scan_t16384_d5120_n16": (
+        lambda u, dt, a, b, c, d, z: selective_scan(u, dt, a, b, c, d, z,
+                                                    impl="pallas"),
+        (S((1, 16384, 5120), BF16), S((1, 16384, 5120), F32),
+         S((5120, 16), F32), S((1, 16384, 16), F32), S((1, 16384, 16), F32),
+         S((5120,), F32), S((1, 16384, 5120), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -151,6 +161,11 @@ def test_kernel_lowers_for_tpu(name):
         # a kernel, down has its own
         assert "stablehlo.case" not in text
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    if name == "selective_scan_t16384_d5120_n16":
+        # the operands go in as they are: no padded or re-laid copy of a
+        # [1, 16384, 5120] array, nothing of shape [L, 5120, 16]
+        assert "stablehlo.pad" not in text
+        assert "16384x5120x16" not in text
     if name == "flash_attention_tiled_grouped_heads_t8192":
         # the kernel's K and V operands keep their 8 heads
         assert "tensor<2x8x8192x64xbf16>" in text
