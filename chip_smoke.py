@@ -615,6 +615,7 @@ def phase_kernels(devices) -> dict:
     from mmlspark_tpu.ops.group_norm import group_norm, group_norm_reference
     from mmlspark_tpu.ops.pallas import attention as fa
     from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
+    from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
     from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
 
     rng = np.random.default_rng(4)
@@ -744,6 +745,29 @@ def phase_kernels(devices) -> dict:
         lambda *o: selective_scan(*o, impl="pallas").astype(f32),
         lambda *o: selective_scan(*o, impl="xla").astype(f32),
         args, SCAN_ATOL, KERNEL_RTOL)
+
+    # the short causal convolution at both families' cells, each reading
+    # the wide float32 product where it lies: the Mamba mixer's (4 taps,
+    # bias, SiLU, the gate half cast beside it) and the gated one (3 taps,
+    # [B | C | u], two rows a step); one bfloat16 rounding apart at most
+    def conv(impl, **parts):
+        return lambda wide, taps, bias=None: causal_conv(
+            wide, taps, bias=bias, dtype=bf16, impl=impl, **parts)
+
+    mamba = dict(channels=5120, cast_at=5120, silu=True)
+    args = (_bf16_exact(rng, (1, 16384, 10240), f32),
+            _bf16_exact(rng, (4, 5120), bf16) / 2,
+            _bf16_exact(rng, (5120,), f32) / 4)
+    facts["causal_conv[L16384,C5120of10240,K4,bias,silu]"] = _kernel_case(
+        "causal_conv", conv("pallas", **mamba), conv("xla", **mamba), args,
+        KERNEL_ATOL, KERNEL_RTOL)
+    gated = dict(channels=2048, at=4096, pre_at=0, post_at=2048)
+    args = (_bf16_exact(rng, (2, 8192, 6144), f32),
+            _bf16_exact(rng, (3, 2048), bf16) / 2)
+    facts["causal_conv[B2,L8192,C2048of6144,K3,gated]"] = _kernel_case(
+        "causal_conv", conv("pallas", **gated), conv("xla", **gated), args,
+        KERNEL_ATOL, KERNEL_RTOL)
+    del args
 
     # no wrapper, here or in any earlier phase, may have given way to its
     # reference over a VMEM estimate

@@ -59,6 +59,11 @@ from mmlspark_tpu.models.lm import (
 )
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
 from mmlspark_tpu.ops.pallas.attention import flash_attention
+# ``causal_taps`` is the convolution's array-code reference, kept under the
+# name both families' tests know it by
+from mmlspark_tpu.ops.pallas.causal_conv import (  # noqa: F401
+    causal_conv, causal_taps,
+)
 from mmlspark_tpu.parallel.moe import moe_dropless
 
 LAYER_KINDS = ("conv", "full_attention")
@@ -169,29 +174,15 @@ def _dot(x, w, dtype):
                    preferred_element_type=jnp.float32)
 
 
-def causal_taps(z, taps, bias=None):
-    """The depthwise causal convolution of ``z`` ``[B, L, channels]`` with
-    ``taps`` ``[K, channels]`` (float32): ``out_t = sum_j taps[j] * z[t -
-    (K - 1) + j]`` (+ ``bias`` a channel), ``z`` zero before the row's
-    start. The one tap loop of both families: the gated short convolution
-    (3 taps, no bias) and the Mamba mixer's (4 taps, a bias, SiLU after)."""
-    n, lead = z.shape[1], taps.shape[0] - 1
-    padded = jnp.pad(z, ((0, 0), (lead, 0), (0, 0)))
-    mixed = taps[lead] * z
-    for j in range(lead):
-        mixed = mixed + taps[j] * padded[:, j:j + n]
-    return mixed if bias is None else mixed + bias
-
-
 def short_conv(p: dict, x, c: ConvLMConfig):
     """The gated short convolution on normed ``x`` ``[B, L, d]``."""
     d = c.hidden_size
     with jax.named_scope("lm/conv/in"):
         bcu = _dot(x, p["in_proj"], c.dtype)
     with jax.named_scope("lm/conv/mix"):
-        gate_b, gate_c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
-        mixed = causal_taps(gate_b * u, p["taps"].astype(jnp.float32))
-        y = (gate_c * mixed).astype(c.dtype)
+        # [B | C | u] read where it lies: C * conv(B * u), rounded once
+        y = causal_conv(bcu, p["taps"], channels=d, at=2 * d, pre_at=0,
+                        post_at=d, dtype=c.dtype)
     with jax.named_scope("lm/conv/out"):
         return _dot(y, p["out_proj"], c.dtype)
 

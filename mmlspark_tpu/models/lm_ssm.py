@@ -56,9 +56,10 @@ from mmlspark_tpu.models.lm import (
     _fan_in_normal, _near_one, rms_norm, token_logprob,
 )
 from mmlspark_tpu.models.lm_conv import (
-    KindStack, _at, _dot, causal_taps, gated_mlp, grouped_attention,
+    KindStack, _at, _dot, gated_mlp, grouped_attention,
 )
 from mmlspark_tpu.obs.metrics import registry as _obs_registry
+from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
 
 LAYER_KINDS = ("mamba", "attention")
@@ -148,10 +149,10 @@ def mamba_mixer(p: dict, x, c: JambaConfig):
     with jax.named_scope("lm/mamba/in"):
         uz = _dot(x, p["in_proj"], c.dtype)
     with jax.named_scope("lm/mamba/conv"):
-        conv = jax.nn.silu(causal_taps(
-            uz[..., :d_i], p["conv_taps"].astype(jnp.float32),
-            p["conv_bias"] if c.mamba_conv_bias else None)).astype(c.dtype)
-        gate = uz[..., d_i:].astype(c.dtype)
+        # both halves of [u | z] read where they lie, in one pass
+        conv, gate = causal_conv(
+            uz, p["conv_taps"], channels=d_i, cast_at=d_i, silu=True,
+            bias=p["conv_bias"] if c.mamba_conv_bias else None, dtype=c.dtype)
     with jax.named_scope("lm/mamba/params"):
         dbc = _dot(conv, p["x_proj"], c.dtype)
         dt = rms_norm(dbc[..., :r], p["dt_norm"], eps)

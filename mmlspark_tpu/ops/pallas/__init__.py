@@ -16,6 +16,13 @@ VMEM-resident formulation avoids HBM traffic the default lowering pays:
   two ``[L, channels, states]`` tensors in HBM (imported from its module:
   the function has the module's name).
 
+* :mod:`~mmlspark_tpu.ops.pallas.causal_conv` — the short depthwise
+  causal convolution of ``models/lm_conv.py`` and ``models/lm_ssm.py``
+  with the gates, bias and activation around it: one read of each input
+  position, taken from the wide float32 product where it lies, instead
+  of a cut-out copy and one HBM read a tap. Its entry is one jitted
+  function, so a model's call sites share a trace and a lowering.
+
 (The fused GroupNorm kernel lives next to its reference in
 ``ops/group_norm.py``.)
 

@@ -36,6 +36,7 @@ import chip_smoke  # noqa: E402 — the shapes under test are the smoke's own
 
 from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
+from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
 from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
 
@@ -133,6 +134,19 @@ KERNEL_CASES = {
         (S((1, 16384, 5120), BF16), S((1, 16384, 5120), F32),
          S((5120, 16), F32), S((1, 16384, 16), F32), S((1, 16384, 16), F32),
          S((5120,), F32), S((1, 16384, 5120), BF16))),
+    # the short causal convolution of both families' cells, handed the wide
+    # float32 product and the channel each part starts at: the Mamba
+    # mixer's (4 taps, bias, SiLU, the gate cast beside it) and the gated
+    # one (3 taps, [B | C | u])
+    "causal_conv_t16384_c5120_of_10240": (
+        lambda uz, w, b: causal_conv(uz, w, channels=5120, cast_at=5120,
+                                     bias=b, silu=True, dtype=BF16,
+                                     impl="pallas"),
+        (S((1, 16384, 10240), F32), S((4, 5120), BF16), S((5120,), F32))),
+    "causal_conv_t8192_c2048_of_6144": (
+        lambda bcu, w: causal_conv(bcu, w, channels=2048, at=4096, pre_at=0,
+                                   post_at=2048, dtype=BF16, impl="pallas"),
+        (S((2, 8192, 6144), F32), S((3, 2048), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -166,6 +180,11 @@ def test_kernel_lowers_for_tpu(name):
         # [1, 16384, 5120] array, nothing of shape [L, 5120, 16]
         assert "stablehlo.pad" not in text
         assert "16384x5120x16" not in text
+    if name.startswith("causal_conv"):
+        # the parts are read where they lie: no slice of the wide product
+        # is cut out (or padded) to feed the call
+        assert "stablehlo.slice" not in text
+        assert "stablehlo.pad" not in text
     if name == "flash_attention_tiled_grouped_heads_t8192":
         # the kernel's K and V operands keep their 8 heads
         assert "tensor<2x8x8192x64xbf16>" in text
