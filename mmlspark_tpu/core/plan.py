@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 import weakref
 from collections import deque
 from typing import Any, Callable, Iterator
@@ -205,14 +204,11 @@ def _windowed_dispatch(fn: Callable, dev_params: Any, batch: np.ndarray,
         with _obs_boundary("plan/dispatch", "plan", labels):
             committed = _upload(chunk, target)
             if attrib:
-                # device attribution: detect a fresh XLA compile via
-                # compile-cache growth around the call and attribute
-                # its time + cost/memory analyses (obs/device.py)
-                cache_before = _obs_rt.jit_cache_size(fn)
-                t_call = time.perf_counter()
+                # device attribution: what the compile tier writes for
+                # this thread inside the call is the call's compiles;
+                # their time + cost/memory analyses (obs/device.py)
+                compiled_before = _obs_dev.thread_compiles()
             outs = fn(dev_params, committed)
-            if attrib:
-                dur_call = time.perf_counter() - t_call
             if not isinstance(outs, tuple):
                 outs = (outs,)
             _issue_fetch(outs)
@@ -221,7 +217,7 @@ def _windowed_dispatch(fn: Callable, dev_params: Any, batch: np.ndarray,
             # program once per entry shape, and that second compile must
             # not count as dispatch time in host_phase_split()
             _obs_dev.note_dispatch(fn, dev_params, chunk, label,
-                                   cache_before, dur_call)
+                                   compiled_before)
         window.append((outs, valid))
         # drain to inflight-1 so at most max_inflight minibatch outputs are
         # ever device-resident (the documented HBM bound)

@@ -41,6 +41,11 @@ timeline:
   compile-time histograms, XLA cost/memory gauges
   (``plan.segment.*``), live device-memory polling, and the host
   phase split over the always-on boundary spans.
+* :mod:`~mmlspark_tpu.obs.compile_tier` — the **compile tier**, always
+  on: every trace, lowering and backend compile (or persistent-cache
+  load) JAX makes, by function, and the collector's pauses, as records
+  in the same ring on the same clock; ``compile_report()`` is the table
+  of what building programs cost.
 * :mod:`~mmlspark_tpu.obs.anomaly` — the **train anomaly plane**:
   non-finite loss sentinel (typed :class:`NonFiniteLossError`) and
   multi-host straggler detection (``train.host_skew``).
@@ -88,6 +93,7 @@ from mmlspark_tpu.obs.health import (  # noqa: F401
     HealthMonitor, HealthPolicy,
 )
 from mmlspark_tpu.obs import anomaly  # noqa: F401
+from mmlspark_tpu.obs import compile_tier  # noqa: F401
 from mmlspark_tpu.obs import device  # noqa: F401
 from mmlspark_tpu.obs import fleet  # noqa: F401
 from mmlspark_tpu.obs import flight  # noqa: F401
@@ -96,9 +102,17 @@ from mmlspark_tpu.obs import timeseries  # noqa: F401
 from mmlspark_tpu.obs.anomaly import (  # noqa: F401
     NonFiniteLossError, NonFiniteSentinel, StragglerDetector,
 )
+from mmlspark_tpu.obs.compile_tier import (  # noqa: F401
+    compile_report, gc_records_dropped,
+)
 from mmlspark_tpu.obs.device import (  # noqa: F401
     host_phase_split, poll_memory,
 )
+
+# the compile tier is always on: the collector's callback now, JAX's
+# listeners too when jax is already imported (obs never imports it
+# first; utils/jit_cache.place_compilation_cache registers otherwise)
+compile_tier.register()
 
 __all__ = [
     "Counter",
@@ -123,6 +137,8 @@ __all__ = [
     "check_journey",
     "chrome_trace",
     "clear",
+    "compile_report",
+    "compile_tier",
     "compiled_programs",
     "device",
     "disable",
@@ -131,6 +147,7 @@ __all__ = [
     "event",
     "fleet",
     "flight",
+    "gc_records_dropped",
     "host_phase_split",
     "lockwitness",
     "metrics_snapshot",

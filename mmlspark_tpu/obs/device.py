@@ -10,12 +10,13 @@ recorded through the shared registry (the one-substrate rule):
 
 * **compile attribution** (:func:`note_dispatch`) — the plan dispatch
   seam calls it after every program invocation when the pillar is on.
-  A fresh XLA compile is detected by compile-cache growth (the obs-owned
-  ``jit_cache_size`` hook, extended from a lifetime count to a
-  per-dispatch delta; first-seen-shape memo when the jit object hides
-  its cache), and attributed as one ``plan.compile_ms{segment=…}``
-  histogram observation plus a ``plan.xla_compiles{segment=…}`` count —
-  compile-time histograms keyed by segment and entry bucket.
+  Whether the call compiled, and for how long, is what the compile tier
+  (``obs/compile_tier.py``: JAX's own compile events, always recorded)
+  wrote for the calling thread inside the call: its ``jit/compile``
+  records become one ``plan.compile_ms{segment=…}`` histogram
+  observation plus a ``plan.xla_compiles{segment=…}`` count. That is
+  compile (or the persistent cache's load) alone: not the trace, the
+  lowering or the dispatch.
 * **cost/memory capture** (:func:`_capture_cost`) — once per
   ``(program, entry shape)`` the same program is AOT-lowered and
   compiled so XLA's own ``cost_analysis``/``memory_analysis`` can be
@@ -60,6 +61,9 @@ import weakref
 from typing import Any
 
 from mmlspark_tpu.obs import runtime as _rt
+from mmlspark_tpu.obs.compile_tier import (  # noqa: F401 - the plan seam's
+    GC, JIT_NAMES, thread_compiles,
+)
 from mmlspark_tpu.obs.metrics import registry as _registry
 
 # the device-attribution pillar flag — mutate only through
@@ -94,41 +98,37 @@ def reset() -> None:
 
 
 def note_dispatch(fn: Any, dev_params: Any, chunk: Any,
-                  label: str | None, cache_before: int | None,
-                  dur_s: float) -> None:
+                  label: str | None, compiled_before: tuple) -> None:
     """Attribute one program invocation at the plan dispatch seam.
 
-    ``cache_before`` is ``jit_cache_size(fn)`` read before the call;
-    growth afterwards means the call included an XLA compile and its
-    duration is the compile time (dispatch issue is sub-ms next to any
-    real compile). Jit objects without a readable cache fall back to a
-    first-seen-shape memo. Attribution must never break dispatch — any
-    failure here is swallowed."""
+    ``compiled_before`` is :func:`thread_compiles` read before the call;
+    what the compile tier has written for this thread since is the
+    call's XLA compiles (or cache loads) and their time. Attribution
+    must never break dispatch — any failure here is swallowed."""
     try:
         shape = tuple(getattr(chunk, "shape", ()))
-        after = _rt.jit_cache_size(fn)
+        compiles, compile_ns = thread_compiles()
+        compiles -= compiled_before[0]
         with _seen_lock:
             shapes = _seen.get(fn)
             if shapes is None:
                 shapes = _seen[fn] = set()
             first = shape not in shapes
             shapes.add(shape)
-        fresh = (after > cache_before
-                 if cache_before is not None and after is not None
-                 else first)
-        if not (fresh or first):
+        if not (compiles or first):
             return
         seg = label or "segment"
         reg = _registry()
-        if fresh:
-            reg.counter("plan.xla_compiles", segment=seg).add()
-            reg.histogram("plan.compile_ms",
-                          segment=seg).observe(dur_s * 1e3)
+        if compiles:
+            reg.counter("plan.xla_compiles", segment=seg).add(compiles)
+            reg.histogram("plan.compile_ms", segment=seg).observe(
+                (compile_ns - compiled_before[1]) / 1e6)
         if first:
-            # cost capture keys on the per-process memo, not on cache
-            # growth: a program compiled before the pillar was enabled
-            # (bench warms, then traces) still gets its cost/memory
-            # gauges — only the compile TIME is unknowable then
+            # cost capture keys on the per-process memo, not on whether
+            # this call compiled: a program compiled before the pillar
+            # was enabled (bench warms, then traces) still gets its
+            # cost/memory gauges — only the compile TIME is unknowable
+            # then
             _capture_cost(fn, dev_params, chunk, seg, shape, reg)
     except Exception:  # pragma: no cover - attribution is best-effort
         pass
@@ -224,6 +224,12 @@ def poll_memory(reg: Any = None) -> dict:
 # threads overlap: an instant belongs to the first phase that covers it.
 # All are boundary-tier span names (docs/observability.md)
 _PHASES = (
+    # the two stalls that interrupt whatever span they land in (the
+    # compile tier's records): a collector pause inside plan/d2h is a
+    # pause, not fetch wait; a compile inside plan/dispatch is a
+    # compile, not dispatch. Both 0 on a steady window
+    ("gc", (GC,)),
+    ("jit", JIT_NAMES),
     ("h2d", ("plan/h2d",)),
     ("dispatch", ("plan/dispatch",)),       # self time: h2d taken out
     ("fetch_wait", ("plan/d2h",)),          # the wait for the device
@@ -235,6 +241,8 @@ _PHASES = (
 # spans that only bound the wall (their self time is unspanned)
 _ROOTS = ("transform",)
 _PHASE_OF = {name: phase for phase, names in _PHASES for name in names}
+# phases that only claim time inside the wall the boundary spans bound
+_INTERRUPTS = ("gc", "jit")
 
 
 def _union(intervals: list) -> list:
@@ -280,7 +288,10 @@ def host_phase_split(records: list | None = None,
     lanes (dp>1) emit overlapping ``plan/dispatch`` spans, and a naive
     per-span duration sum would report more than the wall. Where spans
     of different phases overlap the earlier entry of ``_PHASES`` wins:
-    ``plan/h2d`` is ``h2d``, ``plan/dispatch`` time outside its nested
+    a collector pause (``host/gc``) is ``gc`` and a trace, lowering or
+    compile (``jit/*``) is ``jit`` wherever they land, inside the wall
+    the boundary spans bound (neither stretches it; both are 0 on a
+    steady window), ``plan/h2d`` is ``h2d``, ``plan/dispatch`` time outside its nested
     h2d is ``dispatch`` (issuing the async call and the fetch),
     ``plan/d2h`` outside both is ``fetch_wait`` (the host blocked until
     the device produced a minibatch), and so on down the table. A
@@ -309,10 +320,15 @@ def host_phase_split(records: list | None = None,
             continue
         if phase is not None:
             by_phase[phase].append((r.start_ns, r.end_ns))
+            if phase in _INTERRUPTS:
+                continue
         lo = r.start_ns if lo is None else min(lo, r.start_ns)
         hi = r.end_ns if hi is None else max(hi, r.end_ns)
     if lo is None:
         return None
+    for phase in _INTERRUPTS:
+        by_phase[phase] = [(max(s, lo), min(e, hi))
+                           for s, e in by_phase[phase] if e > lo and s < hi]
     wall = (hi - lo) / 1e9 if wall_s is None else float(wall_s)
     out = {"wall_s": wall}
     claimed: list = []

@@ -10,8 +10,9 @@ allocation, no lock (the ``< 2%`` disabled-overhead gate in
 
 Enable programmatically (``obs.enable()``), or from the environment with
 ``MMLSPARK_TPU_OBS=1`` (read once at import through ``core.config``).
-The boundary tier (``obs/spans.boundary_span``) records into the same
-ring regardless of the flag.
+The boundary tier (``obs/spans.boundary_span``) and the compile tier
+(``obs/compile_tier.py``: JAX's own trace / lower / compile events and the
+collector's pauses) record into the same ring regardless of the flag.
 
 Spans are stamped with ``time.perf_counter_ns``; ONE anchor pair taken
 here at import (:data:`CLOCK_ANCHOR`) places them on the Unix epoch
@@ -30,6 +31,7 @@ observable every layer wants, so :func:`jit_cache_size` /
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import time
 from collections import deque
@@ -60,6 +62,33 @@ def to_epoch_ns(perf_ns: int) -> int:
     """A ``time.perf_counter_ns`` stamp of this process in Unix-epoch
     nanoseconds."""
     return perf_ns - CLOCK_ANCHOR[0] + CLOCK_ANCHOR[1]
+
+
+def from_epoch_ns(epoch_ns: int) -> int:
+    """The inverse of :func:`to_epoch_ns`: an instant somebody else read
+    off the Unix epoch (``time.time``), as a ``perf_counter_ns`` stamp of
+    this process, through the same one anchor."""
+    return epoch_ns - CLOCK_ANCHOR[1] + CLOCK_ANCHOR[0]
+
+
+def process_start_epoch_ns() -> int | None:
+    """When the kernel started this process, in Unix-epoch nanoseconds:
+    the zero "ready after N seconds" is counted from, before the
+    interpreter, before any import. ``starttime`` of ``/proc/self/stat``
+    (clock ticks since boot, so 10 ms of resolution) on the boot time
+    (``time_ns`` less ``CLOCK_BOOTTIME``, read now). ``None`` where there
+    is no ``/proc`` or no such clock."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as fh:
+            # the command name may hold spaces and parentheses: the
+            # numbered fields resume after its closing one (state is 3)
+            fields = fh.read().rpartition(")")[2].split()
+        ticks = int(fields[22 - 3])
+        boot_ns = time.time_ns() - time.clock_gettime_ns(
+            time.CLOCK_BOOTTIME)
+        return boot_ns + ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
 
 
 DEFAULT_BUFFER = 65536
@@ -187,6 +216,24 @@ def record(item: SpanRecord | EventRecord) -> None:
         note_traces(trace, links)
 
 
+def try_record(item: SpanRecord) -> bool:
+    """:func:`record` for a caller that may not wait: the collector's
+    callback (``obs/compile_tier.py``) runs wherever an allocation lands,
+    possibly on a thread that is inside :func:`record` and holds
+    ``_lock``, which is not re-entrant. One try-acquire; ``False`` when
+    the lock is taken and the record was not written. For records
+    outside any request trace only."""
+    global _append_seq
+    if not _lock.acquire(blocking=False):
+        return False
+    try:
+        _buffer.append(item)
+        _append_seq += 1
+    finally:
+        _lock.release()
+    return True
+
+
 def note_traces(trace: int | None, links: tuple | None) -> None:
     """Register a record's trace ids as live; evict the oldest traces
     (batched — each eviction rebuilds the ring once) past the bound."""
@@ -286,6 +333,12 @@ def spans() -> list:
 def captured_count() -> int:
     """O(1) record count (no buffer copy — the /metrics poll path)."""
     return len(_buffer)
+
+
+def ring_full() -> bool:
+    """The ring is at its bound: older records may have been evicted, so
+    a sum over "every record since the process began" is no longer one."""
+    return len(_buffer) == _buffer.maxlen
 
 
 def span_records() -> list[SpanRecord]:

@@ -43,6 +43,12 @@ def place_compilation_cache() -> str:
     """Make sure jax's persistent compilation cache has a home; returns
     the directory. Importing jax here does not initialise a backend."""
     import jax
+
+    # every process that compiles for the device comes through here
+    # before its first compile: where the always-on compile tier's
+    # listeners go in when obs was imported before jax (a no-op after)
+    from mmlspark_tpu.obs import compile_tier
+    compile_tier.register()
     if not os.environ.get(MIN_COMPILE_TIME_ENV_VAR):
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     placed = os.environ.get(ENV_VAR)
