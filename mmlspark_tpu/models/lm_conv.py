@@ -22,8 +22,9 @@ from the configuration's own key names (``docs/lm.md`` has the equations):
 * **experts**: a sigmoid router in float32; the picks are the
   ``num_experts_per_tok`` largest of ``score + bias`` (``use_expert_bias``),
   their weights the unbiased scores over ``(their sum + 1e-6)``; every
-  expert is held, so :func:`~mmlspark_tpu.parallel.moe.moe_dropless` has
-  one rung and no conditional, and drops no token at any load.
+  expert is held, so :func:`~mmlspark_tpu.parallel.moe.moe_dropless` drops
+  no token at any load and, at four row tiles of pairs an expert or more
+  (its buffer packed), has one rung and no conditional.
 
 **The parameter tree is by kind, not by position**: ``conv/*`` stacks the
 conv layers in layer order, ``attn/*`` the attention layers, ``dense/*``

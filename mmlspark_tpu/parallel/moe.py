@@ -46,17 +46,33 @@ from typing import Any
 import numpy as np
 
 EXPERT_IMPLS = ("auto", "ragged", "gmm")
+ROW_TILE_GAUGE = "moe.row_tile"
 
 # the Pallas grouped product's tiles: rows of the sorted pairs, and the
 # elements of a weight tile (the contraction whole up to GMM_WHOLE_K, else
-# within half of that). Read on one v5e chip at [32768, 4096] x [192, 4096,
-# 2048] with ~256 rows a group: 256 rows beat 128 and 512 (a row tile is
-# computed whole for every group that touches it), and these weight tiles
-# beat the smaller ones (PERF.md section 6, PR 28). ``gmm_tiles`` derives
-# the tiles of any shape from them: whole lane rows that divide the axis
+# within half of that). The kernel's grid visits a row tile once for every
+# group with a row in it: the tile is computed whole (the store is masked,
+# the product is not) and that group's weight tiles are read again. So
+# where an expert expects a few tiles of pairs at most, ``moe_dropless``
+# starts every expert on a tile boundary, at the one of GMM_ROWS and
+# ALIGNED_ROWS rows that ``row_tile`` picks from the expected load. Read on
+# one v5e chip (PERF.md section 6, PR 39): at the Mistral cell's real loads
+# (~256 pairs an expert, 109-480), [rows, 4096] x [192, 4096, 2048], a call
+# of the gate product took 1.71 ms packed at 256 rows (63 visits) and
+# aligned 1.98 / 1.32 / 1.15 / 1.30 / 1.81 ms at 128 / 256 / 320 / 384 /
+# 512 rows (80 / 47 / 33 / 32 / 32 visits): at 320 an expert takes one tile
+# in most steps and a visit's product just outweighs its weight read. A
+# packed buffer keeps GMM_ROWS: 256 rows beat 128 and 512 there (PR 28).
+# GMM_VMEM is Mosaic's scoped VMEM, which the tiles have to fit
+# (``_gmm_vmem``): beside 320 rows and a float32 result it holds a [2048,
+# 1024] weight tile, not [1024, 2048], and neither beside 384 rows.
+# ``gmm_tiles`` derives the tiles of any shape from these: whole lane rows
+# that divide the axis
 GMM_ROWS = 256
+ALIGNED_ROWS = 320
 GMM_WEIGHT_TILE = 2 ** 21
 GMM_WHOLE_K = 2048
+GMM_VMEM = 2 ** 24
 
 
 def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int,
@@ -310,22 +326,43 @@ def _lane_tile(width: int, most: int) -> int:
                        if width % (128 * t) == 0), lanes)
 
 
-def gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+def _gmm_vmem(tm: int, tk: int, tn: int) -> int:
+    """The bytes of scoped VMEM Mosaic allots the grouped product at these
+    tiles, as its refusals state them (17.02 MiB at ``(384, 2048, 1024)``):
+    three row tiles and two weight tiles in bfloat16, the result tile in
+    float32 twice and its accumulator."""
+    return 6 * tm * tk + 4 * tk * tn + 12 * tm * tn
+
+
+def gmm_tiles(m: int, k: int, n: int, rows: int = GMM_ROWS
+              ) -> tuple[int, int, int]:
     """``(tm, tk, tn)`` of the Pallas grouped product ``[m, k] x [G, k,
-    n]``, from the shapes alone: ``GMM_ROWS`` rows; the contraction whole
-    up to ``GMM_WHOLE_K`` and within half of it past that; the result's
-    tile within what ``GMM_WEIGHT_TILE`` elements leave beside ``tk``; both
-    by :func:`_lane_tile`, so whole lane rows that divide their axis."""
-    tk = _lane_tile(k, GMM_WHOLE_K if k <= GMM_WHOLE_K else GMM_WHOLE_K // 2)
-    return min(GMM_ROWS, m), tk, _lane_tile(n, GMM_WEIGHT_TILE // tk)
+    n]``, from the shapes alone: ``rows`` rows (``GMM_ROWS``, or the tile
+    the buffer's groups are aligned to); the contraction whole up to
+    ``GMM_WHOLE_K`` and within half of it past that; the result's tile
+    within what ``GMM_WEIGHT_TILE`` elements leave beside ``tk``; both by
+    :func:`_lane_tile`, so whole lane rows that divide their axis. Where a
+    long contraction's tiles pass ``GMM_VMEM`` beside ``rows`` rows (more
+    rows than ``GMM_ROWS``), it is cut within ``GMM_WHOLE_K`` instead,
+    which halves the result tile (where that does not fit either, Mosaic
+    refuses the program)."""
+    tm = min(rows, m)
+    for most in ((GMM_WHOLE_K // 2, GMM_WHOLE_K) if k > GMM_WHOLE_K
+                 else (GMM_WHOLE_K,)):
+        tk = _lane_tile(k, most)
+        tn = _lane_tile(n, GMM_WEIGHT_TILE // tk)
+        if _gmm_vmem(tm, tk, tn) <= GMM_VMEM:
+            break
+    return tm, tk, tn
 
 
-def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
+def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None,
+                 rows: int = GMM_ROWS):
     """``lhs [M, K]`` against ``rhs [G, K, N]``: row ``i`` meets the matrix
     of the group it lies in (groups are consecutive row ranges of
     ``group_sizes``); accumulated in float32, stored as ``out_dtype``
-    (float32 unless given). Rows past the last group are unspecified: the
-    caller masks them."""
+    (float32 unless given), the kernel's row tile ``rows``. Rows past the
+    last group are unspecified: the caller masks them."""
     import jax
     import jax.numpy as jnp
 
@@ -341,34 +378,78 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None):
     # the kernel's dynamic grid visits only the tiles that hold rows of a
     # group, so the work follows the pairs really routed here
     (m, k), n = lhs.shape, rhs.shape[2]
-    return gmm(lhs, rhs, group_sizes, out_dtype, gmm_tiles(m, k, n))
+    return gmm(lhs, rhs, group_sizes, out_dtype, gmm_tiles(m, k, n, rows))
+
+
+def row_tile(pairs: int, width: int) -> int:
+    """The rows every held expert's pairs are padded to a whole number of
+    in the sorted buffer, so that every group starts on a tile boundary and
+    the grouped product visits no tile twice; 1 where the pairs stay packed
+    (no padding). From the expected pairs an expert ``pairs / width``
+    alone: the one of ``GMM_ROWS`` and ``ALIGNED_ROWS`` that holds 1.25x
+    that load in the fewer rows (the larger on a tie: fewer visits), which
+    is the faster on the chip at each of the six loads read, 128 to 1,024
+    pairs an expert; packed under half a tile an expert (every expert
+    takes a whole tile for less than half of one: packed won at 96 and
+    under, lost by 13 % at 128), from four tiles up (a shared tile is at
+    most one visit in five and the padding costs the operations around the
+    products more rows than it saves the products visits: aligned won by
+    5 % at 1,024 and lost by 5 % at 2,048), and where all the pairs fit one
+    tile (PERF.md section 6, PR 39)."""
+    if (pairs <= ALIGNED_ROWS
+            or not GMM_ROWS * width <= 2 * pairs < 8 * ALIGNED_ROWS * width):
+        return 1
+    room = -(-5 * pairs // (4 * width))
+    return min(ALIGNED_ROWS, GMM_ROWS, key=lambda t: -(-room // t) * t)
 
 
 def bucket_ladder(pairs: int, held: int, width: int) -> tuple[int, ...]:
     """The static row counts :func:`moe_dropless` may give its sorted
-    buffer, from shapes alone: with ``pairs = tokens * top_k`` and ``held``
-    of the router's ``width`` experts here, about 1.25x and 2x the
-    expected ``pairs * held / width`` and ``pairs`` itself, each rounded up
-    to whole row tiles (``GMM_ROWS``) and clipped to ``pairs``, duplicates
-    dropped. Increasing; the last rung is ``pairs``, so every load fits
-    one; a single rung when every expert is held or ``pairs`` is under a
-    row tile."""
-    def rung(num: int, den: int) -> int:
-        tiles = -(-num // (den * GMM_ROWS))
-        return min(pairs, tiles * GMM_ROWS)
-    return tuple(sorted({rung(5 * pairs * held, 4 * width),
-                         rung(2 * pairs * held, width), pairs}))
+    buffer, from shapes alone, with ``pairs = tokens * top_k`` and ``held``
+    of the router's ``width`` experts here. Where the buffer is aligned
+    (:func:`row_tile`) it holds **padded** rows: every held expert's pairs
+    fill whole row tiles, so a load of ``count`` held pairs takes up to
+    ``count + held * (tile - 1)`` rows. The first rung holds 1.25x the
+    expected ``pairs * held / width`` pairs and three eighths of a tile of
+    padding an expert, rounded up to whole row tiles (at the Mistral cell,
+    44 tiles: 1,152 of its layer-steps over twelve seeds took 32-41; the
+    loads read at 128 to 1,024 pairs an expert fit theirs too); the others
+    are whole multiples of the first: twice it, and the fewest that hold
+    the worst load of all ``pairs`` (every pair on a held expert and,
+    aligned, every expert's last tile holding one row), so every load fits
+    the last. A single rung, the worst load, where the first rung would
+    hold it: all ``pairs`` within a row tile, or every expert held and the
+    pairs packed or a few tiles an expert."""
+    tile = row_tile(pairs, width)
+    rows = tile if tile > 1 else GMM_ROWS
+    worst = (pairs + held * (tile - 1)) // tile * tile
+    first = -(-5 * pairs * held // (4 * width)) + held * (3 * tile // 8)
+    first = min(worst, -(-first // rows) * rows)
+    most = -(-worst // first)       # first rungs that hold the worst load
+    return tuple(first * k for k in sorted({1, min(2, most), most}))
 
 
-def _piece(rows: int, most: int) -> int:
-    """The rows of the largest equal whole-tile pieces of a ``rows``-row
-    buffer that are at most ``most`` rows each (``rows`` itself where it
-    is no whole number of row tiles)."""
-    tiles, rest = divmod(rows, GMM_ROWS)
-    if rest or rows <= most:
-        return rows
-    return next(rows // k for k in range(2, tiles + 1)
-                if tiles % k == 0 and rows // k <= most)
+def row_tile_visits(sizes, tile: int) -> int:
+    """The row tiles the grouped product visits for consecutive groups of
+    ``sizes`` rows (host integers) at row tile ``tile``: a tile is visited
+    once for every group with a row in it, computed whole and with that
+    group's matrix read again each time. ``sum(ceil(size / tile))`` where
+    every group starts on a tile boundary (the sizes :func:`moe_dropless`
+    hands the kernel); up to one more a group where they start anywhere.
+    ``held pairs / (visits * tile)`` is the share of the computed rows that
+    are pairs."""
+    sizes = np.asarray(sizes, np.int64)
+    ends = np.cumsum(sizes)
+    tiles = -(-ends // tile) - (ends - sizes) // tile
+    return int(tiles[sizes > 0].sum())
+
+
+def _pad_below(pos, bounds, pad):
+    """For each of ``pos`` the sum of ``pad[j]`` over the ``j`` whose
+    ``bounds[j] <= pos``: the padding rows that lie before a position."""
+    import jax.numpy as jnp
+
+    return jnp.sum(jnp.where(bounds <= pos[:, None], pad, 0), axis=1)
 
 
 def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
@@ -394,34 +475,57 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     last) and run through one grouped matrix product per stack; a pick of an
     absent expert adds nothing here (its chip adds it in the deployment).
 
-    The sorted buffer, and everything between the sort and the combine, has
+    **The sorted buffer's layout.** Where an expert expects from half a row
+    tile to four of pairs (:func:`row_tile`, which picks the tile from that
+    load), each held expert's pairs occupy a whole number of row tiles: its
+    group is its count rounded up to the tile (an expert with no pair keeps
+    0), so every group starts on a tile boundary, the kernel visits each
+    tile once, and reads an expert's matrix once a tile of its own
+    (:func:`row_tile_visits`). A pair's
+    buffer row is its rank in the sorted order plus the padding of the
+    experts before its own; a buffer row finds its pair the other way
+    round, from the padded ends. A padding row reads some valid token: it
+    lies in a tile that is computed whole anyway, and is never read back.
+    Elsewhere the pairs are packed: a group starts where the one before it
+    ended.
+
+    The buffer, and everything between the sort and the combine, has
     the row count of a **rung** of :func:`bucket_ladder`: the first that
-    holds this call's count of held pairs, chosen on the device
+    holds this call's (padded) rows, chosen on the device
     (``lax.switch``, one branch per rung). The rows of a rung are a prefix
-    of the sorted order, so they hold every held pair; each token then
+    of the buffer's order, so they hold every held pair; each token then
     gathers its held picks' rows, pick by pick, and adds them weighted in
-    float32 (an absent pick is selected away; the rows past the count are
-    never read). No capacity: a call whose count passes a rung takes the
-    next, and the last rung has room for every pair, so no token is dropped
-    at any load; a rung above the first is worked in equal pieces no larger
-    than the first, so the rare larger buffers need no more scratch than
-    the usual one. With one rung (every expert held, or fewer pairs than a
-    row tile) the program has no conditional.
+    float32 (an absent pick is selected away; the rows past the buffer's
+    last group are never read). No capacity: a call whose rows pass a rung
+    takes the next, and the last rung has room for the worst load of all
+    pairs, so no token is dropped at any load; a rung above the first is a
+    whole number of first rungs and is worked one at a time, so the rare
+    larger buffers need no more scratch, and no other kernel, than the
+    usual one; its rows past the last pair read a clipped pair that no
+    token reads back. With one rung (the first would hold the worst
+    load: every expert held and the pairs packed or a few tiles an expert,
+    or fewer pairs than a row tile) the program has no conditional.
 
     Returns ``(y [N, d] float32, picks [N, k], bucket () int32)``: ``y =
     sum over held picks of w_k E_k(x)``, the weights normalised over all
     ``top_k`` picks; ``bucket`` is the index of the rung taken.
     This is what expert parallelism over ``ep`` asks of one shard; the
     exchange that would bring other shards' tokens here is not part of it.
+    Gauge, set when traced: ``moe.row_tile``.
     """
     import jax
     import jax.numpy as jnp
+
+    from mmlspark_tpu.obs.metrics import registry
 
     if impl not in EXPERT_IMPLS:
         raise ValueError(f"unknown expert impl {impl!r}; one of "
                          f"{EXPERT_IMPLS}")
     n, d = x.shape
     held = experts["gate"].shape[-3]
+    tile = row_tile(n * top_k, router.shape[1])
+    tm = tile if tile > 1 else GMM_ROWS     # the kernel's row tile
+    registry().gauge(ROW_TILE_GAUGE).set(tile)
     picks, weights = route_topk(x, router, top_k, norm_topk, scaling, score,
                                 bias, norm_eps)
     local = picks - first_expert
@@ -430,42 +534,60 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32),
                     axis=0)[:held]
-    count = jnp.sum(sizes)
+    # each expert's rows in the buffer: its pairs and the padding that
+    # fills its last tile
+    padded = -(-sizes // tile) * tile
+    pad = padded - sizes
+    ends = jnp.cumsum(padded)
+    groups = padded
     if layer is not None:
         layers = experts["gate"].shape[0]
-        sizes = jnp.zeros((layers, held), jnp.int32).at[layer].set(
-            sizes).reshape(-1)
+        groups = jnp.zeros((layers, held), jnp.int32).at[layer].set(
+            padded).reshape(-1)
         experts = {k: v.reshape((layers * held,) + v.shape[2:])
                    for k, v in experts.items()}
+    group_ends = jnp.cumsum(groups)
     dtype = x.dtype
-    # the sorted row of each (token, pick); an absent pick reads row 0 and
-    # is selected away
+    # the buffer row of each (token, pick): its sorted rank past the padding
+    # of the experts before its own; an absent pick reads row 0 and is
+    # selected away
     present = (group < held).reshape(n, top_k)
-    back = jnp.where(present, jnp.argsort(order).reshape(n, top_k), 0)
-    ends = jnp.cumsum(sizes)
+    row_of = jnp.argsort(order)
+    if tile > 1:
+        row_of = row_of + _pad_below(group, jnp.arange(1, held + 1), pad)
+    back = jnp.where(present, row_of.reshape(n, top_k), 0)
 
     def products(lo, rows: int):
-        """The expert outputs of the sorted pairs ``[lo, lo + rows)``, whose
-        groups are the parts of the sorted groups that lie in that range."""
-        part = (jnp.clip(ends, lo, lo + rows)
-                - jnp.clip(ends - sizes, lo, lo + rows))
-        pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        """The expert outputs of the buffer rows ``[lo, lo + rows)``, whose
+        groups are the parts of the padded groups that lie in that range."""
+        part = (jnp.clip(group_ends, lo, lo + rows)
+                - jnp.clip(group_ends - groups, lo, lo + rows))
+        # a row's pair: its sorted rank is the row less the padding before
+        # it (a padding row lands on a neighbour's pair; a row past the
+        # last pair is clipped to it)
+        row = lo + jnp.arange(rows)
+        if tile > 1:
+            row = row - _pad_below(row, ends, pad)
+        pair = jnp.take(order, row, mode="clip")
         xs = jnp.take(x, pair // top_k, axis=0, mode="clip")
-        gate = _grouped_dot(xs, experts["gate"], part, impl)
-        up = _grouped_dot(xs, experts["up"], part, impl)
+        gate = _grouped_dot(xs, experts["gate"], part, impl, rows=tm)
+        up = _grouped_dot(xs, experts["up"], part, impl, rows=tm)
         act = (jax.nn.silu(gate) * up).astype(dtype)
-        return _grouped_dot(act, experts["down"], part, impl, dtype)
+        return _grouped_dot(act, experts["down"], part, impl, dtype, rows=tm)
 
-    def on_rows(rows: int, piece: int):
-        """The layer over the first ``rows`` sorted pairs, ``piece`` rows
-        at a time."""
-        if piece == rows:
-            ys = products(0, rows)
+    ladder = bucket_ladder(n * top_k, held, router.shape[1])
+
+    def on_rungs(count: int):
+        """The layer over the first ``count`` first rungs of the buffer, a
+        rung at a time."""
+        if count == 1:
+            ys = products(0, ladder[0])
         else:
-            ys = jax.lax.map(lambda lo: products(lo, piece),
-                             jnp.arange(0, rows, piece)).reshape(rows, d)
-        # a held pair's row lies under the count, so no row past it
-        # (unspecified) is read
+            ys = jax.lax.map(lambda lo: products(lo, ladder[0]),
+                             jnp.arange(count) * ladder[0]
+                             ).reshape(count * ladder[0], d)
+        # a held pair's row lies under the last group's end, so no row past
+        # it (unspecified) is read
         y = jnp.zeros((n, d), jnp.float32)
         for j in range(top_k):
             row = jnp.take(ys, back[:, j], axis=0, mode="clip")
@@ -474,12 +596,9 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                               0.0)
         return y
 
-    ladder = bucket_ladder(n * top_k, held, router.shape[1])
-    # a rung above the first runs in pieces no larger than the first, so the
-    # rare larger buffers need no more scratch than the usual one
-    branches = [functools.partial(on_rows, rows, _piece(rows, ladder[0]))
+    branches = [functools.partial(on_rungs, rows // ladder[0])
                 for rows in ladder]
     if len(ladder) == 1:
         return branches[0](), picks, jnp.zeros((), jnp.int32)
-    bucket = jnp.sum(count > jnp.asarray(ladder[:-1])).astype(jnp.int32)
+    bucket = jnp.sum(ends[-1] > jnp.asarray(ladder[:-1])).astype(jnp.int32)
     return jax.lax.switch(bucket, branches), picks, bucket

@@ -438,33 +438,86 @@ def test_the_expert_layer_matches_the_reference():
     assert plain.shape == picks.shape
 
 
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_every_expert_held_the_padded_buffer_matches_the_reference(
+        impl, stacked, request):
+    """Every expert held at under four row tiles an expert: the buffer
+    holds every expert's pairs in whole row tiles (the sigmoid router with
+    its bias loads the eight experts unevenly), and its one rung holds the
+    worst of them."""
+    if impl == "gmm":
+        request.getfixturevalue("pallas_interpret")
+    cfg = tiny()
+    p = ref.make_layer_params(cfg, jax.random.PRNGKey(5), 3, ("conv", "moe"))
+    x = jnp.asarray(np.random.default_rng(9).normal(size=(1040, 64)),
+                    jnp.float32)
+    experts = {k: p["routed/" + k] for k in ("gate", "up", "down")}
+    kw = {}
+    if stacked:
+        experts = {k: jnp.stack([v * 2.0, v]) for k, v in experts.items()}
+        kw = {"layer": jnp.int32(1)}
+    # 4160 pairs on 8 experts, 520 each: 1.25x that in three tiles of 256
+    # (two of 320 would be more rows); at most 4160 + 8 x 255 rows, in
+    # whole tiles, which the first rung would hold: one rung
+    tile = moe.row_tile(1040 * 4, 8)
+    assert tile == moe.GMM_ROWS == 256
+    assert moe.bucket_ladder(1040 * 4, 8, 8) == (6144,)
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref.moe(p, x, cfg)
+        fn = jax.jit(lambda a: moe.moe_dropless(
+            a, p["router/kernel"], experts, top_k=4, impl=impl,
+            score="sigmoid", bias=p["router/bias"], norm_eps=1e-6, **kw))
+        got, picks, bucket = fn(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    load = np.bincount(np.asarray(picks).ravel(), minlength=8)
+    assert int(bucket) == 0 and load.sum() == 4160
+    assert (load % tile).all() and load.max() > 1.2 * load.min()
+    padded = -(-load // tile) * tile
+    assert moe.row_tile_visits(padded, tile) \
+        < moe.row_tile_visits(load, tile)
+    assert padded.sum() <= 6144
+
+
 # ---- the grouped product's tiles ----
 
-@pytest.mark.parametrize("m,k,n,want", [
-    # the Mistral cell's three products: the tiles PR 28 read on the chip
-    (10240, 4096, 2048, (256, 1024, 2048)),
-    (10240, 2048, 4096, (256, 2048, 1024)),
+@pytest.mark.parametrize("m,k,n,rows,want", [
+    # the Mistral cell's three products on its aligned buffer: 320 rows a
+    # tile, and the weight tile that fits VMEM beside them (PR 39's probe)
+    (14080, 4096, 2048, 320, (320, 2048, 1024)),
+    (14080, 2048, 4096, 320, (320, 2048, 1024)),
+    # the same on a packed buffer: the tiles PR 28 read on the chip
+    (10240, 4096, 2048, 256, (256, 1024, 2048)),
+    (10240, 2048, 4096, 256, (256, 2048, 1024)),
     # widths that are whole lane rows and no power of two: 1792 = 14 x 128
-    (65536, 2048, 1792, (256, 2048, 896)),
-    (65536, 1792, 2048, (256, 1792, 1024)),
-    (16384, 2048, 7168, (256, 2048, 1024)),
-    (16384, 7168, 2048, (256, 1024, 2048)),
+    (65536, 2048, 1792, 256, (256, 2048, 896)),
+    (65536, 1792, 2048, 256, (256, 1792, 1024)),
+    (16384, 2048, 7168, 256, (256, 2048, 1024)),
+    (16384, 7168, 2048, 256, (256, 1024, 2048)),
+    (16384, 7168, 2048, 320, (320, 1792, 1024)),
     # tiny test shapes: the axes whole
-    (64, 64, 32, (64, 64, 32)),
+    (64, 64, 32, 256, (64, 64, 32)),
 ])
-def test_the_grouped_products_tiles_come_from_the_shapes(m, k, n, want):
-    tm, tk, tn = moe.gmm_tiles(m, k, n)
+def test_the_grouped_products_tiles_come_from_the_shapes(m, k, n, rows,
+                                                         want):
+    tm, tk, tn = moe.gmm_tiles(m, k, n, rows)
     assert (tm, tk, tn) == want
+    assert moe.gmm_tiles(m, k, n)[0] == min(m, moe.GMM_ROWS)
+    assert moe._gmm_vmem(tm, tk, tn) <= moe.GMM_VMEM
     if k >= 128 and n >= 128:
         assert tk % 128 == 0 and tn % 128 == 0
         assert k % tk == 0 and n % tn == 0
         assert tk * tn <= moe.GMM_WEIGHT_TILE
+    if rows == moe.GMM_ROWS and k > moe.GMM_WHOLE_K:
+        # a packed buffer's long contraction keeps its half-length tiles
+        assert tk <= moe.GMM_WHOLE_K // 2
 
 
 def test_a_width_no_lane_multiple_divides_still_gets_whole_lane_rows():
     # 5000 = 2^3 x 5^4: no multiple of 128 divides it
     assert moe.gmm_tiles(512, 5000, 5000) == (256, 1024, 2048)
     assert moe.gmm_tiles(512, 2048, 5000) == (256, 2048, 1024)
+    assert moe.gmm_tiles(640, 5000, 5000, 320) == (320, 2048, 1024)
 
 
 # ---- the other family's program is untouched ----

@@ -221,16 +221,23 @@ def test_every_token_on_one_expert_loses_none(seeded):
     assert np.abs(np.asarray(got)).min(axis=1).max() > 0     # none is zero
 
 
-# the row-count ladder: 512 tokens x 4 picks on 4 of 16 experts expect 512
-# held pairs, so the rungs are 768, 1024 and all 2048
+# the row-count ladder. 1024 tokens x 4 picks on 4 of 16 experts expect 1024
+# held pairs (256 an expert: aligned at 320 rows) and a tile and a half of
+# padding, so the rungs are 1920 rows, twice and three times that (the worst
+# padded load of all 4096 pairs is 5120 rows). 500 tokens expect 125 pairs
+# an expert and 250
+# tokens 62: packed, rungs of 256-row tiles, the last of them past all the
+# pairs there are
 LADDER_FIRST = 4
+LADDER = (1920, 3840, 5760)
+LADDERS = {1024: LADDER, 500: (768, 1536, 2304), 250: (512, 1024)}
 ROUTERS = {
-    # the seeded router: ~512 held pairs, the first rung
+    # the seeded router: ~1024 held pairs, the first rung
     "balanced": (0, {}),
-    # every token picks two held experts and never the other two: 1024
-    # held pairs, the middle rung filled to its last row
+    # every token picks two held experts and never the other two: 2048
+    # held pairs in eight tiles, the middle rung
     "skewed": (1, {4: 4.0, 5: 4.0, 6: -4.0, 7: -4.0}),
-    # every pick of every token is a held expert: 2048 = N k, the last rung
+    # every pick of every token is a held expert: 4096 = N k, the last rung
     "all_held": (2, {4: 4.0, 5: 4.0, 6: 4.0, 7: 4.0}),
 }
 
@@ -241,18 +248,25 @@ def held_share(layer: dict, first: int, count: int = 4) -> dict:
                 else v) for k, v in layer.items()}
 
 
-def ladder_case(seeded, name):
+def padded_rows(load, pairs: int, width: int) -> np.ndarray:
+    """The buffer rows of held loads ``[..., held]``: every expert's pairs
+    in whole row tiles where the buffer is aligned (``moe.row_tile``)."""
+    tile = moe.row_tile(pairs, width)
+    return (-(-np.asarray(load) // tile) * tile).sum(axis=-1)
+
+
+def ladder_case(seeded, name, tokens=1024):
     """``(p, cfg, x, router, experts)`` of the share that holds experts
-    4..7 of layer 0, under the named router; where it has constant columns
-    the tokens are positive, so that such a column decides its expert for
-    every token."""
+    4..7 of layer 0, under the named router, for ``tokens`` tokens; where
+    it has constant columns the tokens are positive, so that such a column
+    decides its expert for every token."""
     _, params = seeded
     p = held_share(params["layers"][0], LADDER_FIRST)
     router = np.array(p["moe/router/kernel"], np.float32)
     for column, value in ROUTERS[name][1].items():
         router[:, column] = value
     p["moe/router/kernel"] = jnp.asarray(router)
-    x = jnp.asarray(np.random.default_rng(8).normal(size=(512, 64)),
+    x = jnp.asarray(np.random.default_rng(8).normal(size=(tokens, 64)),
                     jnp.float32)
     if ROUTERS[name][1]:
         x = jnp.abs(x) + 0.1
@@ -260,53 +274,251 @@ def ladder_case(seeded, name):
     return (p, cfg, x) + moe_parts(p)
 
 
-@pytest.mark.parametrize("name", sorted(ROUTERS))
+@pytest.mark.parametrize("tokens,name", [
+    (tokens, name) for tokens in (1024, 500) for name in sorted(ROUTERS)
+] + [(250, "all_held")])    # 1000 pairs: the second of two packed rungs
 @pytest.mark.parametrize("impl", ["ragged", "gmm"])
-def test_each_rung_of_the_ladder_matches_the_reference(seeded, impl, name,
-                                                       request):
+def test_each_rung_of_the_ladder_matches_the_reference(seeded, impl, tokens,
+                                                       name, request):
+    """Aligned (1024 tokens) and packed, each rung at a load that takes it:
+    a packed ladder's upper rungs reach past the last pair, and the rows
+    there read a clipped pair that no token reads back."""
     if impl == "gmm":
         request.getfixturevalue("pallas_interpret")
-    p, cfg, x, router, experts = ladder_case(seeded, name)
-    assert moe.bucket_ladder(512 * 4, 4, 16) == (768, 1024, 2048)
+    p, cfg, x, router, experts = ladder_case(seeded, name, tokens)
+    ladder = LADDERS[tokens]
+    assert moe.bucket_ladder(tokens * 4, 4, 16) == ladder
+    assert (moe.row_tile(tokens * 4, 16) > 1) == (tokens == 1024)
     with jax.default_matmul_precision("highest"):
         got, picks, bucket = jax.jit(lambda a: moe.moe_dropless(
             a, router, experts, top_k=4, first_expert=LADDER_FIRST,
             impl=impl))(x)
         routed, _, _ = ref.moe(p, x, cfg, parts=True)
-    held = int(((np.asarray(picks) >= 4) & (np.asarray(picks) < 8)).sum())
-    want_bucket = ROUTERS[name][0]
+    load = np.bincount(np.asarray(picks).ravel(), minlength=16)[4:8]
+    rows = int(padded_rows(load, tokens * 4, 16))
+    want_bucket = min(ROUTERS[name][0], len(ladder) - 1)
     assert int(bucket) == want_bucket
-    assert (768, 1024, 2048)[want_bucket] >= held
-    assert want_bucket == 0 or held > (768, 1024)[want_bucket - 1]
+    assert ladder[want_bucket] >= rows
+    assert want_bucket == 0 or rows > ladder[want_bucket - 1]
+    if name == "all_held":      # the worst load: every pair, none dropped
+        assert load.sum() == tokens * 4 and ladder[-1] > load.sum()
     # no pair is left out at any load: the later rungs answer like the first
     np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
                                atol=2e-6)
 
 
 @pytest.mark.parametrize("pairs,held,width,want", [
-    (8192 * 4, 32, 128, (10240, 16384, 32768)),   # the benchmark's cell
-    (512 * 4, 4, 16, (768, 1024, 2048)),
-    (8192 * 4, 8, 128, (2560, 4096, 32768)),      # 1/16 of the experts
-    (1000, 4, 16, (512, 1000)),     # both small rungs round to two tiles
-    (40 * 4, 4, 16, (160,)),        # under a row tile: today's one buffer
-    (8192 * 4, 128, 128, (32768,)),               # every expert held
-    (8192 * 4, 96, 128, (30720, 32768)),          # 2x the share: clipped
+    (8192 * 4, 32, 128, (14080, 28160, 56320)),   # the benchmark's cell
+    (1024 * 4, 4, 16, LADDER),
+    (8192 * 4, 8, 128, (3520, 7040, 35200)),      # 1/16 of the experts
+    (8192 * 4, 128, 128, (56320, 112640)),        # every expert held
+    (8192 * 4, 96, 128, (42240, 84480)),
+    # 400 pairs an expert: two tiles of 256 hold 1.25x that, not one of 320
+    (12800 * 4, 32, 128, (19200, 38400, 76800)),
+    (6144 * 4, 32, 128, (10752, 21504, 43008)),   # 192: one tile of 256
+    (32768 * 4, 32, 128, (44800, 89600, 179200)),     # 1024: four of 320
+    (512 * 4, 4, 16, (1024, 2048, 3072)),         # 128: half a tile of 256
+    # under half a tile an expert: the pairs packed, rungs of 256-row tiles
+    (500 * 4, 4, 16, (768, 1536, 2304)),
+    (1000, 4, 16, (512, 1024)),
+    (40 * 4, 4, 16, (160,)),        # under a row tile: one buffer
+    # four tiles or more an expert, every expert held: the other family's
+    # cell keeps its one packed buffer
+    (16384 * 4, 32, 32, (65536,)),
 ])
 def test_the_ladder_comes_from_the_shapes(pairs, held, width, want):
     ladder = moe.bucket_ladder(pairs, held, width)
     assert ladder == want
-    assert ladder[-1] == pairs and list(ladder) == sorted(set(ladder))
-    assert all(r % moe.GMM_ROWS == 0 for r in ladder[:-1])
-    assert len(ladder) <= 3
-    if len(ladder) > 1:             # room over the expected load
-        assert ladder[0] >= 1.25 * pairs * held / width
-    # a larger rung is worked in equal whole-tile pieces within the first
-    for rows in ladder:
-        piece = moe._piece(rows, ladder[0])
-        assert rows % piece == 0
-        assert piece == rows or (piece <= ladder[0]
-                                 and piece % moe.GMM_ROWS == 0)
-    assert moe._piece(ladder[0], ladder[0]) == ladder[0]
+    assert list(ladder) == sorted(set(ladder)) and len(ladder) <= 3
+    tile = moe.row_tile(pairs, width)
+    assert tile in (1, moe.GMM_ROWS, moe.ALIGNED_ROWS)
+    # the last rung holds the worst load: every pair on a held expert and,
+    # aligned, every expert's last tile holding one row
+    spread = np.full(held, pairs // held)
+    spread[:pairs % held] += 1
+    assert ladder[-1] >= int(padded_rows(spread, pairs, width))
+    assert ladder[-1] >= (pairs + held * (tile - 1)) // tile * tile
+    if len(ladder) > 1:     # whole tiles, with room over the expected load
+        assert ladder[0] % (tile if tile > 1 else moe.GMM_ROWS) == 0
+        assert ladder[0] >= (1.25 * pairs * held / width
+                             + held * (3 * tile // 8))
+    # a larger rung is a whole number of first rungs, worked one at a time
+    assert all(rows % ladder[0] == 0 for rows in ladder)
+
+
+@pytest.mark.parametrize("group,want", [
+    (64, 1), (96, 1), (127, 1),         # under half a tile an expert: packed
+    # the tile that holds 1.25x the expected load in the fewer rows; the
+    # chip's readings (PERF.md section 6, PR 39) are 128, 192, 256, 400,
+    # 640 and 1024
+    (128, 256), (192, 256), (256, 320), (400, 256), (640, 320), (1024, 320),
+    (1279, 320), (1280, 1), (2048, 1),  # four tiles an expert or more: packed
+])
+def test_the_row_tile_comes_from_the_expected_load(group, want):
+    assert moe.row_tile(group * 128, 128) == want
+    assert moe.row_tile(group * 32, 32) == want     # whatever the width
+    if want > 1:
+        room = -(-5 * group // 4)
+        other = ({moe.GMM_ROWS, moe.ALIGNED_ROWS} - {want}).pop()
+        assert -(-room // want) * want <= -(-room // other) * other
+
+
+def test_all_the_pairs_within_one_tile_stay_packed():
+    assert moe.row_tile(moe.ALIGNED_ROWS, 1) == 1
+    assert moe.row_tile(moe.ALIGNED_ROWS + 1, 1) == moe.GMM_ROWS
+
+
+# ---- the buffer's layout: every expert's pairs in whole row tiles ----
+
+# 18 tokens x 4 picks over a router 16 wide, experts 4..7 held, at a row
+# tile of 8 (the constant is read when the layer is traced): rungs of 40,
+# 80 and 120 rows, the worst padded load of all 72 pairs being 96. Each
+# case is ``{the four experts a kind of token picks: how many tokens}``
+LAYOUT_TILE, LAYOUT_FIRST, LAYOUT_HELD = 8, 4, 4
+LAYOUT_LADDER = (40, 80, 120)
+LAYOUTS = {
+    # expert 5 holds 13 pairs (over a tile), 6 exactly a tile, 7 under one,
+    # 4 none: 32 rows, the first rung
+    "uneven": (0, {(5, 6, 0, 1): 8, (5, 7, 0, 1): 5, (0, 1, 2, 3): 5}),
+    # nine pairs each (two tiles for one row more): 64 rows, past the first
+    "past_the_first": (1, {(4, 5, 6, 7): 9, (0, 1, 2, 3): 9}),
+    # every pair on a held expert and every expert two rows into its third
+    # tile: 96 rows for 72 pairs, the worst there is, on the last rung
+    "worst": (2, {(4, 5, 6, 7): 18}),
+}
+
+
+def layout_case(name):
+    """``(x, router, experts, load)``: tokens that are one-hot in their
+    kind (then noise the router does not read), a router that gives a
+    kind's four experts a high logit, seeded expert stacks ``[4, 64, 32]``
+    and the held experts' loads."""
+    rng = np.random.default_rng(31)
+    kinds = LAYOUTS[name][1]
+    x = rng.normal(size=(18, 64)).astype(np.float32)
+    x[:, :8] = 0.0
+    router = np.zeros((64, 16), np.float32)
+    load = np.zeros(16, np.int64)
+    at = 0
+    for kind, (experts, count) in enumerate(kinds.items()):
+        x[at:at + count, kind] = 1.0
+        router[kind, list(experts)] = 8.0 + 0.1 * np.arange(4)
+        load[list(experts)] += count
+        at += count
+    assert at == 18
+    stacks = {k: jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[1]),
+                             jnp.float32)
+              for k, shape in (("gate", (4, 64, 32)), ("up", (4, 64, 32)),
+                               ("down", (4, 32, 64)))}
+    held = load[LAYOUT_FIRST:LAYOUT_FIRST + LAYOUT_HELD]
+    return jnp.asarray(x[rng.permutation(18)]), jnp.asarray(router), stacks, held
+
+
+def dense_layer(x, router, experts, first):
+    """The routed part in its dense form: every held expert on every
+    token, each token's held picks selected and weighted."""
+    picks, weights = moe.route_topk(x, router, 4)
+    hidden = jax.nn.silu(jnp.einsum("nd,edf->enf", x, experts["gate"])) \
+        * jnp.einsum("nd,edf->enf", x, experts["up"])
+    out = jnp.einsum("enf,efd->end", hidden, experts["down"])
+    held = out.shape[0]
+    y = jnp.zeros(x.shape, jnp.float32)
+    for j in range(4):
+        local = picks[:, j] - first
+        ok = (local >= 0) & (local < held)
+        row = out[jnp.clip(local, 0, held - 1), jnp.arange(x.shape[0])]
+        y = y + jnp.where(ok[:, None], row * weights[:, j, None], 0.0)
+    return y
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_every_experts_pairs_fill_whole_row_tiles(impl, name, stacked,
+                                                  request, monkeypatch):
+    if impl == "gmm":
+        request.getfixturevalue("pallas_interpret")
+    monkeypatch.setattr(moe, "ALIGNED_ROWS", LAYOUT_TILE)
+    monkeypatch.setattr(moe, "GMM_ROWS", LAYOUT_TILE)
+    x, router, experts, load = layout_case(name)
+    assert moe.row_tile(18 * 4, 16) == LAYOUT_TILE
+    assert moe.bucket_ladder(18 * 4, LAYOUT_HELD, 16) == LAYOUT_LADDER
+    # what the grouped products are handed, read where they are called
+    seen = []
+    grouped_dot = moe._grouped_dot
+
+    def recorded(lhs, rhs, sizes, *args, **kw):
+        jax.debug.callback(lambda s: seen.append(np.asarray(s)), sizes)
+        return grouped_dot(lhs, rhs, sizes, *args, **kw)
+    monkeypatch.setattr(moe, "_grouped_dot", recorded)
+    kw = {}
+    if stacked:     # layer 1 of three, read in place
+        experts = {k: jnp.stack([v * 0.5, v, v * 2.0])
+                   for k, v in experts.items()}
+        kw = {"layer": jnp.int32(1)}
+    with jax.default_matmul_precision("highest"):
+        got, picks, bucket = jax.jit(lambda a, e: moe.moe_dropless(
+            a, router, e, top_k=4, first_expert=LAYOUT_FIRST, impl=impl,
+            **kw))(x, experts)
+        want = dense_layer(x, router, {k: v[1] if stacked else v
+                                       for k, v in experts.items()},
+                           LAYOUT_FIRST)
+    jax.effects_barrier()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    np.testing.assert_array_equal(
+        np.bincount(np.asarray(picks).ravel(), minlength=16)[4:8], load)
+    want_bucket = LAYOUTS[name][0]
+    tiles = -(-load // LAYOUT_TILE)
+    rows = int(tiles.sum()) * LAYOUT_TILE
+    assert int(bucket) == want_bucket and rows <= LAYOUT_LADDER[want_bucket]
+    assert want_bucket == 0 or rows > LAYOUT_LADDER[want_bucket - 1]
+    if name == "worst":         # no padded load is larger
+        assert rows == (72 + LAYOUT_HELD * (LAYOUT_TILE - 1)) \
+            // LAYOUT_TILE * LAYOUT_TILE
+    # three products a first rung worked; together each product's groups
+    # are every expert's pairs in whole tiles, each starting on a boundary
+    assert len(seen) == 3 * (want_bucket + 1)
+    assert all((s % LAYOUT_TILE == 0).all() for s in seen)
+    for product in range(3):
+        groups = sum(seen[product::3])
+        if stacked:
+            groups = groups.reshape(3, LAYOUT_HELD)
+            assert not groups[[0, 2]].any()
+            groups = groups[1]
+        np.testing.assert_array_equal(groups, tiles * LAYOUT_TILE)
+        assert moe.row_tile_visits(groups, LAYOUT_TILE) == tiles.sum()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_aligned_groups_are_visited_once_a_tile_of_their_own(seed):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+
+    rng = np.random.default_rng(seed)
+    tile = int(rng.choice([8, 128, 256, 384]))
+    sizes = rng.integers(0, 4 * tile, size=int(rng.integers(1, 40)))
+    sizes[rng.random(sizes.shape) < 0.2] = 0            # experts with no pair
+    sizes[rng.random(sizes.shape) < 0.2] = 2 * tile     # whole tiles
+    padded = -(-sizes // tile) * tile
+    starts = np.cumsum(padded) - padded
+    assert (starts % tile == 0).all() and (padded[sizes == 0] == 0).all()
+    assert ((padded >= sizes) & (padded - sizes < tile)).all()
+    aligned = moe.row_tile_visits(padded, tile)
+    assert aligned == int(np.ceil(sizes / tile).sum())
+    assert aligned * tile == padded.sum()       # every visited row a group's
+    # where groups start anywhere, a tile two groups share is visited twice
+    packed = moe.row_tile_visits(sizes, tile)
+    live = int((sizes > 0).sum())
+    assert -(-sizes.sum() // tile) <= packed <= sizes.sum() // tile + live
+    assert packed <= aligned + live
+    # the count is the kernel's own grid
+    for groups in (sizes, padded):
+        m = max(int(-(-groups.sum() // tile) * tile), tile)
+        _, visits = make_group_metadata(
+            group_sizes=jnp.asarray(groups, jnp.int32), m=m, tm=tile,
+            start_group=jnp.int32(0), num_nonzero_groups=len(groups),
+            visit_empty_groups=False)
+        assert int(visits) == moe.row_tile_visits(groups, tile)
 
 
 def test_a_single_rung_leaves_no_conditional_in_the_program(seeded):
@@ -319,7 +531,7 @@ def test_a_single_rung_leaves_no_conditional_in_the_program(seeded):
             a, router, held, top_k=4, first_expert=first,
             impl="ragged")).lower(
                 jax.ShapeDtypeStruct((n, 64), jnp.float32)).as_text()
-    assert "case" not in text(512, 0, 16)       # every expert held
+    assert "case" not in text(500, 0, 16)       # every expert held, packed
     assert "case" not in text(40, 4, 4)         # pairs under a row tile
     assert "case" in text(512, 4, 4)
 
@@ -328,25 +540,27 @@ def test_the_bucket_node_and_its_counters(seeded):
     from mmlspark_tpu.obs.metrics import registry
 
     cfg, params = seeded
-    # 4 of 16 experts held; 64 tokens x 4 picks a row, 8 rows a step: 2048
-    # pairs a step, the ladder of the cases above
+    # 4 of 16 experts held; 62 tokens x 4 picks a row, 8 rows a step: 1984
+    # pairs a step, 124 an expert (packed): rungs of 768, 1536 and 2304 rows
     share_cfg = tiny(n_routed_experts=4, first_expert=LADDER_FIRST)
     share = {"outer": params["outer"], "layers": [
         held_share(p, LADDER_FIRST) for p in params["layers"]]}
-    # layer 1 sends every pick to a held expert: its steps take the last rung
+    # layer 1 sends every pick of half the tokens to the held experts: its
+    # steps pass the first rung
     router = np.array(share["layers"][1]["moe/router/kernel"], np.float32)
     router[:, 4:8] = 50.0 * np.abs(router[:, 4:8]).max()
     share["layers"][1] = dict(share["layers"][1],
                               **{"moe/router/kernel": jnp.asarray(router)})
-    tokens = tokens_of(16, (8, 64))
+    tokens = tokens_of(16, (8, 62))
     bucket = apply(share_cfg, share, tokens, "moe_bucket")
     assert bucket.shape == (8, 3) and bucket.dtype == np.int32
     assert (bucket == bucket[0]).all()          # one step: one rung a layer
     load = apply(share_cfg, share, tokens, "expert_load").reshape(8, 3, 4)
-    count = load.sum(axis=(0, 2))               # held pairs a layer-step
-    ladder = np.array(moe.bucket_ladder(8 * 64 * 4, 4, 16))
+    rows = padded_rows(load.sum(axis=0), 8 * 62 * 4, 16)    # a layer-step
+    ladder = np.array(moe.bucket_ladder(8 * 62 * 4, 4, 16))
+    assert tuple(ladder) == (768, 1536, 2304)
     np.testing.assert_array_equal(
-        bucket[0], [int(np.argmax(ladder >= c)) for c in count])
+        bucket[0], [int(np.argmax(ladder >= r)) for r in rows])
     assert bucket[0, 0] == 0
     before = {k: registry().value(k) or 0
               for k in ("moe.bucket_steps", "moe.bucket_steps_first")}
@@ -535,7 +749,8 @@ def test_a_padded_tail_step_takes_a_later_rung_and_answers_alike(seeded):
         whole = np.asarray(module.apply(
             {"params": tree}, jnp.asarray(tokens[:8], jnp.float32),
             output="token_logprob"))
-    assert moe.bucket_ladder(8 * 64 * 4, 4, 16) == (768, 1024, 2048)
+    # 128 pairs an expert: every expert's pairs in whole tiles of 256 rows
+    assert moe.bucket_ladder(8 * 64 * 4, 4, 16) == (1024, 2048, 3072)
     assert (bucket[:8] == 0).all()              # the full step: first rung
     assert bucket[8].max() > 0                  # the padded one: a later one
     np.testing.assert_allclose(got[8:], alone, atol=3e-5)

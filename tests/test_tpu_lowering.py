@@ -102,8 +102,9 @@ KERNEL_CASES = {
          S((2, 32, 4096, 2048), BF16), S((2, 32, 4096, 2048), BF16),
          S((2, 32, 2048, 4096), BF16))),
     # the same layer as the model runs it: under the layer scan, where its
-    # row-count ladder (10240, 16384, 32768 at these shapes) puts the three
-    # grouped products of each rung in a branch of a conditional
+    # row-count ladder (14080 padded rows, twice and four times that at
+    # these shapes) puts the three grouped products of each rung in a branch
+    # of a conditional
     "moe_dropless_ladder_under_the_layer_scan": (
         _moe_layers,
         (S((8192, 4096), BF16), S((4096, 128), F32),
@@ -165,11 +166,11 @@ def test_kernel_lowers_for_tpu(name):
     # the kernel itself is in the program — not its XLA reference
     assert "tpu_custom_call" in text
     if name == "moe_dropless_ladder_under_the_layer_scan":
-        # the rungs' grouped products, two kernels a row count (gate and
-        # up have one signature and share a function; the last rung runs in
-        # pieces of the middle one and shares its kernels)
+        # the rungs' grouped products: two kernels in all (gate and up
+        # have one signature and share a function; a higher rung is worked
+        # a first rung at a time and shares the first's kernels)
         assert "stablehlo.case" in text and "stablehlo.while" in text
-        assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
     if name == "moe_dropless_grouped_products_f1792":
         # every expert held: one rung, so no conditional; gate and up share
         # a kernel, down has its own
