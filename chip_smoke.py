@@ -25,9 +25,10 @@ compiler:
   kernel;
 * **kernels** — ``decode_attention``, ``flash_attention`` (ViT-B tile,
   causal T=1024, and the tiled kernel at a causal T=4096 and a masked
-  T=2100), ``attention_block_update``, ``group_norm``, and the selective
-  scan at one row of 16,384 positions x 5,120 channels, each against its
-  XLA reference.
+  T=2100), ``attention_block_update``, ``group_norm``, the selective
+  scan at one row of 16,384 positions x 5,120 channels, the Mamba-2 scan
+  at one row of 16,384 positions x 64 heads of 64 and the short causal
+  convolution at both families' shapes, each against its XLA reference.
 
 With more than one device visible the multi-device branches switch on:
 the train phase also runs over the default ``dp`` mesh (one batch shard
@@ -109,6 +110,10 @@ KERNEL_RTOL = 2e-2
 GN_ATOL = 6e-2
 # the selective scan's gated output in bfloat16, values up to ~20
 SCAN_ATOL = 5e-2
+# the Mamba-2 scan: products of a chunk's 128 terms on bfloat16 operands
+# (the decay-weighted C.B and dt x x rounded to 2^-9 each), a bfloat16
+# output, values up to ~10 (read on the chip: 0.125 at most on this draw)
+SSD_ATOL = 1.5e-1
 
 
 class SmokeFailure(Exception):
@@ -617,6 +622,7 @@ def phase_kernels(devices) -> dict:
     from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
     from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
     from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
+    from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan
 
     rng = np.random.default_rng(4)
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -745,6 +751,21 @@ def phase_kernels(devices) -> dict:
         lambda *o: selective_scan(*o, impl="pallas").astype(f32),
         lambda *o: selective_scan(*o, impl="xla").astype(f32),
         args, SCAN_ATOL, KERNEL_RTOL)
+
+    # the Mamba-2 scan at one row of its cell: 16,384 positions in 16 blocks
+    # of 8 chunks whose state (a group's [128, 8 x 64]) is carried in VMEM,
+    # 64 heads of 64 in 8 groups, [x | B | C] read where they lie
+    sizes = dict(heads=64, head_dim=64, groups=8, state=128)
+    args = (_bf16_exact(rng, (1, L, 6144), bf16),
+            jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                           (1, L, 64))), f32),
+            -jnp.asarray(rng.uniform(1, 16, 64), f32),
+            1 + 0.1 * _bf16_exact(rng, (64,), f32))
+    facts["ssd_scan[L16384,H64,P64,G8,N128,bf16]"] = _kernel_case(
+        "ssd_scan",
+        lambda *o: ssd_scan(*o, impl="pallas", **sizes).astype(f32),
+        lambda *o: ssd_scan(*o, impl="xla", **sizes).astype(f32),
+        args, SSD_ATOL, KERNEL_RTOL)
 
     # the short causal convolution at both families' cells, each reading
     # the wide float32 product where it lies: the Mamba mixer's (4 taps,

@@ -249,13 +249,16 @@ class ExpertStacks(nn.Module):
     """The held routed experts' weights of ALL layers, ``[layers, held, ...]``
     stacks that ``moe_dropless`` reads in place (a scan over them would
     copy a layer's 1.6 GB out of the stack every step: a kernel's operand
-    cannot be a slice)."""
+    cannot be a slice). ``gated``: whether the experts have a ``gate``
+    stack beside ``up`` and ``down`` (``moe_dropless`` takes the expert's
+    form from the stacks it is handed)."""
 
     layers: int
     held: int
     hidden: int
     width: int
     param_dtype: Any
+    gated: bool = True
 
     @nn.compact
     def __call__(self) -> dict:
@@ -263,9 +266,11 @@ class ExpertStacks(nn.Module):
             return self.param(name, _fan_in_normal,
                               (self.layers, self.held, rows, cols),
                               self.param_dtype)
-        return {"gate": stack("gate", self.hidden, self.width),
-                "up": stack("up", self.hidden, self.width),
-                "down": stack("down", self.width, self.hidden)}
+        shapes = {"gate": (self.hidden, self.width),
+                  "up": (self.hidden, self.width),
+                  "down": (self.width, self.hidden)}
+        return {name: stack(name, *shape) for name, shape in shapes.items()
+                if self.gated or name != "gate"}
 
 
 class SharedExpert(nn.Module):
@@ -446,9 +451,16 @@ def _jamba_lm(cfg: dict, overrides: dict) -> nn.Module:
         **config_fields(lm_ssm.JambaConfig, cfg, **overrides)))
 
 
+def _nemotron_h_lm(cfg: dict, overrides: dict) -> nn.Module:
+    from mmlspark_tpu.models import lm_mamba2
+    return lm_mamba2.NemotronHLM(lm_mamba2.NemotronHConfig(
+        **config_fields(lm_mamba2.NemotronHConfig, cfg, **overrides)))
+
+
 # ``model_type`` -> the family's builder; a type that is not here is built
 # as a LatentMoELM (``mistral4`` / DeepSeek-V3-style keys)
-FAMILIES = {"lfm2_moe": _conv_lm, "jamba": _jamba_lm}
+FAMILIES = {"lfm2_moe": _conv_lm, "jamba": _jamba_lm,
+            "nemotron_h": _nemotron_h_lm}
 
 
 def from_config(cfg: dict, **overrides) -> nn.Module:
@@ -456,9 +468,10 @@ def from_config(cfg: dict, **overrides) -> nn.Module:
     (plus ``compute_dtype`` / ``param_dtype``), by its ``model_type``
     (:data:`FAMILIES`): ``lfm2_moe`` gives a :class:`~mmlspark_tpu.models.
     lm_conv.ConvMoELM`, ``jamba`` a :class:`~mmlspark_tpu.models.lm_ssm.
-    JambaLM`, anything else a :class:`LatentMoELM` (with ``router_width`` /
-    ``first_expert`` for a share); ``overrides`` are further fields of the
-    module's config."""
+    JambaLM`, ``nemotron_h`` a :class:`~mmlspark_tpu.models.lm_mamba2.
+    NemotronHLM` (``router_width`` / ``first_expert`` for a share), anything
+    else a :class:`LatentMoELM` (the same two keys for a share);
+    ``overrides`` are further fields of the module's config."""
     return FAMILIES.get(cfg.get("model_type"), _latent_lm)(cfg, overrides)
 
 

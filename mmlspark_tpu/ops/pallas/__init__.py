@@ -16,11 +16,17 @@ VMEM-resident formulation avoids HBM traffic the default lowering pays:
   two ``[L, channels, states]`` tensors in HBM (imported from its module:
   the function has the module's name).
 
+* :mod:`~mmlspark_tpu.ops.pallas.ssd_scan` — the Mamba-2 recurrence of
+  ``models/lm_mamba2.py`` (one scalar decay a head a position) in its
+  chunked matrix-product form: ``[x | B | C]`` read where they lie, a
+  group's ``[state, heads x head_dim]`` state carried in VMEM, the decay
+  matrices never in HBM. Its entry is one jitted function too.
+
 * :mod:`~mmlspark_tpu.ops.pallas.causal_conv` — the short depthwise
-  causal convolution of ``models/lm_conv.py`` and ``models/lm_ssm.py``
-  with the gates, bias and activation around it: one read of each input
-  position, taken from the wide float32 product where it lies, instead
-  of a cut-out copy and one HBM read a tap. Its entry is one jitted
+  causal convolution of ``models/lm_conv.py``, ``models/lm_ssm.py`` and
+  ``models/lm_mamba2.py`` with the gates, bias and activation around it:
+  one read of each input position, taken from the wide float32 product
+  where it lies, instead of a cut-out copy and one HBM read a tap. Its entry is one jitted
   function, so a model's call sites share a trace and a lowering.
 
 (The fused GroupNorm kernel lives next to its reference in

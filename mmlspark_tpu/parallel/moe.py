@@ -280,6 +280,16 @@ def moe_reference(params: dict, x: Any) -> Any:
 # ---- the dropless top-k layer of one expert-parallel share ----
 
 ROUTER_SCORES = ("softmax", "sigmoid")
+# what an expert applies between its products (``relu2``: the squared ReLU)
+ACTIVATIONS = ("silu", "relu2")
+
+
+def _activation(name: str):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.nn.silu if name == "silu" else (
+        lambda v: jnp.square(jax.nn.relu(v)))
 
 
 def route_topk(x: Any, router: Any, top_k: int, norm_topk: bool = True,
@@ -456,14 +466,19 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                  first_expert: int = 0, norm_topk: bool = True,
                  scaling: float = 1.0, impl: str = "auto",
                  layer: Any = None, score: str = "softmax",
-                 bias: Any = None, norm_eps: float = 0.0
-                 ) -> tuple[Any, Any, Any]:
+                 bias: Any = None, norm_eps: float = 0.0,
+                 activation: str | None = None) -> tuple[Any, Any, Any]:
     """The routed part of a top-k expert layer on the share that holds
     experts ``[first_expert, first_expert + held)`` of the router's width.
 
     ``x`` ``[N, d]``; ``router`` ``[d, E]``; ``experts`` holds the held
-    experts' gated-SiLU stacks ``gate``/``up`` ``[held, d, f]`` and ``down``
-    ``[held, f, d]`` — or, with ``layer`` (an index, which may be traced),
+    experts' stacks ``up`` ``[held, d, f]`` and ``down`` ``[held, f, d]``,
+    and the expert's form follows what it is handed: with a ``gate`` stack
+    beside them the expert is gated, ``down(act(x gate) * (x up))``, three
+    grouped products; without one it is ungated, ``down(act(x up))``, two.
+    ``activation`` is one of ``ACTIVATIONS`` (``None``: ``silu`` for a gated
+    expert, ``relu2``, the squared ReLU, for an ungated one). Or, with
+    ``layer`` (an index, which may be traced),
     the stacks of ALL layers with a leading layer axis: the grouped product
     then reads layer ``layer``'s matrices where they lie (its groups are
     that layer's; every other layer's are empty), where slicing the layer
@@ -522,7 +537,13 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
         raise ValueError(f"unknown expert impl {impl!r}; one of "
                          f"{EXPERT_IMPLS}")
     n, d = x.shape
-    held = experts["gate"].shape[-3]
+    gated = "gate" in experts
+    if activation is None:
+        activation = "silu" if gated else "relu2"
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"unknown expert activation {activation!r}; one "
+                         f"of {sorted(ACTIVATIONS)}")
+    held = experts["down"].shape[-3]
     tile = row_tile(n * top_k, router.shape[1])
     tm = tile if tile > 1 else GMM_ROWS     # the kernel's row tile
     registry().gauge(ROW_TILE_GAUGE).set(tile)
@@ -541,7 +562,7 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     ends = jnp.cumsum(padded)
     groups = padded
     if layer is not None:
-        layers = experts["gate"].shape[0]
+        layers = experts["down"].shape[0]
         groups = jnp.zeros((layers, held), jnp.int32).at[layer].set(
             padded).reshape(-1)
         experts = {k: v.reshape((layers * held,) + v.shape[2:])
@@ -570,10 +591,16 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
             row = row - _pad_below(row, ends, pad)
         pair = jnp.take(order, row, mode="clip")
         xs = jnp.take(x, pair // top_k, axis=0, mode="clip")
-        gate = _grouped_dot(xs, experts["gate"], part, impl, rows=tm)
-        up = _grouped_dot(xs, experts["up"], part, impl, rows=tm)
-        act = (jax.nn.silu(gate) * up).astype(dtype)
-        return _grouped_dot(act, experts["down"], part, impl, dtype, rows=tm)
+        act = _activation(activation)
+        if gated:
+            gate = _grouped_dot(xs, experts["gate"], part, impl, rows=tm)
+            up = _grouped_dot(xs, experts["up"], part, impl, rows=tm)
+            hidden = (act(gate) * up).astype(dtype)
+        else:
+            hidden = act(_grouped_dot(xs, experts["up"], part, impl,
+                                      rows=tm)).astype(dtype)
+        return _grouped_dot(hidden, experts["down"], part, impl, dtype,
+                            rows=tm)
 
     ladder = bucket_ladder(n * top_k, held, router.shape[1])
 

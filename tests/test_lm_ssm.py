@@ -290,7 +290,7 @@ def test_routed_experts_and_other_unbuilt_settings_are_refused():
     import test_lm_latent_moe as latent
     assert isinstance(lm.from_config(conv.tiny()), lm_conv.ConvMoELM)
     assert isinstance(lm.from_config(latent.tiny()), lm.LatentMoELM)
-    assert set(lm.FAMILIES) == {"lfm2_moe", "jamba"}
+    assert set(lm.FAMILIES) == {"lfm2_moe", "jamba", "nemotron_h"}
 
 
 def test_the_reference_makes_every_leaf_of_the_programs_tree(seeded):

@@ -38,6 +38,7 @@ from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
+from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan  # noqa: E402
 from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
 
 S = jax.ShapeDtypeStruct
@@ -148,6 +149,22 @@ KERNEL_CASES = {
         lambda bcu, w: causal_conv(bcu, w, channels=2048, at=4096, pre_at=0,
                                    post_at=2048, dtype=BF16, impl="pallas"),
         (S((2, 8192, 6144), F32), S((3, 2048), BF16))),
+    # the Mamba-2 family's cell: one row of 16,384 positions, 64 heads of 64
+    # in 8 groups, state 128; [x | B | C] read where they lie in the
+    # convolved [1, 16384, 6144], 16 blocks of 8 chunks carry a group's
+    # state in VMEM scratch; and its two UNGATED grouped products at d 2688,
+    # f 1856: a width that is 14.5 lane rows, taken whole
+    "ssd_scan_t16384_h64_p64_g8_n128": (
+        lambda xbc, dt, a, d: ssd_scan(xbc, dt, a, d, heads=64, head_dim=64,
+                                       groups=8, state=128, impl="pallas"),
+        (S((1, 16384, 6144), BF16), S((1, 16384, 64), F32), S((64,), F32),
+         S((64,), F32))),
+    "moe_dropless_ungated_products_f1856": (
+        lambda x, r, b, u, d: moe_dropless(
+            x, r, {"up": u, "down": d}, top_k=6, impl="gmm", layer=1,
+            scaling=2.5, score="sigmoid", bias=b, norm_eps=1e-20)[0],
+        (S((16384, 2688), BF16), S((2688, 128), F32), S((128,), F32),
+         S((2, 64, 2688, 1856), BF16), S((2, 64, 1856, 2688), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -181,6 +198,17 @@ def test_kernel_lowers_for_tpu(name):
         # [1, 16384, 5120] array, nothing of shape [L, 5120, 16]
         assert "stablehlo.pad" not in text
         assert "16384x5120x16" not in text
+    if name == "ssd_scan_t16384_h64_p64_g8_n128":
+        # the wide operand goes in as it is, three times: no part of it is
+        # cut out or padded to feed the call
+        assert "stablehlo.slice" not in text and "stablehlo.pad" not in text
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+    if name == "moe_dropless_ungated_products_f1856":
+        # half the experts held: a ladder of two rungs, so a conditional;
+        # each rung's two products (no third: the experts have no gate),
+        # the higher rung sharing the first's kernels
+        assert "stablehlo.case" in text
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
     if name.startswith("causal_conv"):
         # the parts are read where they lie: no slice of the wide product
         # is cut out (or padded) to feed the call
