@@ -27,8 +27,9 @@ compiler:
   causal T=1024, and the tiled kernel at a causal T=4096 and a masked
   T=2100), ``attention_block_update``, ``group_norm``, the selective
   scan at one row of 16,384 positions x 5,120 channels, the Mamba-2 scan
-  at one row of 16,384 positions x 64 heads of 64 and the short causal
-  convolution at both families' shapes, each against its XLA reference.
+  at one row of 16,384 positions x 64 heads of 64, the short causal
+  convolution at both families' shapes and the expert combine at the
+  Nemotron cell's buffer (to the bit), each against its XLA reference.
 
 With more than one device visible the multi-device branches switch on:
 the train phase also runs over the default ``dp`` mesh (one batch shard
@@ -621,6 +622,7 @@ def phase_kernels(devices) -> dict:
     from mmlspark_tpu.ops.pallas import attention as fa
     from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
     from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
+    from mmlspark_tpu.ops.pallas.moe_combine import moe_combine
     from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
     from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan
 
@@ -766,6 +768,31 @@ def phase_kernels(devices) -> dict:
         lambda *o: ssd_scan(*o, impl="pallas", **sizes).astype(f32),
         lambda *o: ssd_scan(*o, impl="xla", **sizes).astype(f32),
         args, SSD_ATOL, KERNEL_RTOL)
+
+    # the expert combine at the Nemotron cell's first rung: 16,384 tokens'
+    # picks of 6 over a router 128 wide, the first 64 experts held, their
+    # pairs sorted by expert in groups padded to 320-row tiles as
+    # moe_dropless lays them in the 354 MiB buffer, the rows past the last
+    # group poisoned; equal to the array form to the bit
+    n, k, width, held, tile = 16384, 6, 128, 64, 320
+    picks = np.argsort(rng.random((n, width)), axis=1)[:, :k]
+    group = np.where(picks < held, picks, held).reshape(-1)
+    rank = np.argsort(np.argsort(group, kind="stable"))
+    sizes = np.bincount(group, minlength=held + 1)[:held]
+    starts = np.concatenate([[0], np.cumsum(-(-sizes // tile) * tile)])
+    before = np.concatenate([[0], np.cumsum(sizes)])
+    first = np.minimum(group, held)
+    back = np.where(group < held, starts[first] + rank - before[first], 0)
+    ys = _bf16_exact(rng, (69120, 2688), bf16).at[int(starts[-1]):].set(
+        jnp.nan)
+    args = (ys, jnp.asarray(back.reshape(n, k), jnp.int32),
+            jnp.asarray(group.reshape(n, k), jnp.int32),
+            jnp.asarray(rng.random((n, k)), f32))
+    facts["moe_combine[ys69120x2688,N16384,k6,held64]"] = _kernel_case(
+        "moe_combine",
+        lambda *o: moe_combine(*o, held=held, impl="pallas"),
+        lambda *o: moe_combine(*o, held=held, impl="xla"),
+        args, 0.0, 0.0)
 
     # the short causal convolution at both families' cells, each reading
     # the wide float32 product where it lies: the Mamba mixer's (4 taps,

@@ -29,6 +29,13 @@ VMEM-resident formulation avoids HBM traffic the default lowering pays:
   where it lies, instead of a cut-out copy and one HBM read a tap. Its entry is one jitted
   function, so a model's call sites share a trace and a lowering.
 
+* :mod:`~mmlspark_tpu.ops.pallas.moe_combine` — the combine of
+  ``parallel/moe.moe_dropless``: each token's held picks read from the
+  sorted expert buffer where it lies (the 8-row tiles a block of tokens'
+  rows lie in, staged by DMA), weighted and summed in float32 to the bit
+  of the array form, where that form is ``k`` row gathers over the whole
+  buffer. One jitted entry.
+
 (The fused GroupNorm kernel lives next to its reference in
 ``ops/group_norm.py``.)
 

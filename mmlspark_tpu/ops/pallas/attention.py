@@ -594,7 +594,8 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
-def _takes_kernel(impl: str, kernel: str, fits: bool, shape: tuple) -> bool:
+def _takes_kernel(impl: str, kernel: str, fits: bool, shape: tuple,
+                  budget: int = VMEM_BUDGET) -> bool:
     """The one dispatch decision of every wrapper below. A tile that
     does not fit VMEM never switches implementation quietly: under
     ``auto`` the reference runs and the miss is logged and counted,
@@ -607,7 +608,7 @@ def _takes_kernel(impl: str, kernel: str, fits: bool, shape: tuple) -> bool:
         raise ValueError(
             f"{kernel}: tile {shape} exceeds the VMEM budget and "
             "impl='pallas' was demanded; use impl='auto' or 'xla'")
-    note_vmem_fallback(kernel, shape)
+    note_vmem_fallback(kernel, shape, budget)
     return False
 
 

@@ -376,8 +376,6 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str, out_dtype=None,
     import jax
     import jax.numpy as jnp
 
-    if impl == "auto":
-        impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     if impl == "ragged":
         return jax.lax.ragged_dot(
@@ -509,29 +507,37 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     holds this call's (padded) rows, chosen on the device
     (``lax.switch``, one branch per rung). The rows of a rung are a prefix
     of the buffer's order, so they hold every held pair; each token then
-    gathers its held picks' rows, pick by pick, and adds them weighted in
-    float32 (an absent pick is selected away; the rows past the buffer's
-    last group are never read). No capacity: a call whose rows pass a rung
-    takes the next, and the last rung has room for the worst load of all
-    pairs, so no token is dropped at any load; a rung above the first is a
-    whole number of first rungs and is worked one at a time, so the rare
-    larger buffers need no more scratch, and no other kernel, than the
-    usual one; its rows past the last pair read a clipped pair that no
-    token reads back. With one rung (the first would hold the worst
-    load: every expert held and the pairs packed or a few tiles an expert,
-    or fewer pairs than a row tile) the program has no conditional.
+    reads its held picks' rows back and adds them weighted in float32, pick
+    by pick (an absent pick is selected away; the rows past the buffer's
+    last group reach no token): beside the ``gmm`` kernel that is the
+    :mod:`~mmlspark_tpu.ops.pallas.moe_combine` kernel, which reads the
+    rows where the buffer lies and only the tiles they lie in, where the
+    array form (beside ``ragged_dot``) is ``top_k`` row gathers over the
+    whole buffer; the two answer alike to the bit. No capacity: a call
+    whose rows pass a rung takes the next, and the last rung has room for
+    the worst load of all pairs, so no token is dropped at any load; a
+    rung above the first is a whole number of first rungs and is worked
+    one at a time, so the rare larger buffers need no more scratch, and no
+    other kernel, than the usual one; its rows past the last pair read a
+    clipped pair that no token reads back. With one rung (the first would
+    hold the worst load: every expert held and the pairs packed or a few
+    tiles an expert, or fewer pairs than a row tile) the program has no
+    conditional.
 
     Returns ``(y [N, d] float32, picks [N, k], bucket () int32)``: ``y =
     sum over held picks of w_k E_k(x)``, the weights normalised over all
     ``top_k`` picks; ``bucket`` is the index of the rung taken.
     This is what expert parallelism over ``ep`` asks of one shard; the
     exchange that would bring other shards' tokens here is not part of it.
-    Gauge, set when traced: ``moe.row_tile``.
+    Gauges, set when traced: ``moe.row_tile``; ``moe.combine`` (by
+    :func:`~mmlspark_tpu.ops.pallas.moe_combine.moe_combine`: 1 where the
+    combine is the kernel, 0 where it is the array form).
     """
     import jax
     import jax.numpy as jnp
 
     from mmlspark_tpu.obs.metrics import registry
+    from mmlspark_tpu.ops.pallas.moe_combine import moe_combine
 
     if impl not in EXPERT_IMPLS:
         raise ValueError(f"unknown expert impl {impl!r}; one of "
@@ -547,6 +553,12 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
     tile = row_tile(n * top_k, router.shape[1])
     tm = tile if tile > 1 else GMM_ROWS     # the kernel's row tile
     registry().gauge(ROW_TILE_GAUGE).set(tile)
+    if impl == "auto":
+        impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
+    # the combine follows the grouped products: beside gmm the kernel where
+    # the backend and the shapes take it (a miss is logged and counted),
+    # beside ragged_dot the array form
+    combine = "auto" if impl == "gmm" else "xla"
     picks, weights = route_topk(x, router, top_k, norm_topk, scaling, score,
                                 bias, norm_eps)
     local = picks - first_expert
@@ -614,14 +626,9 @@ def moe_dropless(x: Any, router: Any, experts: dict, *, top_k: int,
                              jnp.arange(count) * ladder[0]
                              ).reshape(count * ladder[0], d)
         # a held pair's row lies under the last group's end, so no row past
-        # it (unspecified) is read
-        y = jnp.zeros((n, d), jnp.float32)
-        for j in range(top_k):
-            row = jnp.take(ys, back[:, j], axis=0, mode="clip")
-            y = y + jnp.where(present[:, j, None],
-                              row.astype(jnp.float32) * weights[:, j, None],
-                              0.0)
-        return y
+        # it (unspecified) reaches a token
+        return moe_combine(ys, back, group.reshape(n, top_k), weights,
+                           held=held, impl=combine)
 
     branches = [functools.partial(on_rungs, rows // ladder[0])
                 for rows in ladder]

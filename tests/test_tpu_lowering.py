@@ -37,6 +37,7 @@ import chip_smoke  # noqa: E402 — the shapes under test are the smoke's own
 from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402
+from mmlspark_tpu.ops.pallas.moe_combine import moe_combine  # noqa: E402
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
 from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan  # noqa: E402
 from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
@@ -165,6 +166,13 @@ KERNEL_CASES = {
             scaling=2.5, score="sigmoid", bias=b, norm_eps=1e-20)[0],
         (S((16384, 2688), BF16), S((2688, 128), F32), S((128,), F32),
          S((2, 64, 2688, 1856), BF16), S((2, 64, 1856, 2688), BF16))),
+    # the expert combine at the Nemotron cell's first rung: each token's
+    # held picks of six read from the 354 MiB sorted buffer where it lies
+    "moe_combine_ys69120_d2688_k6": (
+        lambda ys, back, expert, w: moe_combine(ys, back, expert, w,
+                                                held=64, impl="pallas"),
+        (S((69120, 2688), BF16), S((16384, 6), jnp.int32),
+         S((16384, 6), jnp.int32), S((16384, 6), F32))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -177,22 +185,26 @@ KERNEL_CASES = {
 
 
 @pytest.mark.parametrize("name", sorted(KERNEL_CASES))
-def test_kernel_lowers_for_tpu(name):
+def test_kernel_lowers_for_tpu(name, monkeypatch):
     fn, specs = KERNEL_CASES[name]
+    if name.startswith("moe_dropless"):
+        # beside gmm the combine resolves by the backend: the chip's here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = lower_for_tpu(fn, *specs)
     # the kernel itself is in the program — not its XLA reference
     assert "tpu_custom_call" in text
     if name == "moe_dropless_ladder_under_the_layer_scan":
         # the rungs' grouped products: two kernels in all (gate and up
         # have one signature and share a function; a higher rung is worked
-        # a first rung at a time and shares the first's kernels)
+        # a first rung at a time and shares the first's kernels); and the
+        # combine once a rung (three): its buffer's row count is the rung's
         assert "stablehlo.case" in text and "stablehlo.while" in text
-        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 5
     if name == "moe_dropless_grouped_products_f1792":
         # every expert held: one rung, so no conditional; gate and up share
-        # a kernel, down has its own
+        # a kernel, down has its own, and the combine is the third
         assert "stablehlo.case" not in text
-        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
     if name == "selective_scan_t16384_d5120_n16":
         # the operands go in as they are: no padded or re-laid copy of a
         # [1, 16384, 5120] array, nothing of shape [L, 5120, 16]
@@ -206,9 +218,19 @@ def test_kernel_lowers_for_tpu(name):
     if name == "moe_dropless_ungated_products_f1856":
         # half the experts held: a ladder of two rungs, so a conditional;
         # each rung's two products (no third: the experts have no gate),
-        # the higher rung sharing the first's kernels
+        # the higher rung sharing the first's kernels; and a combine a rung
         assert "stablehlo.case" in text
-        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
+    if name == "moe_combine_ys69120_d2688_k6":
+        # one kernel, handed the buffer as it is: what touches the
+        # [69120, 2688] buffer is the call into the jitted entry and the
+        # kernel itself (no slice, pad, copy or gather of it)
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+        uses = [line.split("=", 1)[1].split("(")[0].strip()
+                for line in text.splitlines()
+                if "69120x2688" in line and line.lstrip().startswith("%")]
+        assert sorted(uses) == ["call @_combine_call",
+                                "stablehlo.custom_call @tpu_custom_call"]
     if name.startswith("causal_conv"):
         # the parts are read where they lie: no slice of the wide product
         # is cut out (or padded) to feed the call
