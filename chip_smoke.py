@@ -27,7 +27,8 @@ compiler:
   causal T=1024, and the tiled kernel at a causal T=4096 and a masked
   T=2100), ``attention_block_update``, ``group_norm``, the selective
   scan at one row of 16,384 positions x 5,120 channels, the Mamba-2 scan
-  at one row of 16,384 positions x 64 heads of 64, the short causal
+  at one row of 16,384 positions x 64 heads of 64, the gated delta rule at
+  two rows of 8,192 positions x 32 value heads of 128, the short causal
   convolution at both families' shapes and the expert combine at the
   Nemotron cell's buffer (to the bit), each against its XLA reference.
 
@@ -115,6 +116,11 @@ SCAN_ATOL = 5e-2
 # (the decay-weighted C.B and dt x x rounded to 2^-9 each), a bfloat16
 # output, values up to ~10 (read on the chip: 0.125 at most on this draw)
 SSD_ATOL = 1.5e-1
+# the gated delta rule: bfloat16 operands of the chunk's products (the
+# triangle's inverse, the state and the corrected values rounded to 2^-9
+# each) against float32 at "highest", a bfloat16 output of values up to
+# ~0.12 (read on the chip: 9.8e-4 at most on this kind of draw)
+GDN_ATOL = 1e-2
 
 
 class SmokeFailure(Exception):
@@ -622,6 +628,7 @@ def phase_kernels(devices) -> dict:
     from mmlspark_tpu.ops.pallas import attention as fa
     from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER
     from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
+    from mmlspark_tpu.ops.pallas.gated_delta import gated_delta
     from mmlspark_tpu.ops.pallas.moe_combine import moe_combine
     from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
     from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan
@@ -768,6 +775,21 @@ def phase_kernels(devices) -> dict:
         lambda *o: ssd_scan(*o, impl="pallas", **sizes).astype(f32),
         lambda *o: ssd_scan(*o, impl="xla", **sizes).astype(f32),
         args, SSD_ATOL, KERNEL_RTOL)
+
+    # the gated delta rule at one step of its cell: two rows of 8,192
+    # positions, 16 key heads of 128 on 32 value heads of 128, [q_j | k_j |
+    # v of j's value heads] read where they lie, the states carried in VMEM
+    # over 8 blocks of 16 chunks; a head forgets over 1 to 1,000 positions
+    sizes = dict(key_heads=16, key_dim=128, value_heads=32, value_dim=128)
+    args = (_bf16_exact(rng, (2, 8192, 8192), bf16),
+            -jnp.asarray(np.exp(rng.uniform(np.log(1e-3), 0.0,
+                                            (2, 8192, 32))), f32),
+            jnp.asarray(rng.uniform(0, 1, (2, 8192, 32)), f32))
+    facts["gated_delta[B2,L8192,HK16,HV32,D128,bf16]"] = _kernel_case(
+        "gated_delta",
+        lambda *o: gated_delta(*o, impl="pallas", **sizes).astype(f32),
+        lambda *o: gated_delta(*o, impl="xla", **sizes).astype(f32),
+        args, GDN_ATOL, KERNEL_RTOL)
 
     # the expert combine at the Nemotron cell's first rung: 16,384 tokens'
     # picks of 6 over a router 128 wide, the first 64 experts held, their

@@ -457,10 +457,16 @@ def _nemotron_h_lm(cfg: dict, overrides: dict) -> nn.Module:
         **config_fields(lm_mamba2.NemotronHConfig, cfg, **overrides)))
 
 
+def _qwen3_next_lm(cfg: dict, overrides: dict) -> nn.Module:
+    from mmlspark_tpu.models import lm_gdn
+    return lm_gdn.Qwen3NextLM(lm_gdn.Qwen3NextConfig(
+        **config_fields(lm_gdn.Qwen3NextConfig, cfg, **overrides)))
+
+
 # ``model_type`` -> the family's builder; a type that is not here is built
 # as a LatentMoELM (``mistral4`` / DeepSeek-V3-style keys)
 FAMILIES = {"lfm2_moe": _conv_lm, "jamba": _jamba_lm,
-            "nemotron_h": _nemotron_h_lm}
+            "nemotron_h": _nemotron_h_lm, "qwen3_next": _qwen3_next_lm}
 
 
 def from_config(cfg: dict, **overrides) -> nn.Module:
@@ -469,8 +475,10 @@ def from_config(cfg: dict, **overrides) -> nn.Module:
     (:data:`FAMILIES`): ``lfm2_moe`` gives a :class:`~mmlspark_tpu.models.
     lm_conv.ConvMoELM`, ``jamba`` a :class:`~mmlspark_tpu.models.lm_ssm.
     JambaLM`, ``nemotron_h`` a :class:`~mmlspark_tpu.models.lm_mamba2.
-    NemotronHLM` (``router_width`` / ``first_expert`` for a share), anything
-    else a :class:`LatentMoELM` (the same two keys for a share);
+    NemotronHLM` (``router_width`` / ``first_expert`` for a share),
+    ``qwen3_next`` a :class:`~mmlspark_tpu.models.lm_gdn.Qwen3NextLM` (the
+    same two keys, ``num_experts`` the held count), anything else a
+    :class:`LatentMoELM` (the same two keys for a share);
     ``overrides`` are further fields of the module's config."""
     return FAMILIES.get(cfg.get("model_type"), _latent_lm)(cfg, overrides)
 

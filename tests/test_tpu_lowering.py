@@ -37,6 +37,7 @@ import chip_smoke  # noqa: E402 — the shapes under test are the smoke's own
 from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402
+from mmlspark_tpu.ops.pallas.gated_delta import gated_delta  # noqa: E402
 from mmlspark_tpu.ops.pallas.moe_combine import moe_combine  # noqa: E402
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
 from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan  # noqa: E402
@@ -173,6 +174,29 @@ KERNEL_CASES = {
                                                 held=64, impl="pallas"),
         (S((69120, 2688), BF16), S((16384, 6), jnp.int32),
          S((16384, 6), jnp.int32), S((16384, 6), F32))),
+    # the Gated DeltaNet family's cell: two rows of 8192 positions, 16 key
+    # heads of 128 on 32 value heads of 128; [q_j | k_j | v of j's value
+    # heads] read where they lie in the convolved [2, 8192, 8192], blocks of
+    # 1,024 positions in chunks of 64 carry two value heads' states in VMEM
+    # scratch; the convolution that makes that array from the key-head
+    # grouped [q | k | v] product; and its attention,
+    # 16 query heads on 2 key/value heads of 256 (the widest head the tiled
+    # kernel has met)
+    "gated_delta_t8192_hk16_hv32_d128": (
+        lambda qkv, g, b: gated_delta(qkv, g, b, key_heads=16, key_dim=128,
+                                      value_heads=32, value_dim=128,
+                                      impl="pallas"),
+        (S((2, 8192, 8192), BF16), S((2, 8192, 32), F32),
+         S((2, 8192, 32), F32))),
+    "causal_conv_qkv_t8192_c8192": (
+        lambda qkv, w: causal_conv(qkv, w, channels=8192, silu=True,
+                                   dtype=BF16, impl="pallas"),
+        (S((2, 8192, 8192), F32), S((4, 8192), BF16))),
+    "flash_attention_tiled_hd256_t8192": (
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           impl="pallas"),
+        (S((2, 16, 8192, 256), BF16), S((2, 2, 8192, 256), BF16),
+         S((2, 2, 8192, 256), BF16))),
     "group_norm_56x56x256": (
         lambda x, s, b: group_norm(x, s, b, 32, relu=True),
         (S((8, 56, 56, 256), BF16), S((256,), F32), S((256,), F32))),
@@ -231,6 +255,15 @@ def test_kernel_lowers_for_tpu(name, monkeypatch):
                 if "69120x2688" in line and line.lstrip().startswith("%")]
         assert sorted(uses) == ["call @_combine_call",
                                 "stablehlo.custom_call @tpu_custom_call"]
+    if name == "gated_delta_t8192_hk16_hv32_d128":
+        # the wide operand goes in as it is, three times, and nothing of
+        # the value heads' width is repeated to the key heads' count
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+        assert "tensor<2x8192x8192xbf16>, tensor<2x8192x8192xbf16>, " \
+            "tensor<2x8192x8192xbf16>" in text
+        assert "stablehlo.pad" not in text
+    if name == "flash_attention_tiled_hd256_t8192":
+        assert "tensor<2x2x8192x256xbf16>" in text
     if name.startswith("causal_conv"):
         # the parts are read where they lie: no slice of the wide product
         # is cut out (or padded) to feed the call
