@@ -25,8 +25,7 @@ lower ``[C, C]`` matrix ``beta_i (k_i . k_j) D_ij`` and ``T = (I + A)^-1``::
 * the grid is ``(row, key head, block of positions)``, the block axis
   sequential; the states of the key head's value heads, ``[heads a key head,
   key_dim, value_dim]`` float32 in VMEM scratch, are zeroed at a row's first
-  block and carried to the next. They never exist in HBM. Inside a block a
-  loop walks chunks of ``CHUNK`` positions;
+  block and carried to the next. They never exist in HBM;
 * **the operands are read where they lie**: the caller's causal convolution
   leaves ``[q_j | k_j | v of j's value heads]`` for every key head ``j`` side
   by side in one wide array, and the kernel is handed that array three times
@@ -45,6 +44,20 @@ lower ``[C, C]`` matrix ``beta_i (k_i . k_j) D_ij`` and ``T = (I + A)^-1``::
   (:func:`unit_lower_inverse`: ``log2 C`` steps of two products, each step
   inverting twice as wide diagonal blocks from the last), never a loop over
   rows;
+* **a block runs in two phases**. First the work that does not read the
+  state: for a group of the block's chunks at once, the decays, ``A``,
+  ``T``, ``W``, ``U``, the masked ``q k^T`` and ``k`` decayed to the chunk's
+  end, each product ONE batched product ``[group, heads a key head x C,
+  ...]`` (so each of the doubling's steps gives the MXU a group of
+  independent products, not a chain of one each), kept for the whole block
+  in VMEM scratch (``U`` float32, the rest in the operands' type, rounded
+  where the one-chunk form rounded them); a group that does not divide the
+  block's chunks leaves a shorter last group. Then the state chain walks
+  the block's chunks and does only what reads the state: ``N = U - W S``,
+  ``q S``, the output and ``S``'s update. The group is the longest of
+  ``_MAX_GROUP`` chunks and its halves whose float32 temporaries leave room
+  for a block (:func:`block_positions`); a shape with no room for a group
+  of two takes groups of one, the same two phases;
 * the l2 norms are taken inside the kernel in float32; ``g``, its
   cumulative sums, the decays, ``T`` and the states are float32; the
   products, the doubling's too, run on operands of the wide array's type
@@ -71,8 +84,9 @@ chunked function as array code (a ``lax.scan`` over chunks, float32 at
 number of lane tiles, a block that cannot fit the budget) give way to it
 under ``auto``, logged and counted (``ops.pallas.vmem_fallback{kernel=
 gated_delta}``), and raise under ``pallas``. Counted when the kernel call is
-traced: ``ops.pallas.gated_delta_grid_steps``; gauge
-``ops.pallas.gated_delta_chunk`` (positions a block).
+traced: ``ops.pallas.gated_delta_grid_steps``; gauges
+``ops.pallas.gated_delta_chunk`` (positions a block) and
+``ops.pallas.gated_delta_group`` (chunks a group).
 """
 
 from __future__ import annotations
@@ -88,6 +102,7 @@ from mmlspark_tpu.ops.pallas.budget import VMEM_BUDGET
 
 GRID_STEPS_COUNTER = "ops.pallas.gated_delta_grid_steps"
 CHUNK_GAUGE = "ops.pallas.gated_delta_chunk"
+GROUP_GAUGE = "ops.pallas.gated_delta_group"
 
 _LANES = 128
 # positions of one chunk: the public kernel's chunk, and where the
@@ -96,6 +111,11 @@ CHUNK = 64
 # positions of the longest block: past it a grid step's fixed cost is
 # already under a hundredth of its work
 _MAX_BLOCK = 1024
+# chunks of the longest group whose state-free products are issued as one
+# batched product each
+_MAX_GROUP = 8
+# float32 [per CHUNK, ...] temporaries a chunk of a group holds at once
+_TEMPORARIES = 8
 # the kernel's triangular solve: its products on bfloat16 operands, as the
 # products that use its inverse (which is rounded to the operands' type)
 _SOLVE_PRECISION = jax.lax.Precision.DEFAULT
@@ -104,30 +124,62 @@ L2_EPS = 1e-6
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+def vmem_bytes(block: int, group: int, per: int, key_dim: int,
+               value_dim: int, itemsize: int) -> int:
+    """What a grid step of ``block`` positions and groups of ``group``
+    chunks holds in VMEM (see :func:`block_positions`)."""
+    stacked = per * CHUNK
+    # the operands and outputs, double-buffered; then what the state chain
+    # reads, held for the block: q, W, k decayed, the masked q k^T, U
+    per_position = (2 * (itemsize * (2 * key_dim + 2 * per * value_dim)
+                         + 4 * 8 * per)
+                    + itemsize * (key_dim + per * (2 * key_dim + stacked))
+                    + 4 * per * value_dim)
+    return (4 * per * key_dim * value_dim
+            + group * _TEMPORARIES * stacked
+            * max(stacked, key_dim, value_dim) * 4
+            + block * per_position)
+
+
+def _plan(length: int, per: int, key_dim: int, value_dim: int,
+          itemsize: int) -> tuple[int, int]:
+    """Positions of a block and chunks of a group (both 0 where the kernel
+    takes no such shape): see :func:`block_positions`."""
+    width = 2 * key_dim + per * value_dim
+    if (key_dim % _LANES or value_dim % _LANES
+            or width % (per * value_dim) or 2 * key_dim % (per * value_dim)):
+        return 0, 0
+    whole = -(-length // CHUNK)
+    group = _MAX_GROUP
+    while group:
+        def fits(chunks):
+            return vmem_bytes(CHUNK * chunks, group, per, key_dim,
+                              value_dim, itemsize) <= VMEM_BUDGET
+        if fits(min(group, whole)):
+            chunks = max(c for c in range(1, min(whole, _MAX_BLOCK // CHUNK)
+                                          + 1) if whole % c == 0 and fits(c))
+            return CHUNK * chunks, min(group, chunks)
+        group //= 2
+    return 0, 0
+
+
 def block_positions(length: int, per: int, key_dim: int, value_dim: int,
                     itemsize: int) -> int:
     """Positions of one block, from the shapes and the VMEM budget alone: a
     position holds a key head's ``q``, ``k``, its value heads' ``v`` and
     outputs (``itemsize`` each) and its scalars (float32, padded to a
-    sublane tile), double-buffered; the states and a chunk's stacked
-    float32 temporaries are held once. Of the lengths that fit, the longest
-    that divides the sequence (in whole chunks), at most ``_MAX_BLOCK``;
-    ``0`` where a head is no whole number of lane tiles or not even one
-    chunk fits, or where the parts of a key head's channels are no whole
-    number of blocks of their own width (the kernel reads them in place)."""
-    width = 2 * key_dim + per * value_dim
-    if (key_dim % _LANES or value_dim % _LANES
-            or width % (per * value_dim) or 2 * key_dim % (per * value_dim)):
-        return 0
-    stacked = per * CHUNK
-    held = (4 * per * key_dim * value_dim
-            + 16 * stacked * max(stacked, key_dim, value_dim) * 4)
-    per_position = 2 * (itemsize * (2 * key_dim + 2 * per * value_dim)
-                        + 4 * 8 * per)
-    whole = -(-length // CHUNK)
-    most = min((VMEM_BUDGET - held) // per_position, _MAX_BLOCK) // CHUNK
-    return CHUNK * max((c for c in range(1, min(most, whole) + 1)
-                        if whole % c == 0), default=0)
+    sublane tile), double-buffered, and what the state chain reads of it
+    (``q``, ``W``, ``k`` decayed and the masked ``q k^T`` in ``itemsize``,
+    ``U`` in float32), once; the states and a group's float32 temporaries
+    are held once. The group is the longest of ``_MAX_GROUP`` chunks and
+    its halves that leaves room for a block of a group, down to one chunk
+    (the same two phases, each product a chunk's). Of the block lengths
+    that fit, the longest that divides the sequence (in whole chunks), at
+    most ``_MAX_BLOCK``; ``0`` where a head is no whole number of lane
+    tiles or not even one chunk fits, or where the parts of a key head's
+    channels are no whole number of blocks of their own width (the kernel
+    reads them in place)."""
+    return _plan(length, per, key_dim, value_dim, itemsize)[0]
 
 
 def unit_lower_inverse(a, block: int | None = None, precision=HIGHEST):
@@ -234,17 +286,22 @@ def gated_delta_reference(qkv, g, beta, *, key_heads: int, key_dim: int,
     return o[:, :length]
 
 
-def _gdn_kernel(q_ref, k_ref, v_ref, r_ref, o_ref, st_ref, *,
-                per: int, value_dim: int):
+def _gdn_kernel(q_ref, k_ref, v_ref, r_ref, o_ref, st_ref, qd_ref, w_ref,
+                u_ref, qk_ref, kt_ref, *, per: int, value_dim: int,
+                group: int):
     # grid (row, key head, block). Blocks: q / k [1, block, key_dim]; v /
     # out [1, block, per x value_dim] (the key head's value heads side by
     # side); r [1, 1, chunks, 2, per x CHUNK] float32 (a chunk's cumulative
     # sums of g, then its betas, value head by value head on lanes).
-    # Scratch [per, key_dim, value_dim] float32: the states. A chunk's
-    # value heads are STACKED: row h C + t is value head h's position t, so
-    # that what each head needs of the chunk (the triangle, its inverse, W,
-    # U, the masked q k^T) is one [per C, ...] product, block-diagonal where
-    # it is square
+    # Scratch: the states [per, key_dim, value_dim] float32, then what the
+    # state chain reads of each of the block's chunks, made before it:
+    # q [chunks, CHUNK, key_dim], W [chunks, per CHUNK, key_dim], U (float32)
+    # [chunks, per CHUNK, value_dim], the masked q k^T [chunks, per CHUNK,
+    # per CHUNK] and k decayed to the chunk's end [chunks, per CHUNK,
+    # key_dim]. A chunk's value heads are STACKED: row h C + t is value head
+    # h's position t, so that what each head needs of the chunk is one
+    # [per C, ...] product, block-diagonal where it is square; and `group`
+    # chunks' are ONE batched product, [group, per C, ...]
     import jax.experimental.pallas as pl
 
     f32, c = jnp.float32, CHUNK
@@ -264,42 +321,63 @@ def _gdn_kernel(q_ref, k_ref, v_ref, r_ref, o_ref, st_ref, *,
     # the two rows of scalars padded to a sublane tile, to be transposed
     fill = jnp.zeros((8 - 2, m), f32)
 
+    def columns(r):
+        """A chunk's scalars ``[2, m]`` down the sublanes: ``[m, 8]``."""
+        return jnp.concatenate([r, fill], axis=0).T
+
+    def ends(cols):
+        """Each value head's cumulative sum at its chunk's last position."""
+        return [cols[..., h * c + c - 1:h * c + c, 0:1] for h in range(per)]
+
     def dot(a, b):
         return jnp.dot(a, b, preferred_element_type=f32)
 
-    def dot_t(a, b):
-        """``a b^T`` without a transposed copy of ``b``."""
-        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+    def bdot(a, b, contract):
+        """``a b`` (``contract`` 1) or ``a b^T`` (2) of each chunk of a
+        group, as one batched product."""
+        return jax.lax.dot_general(a, b, (((2,), (contract,)), ((0,), (0,))),
                                    preferred_element_type=f32)
 
-    def chunk(s, carry):
-        rows = pl.ds(pl.multiple_of(s * c, c), c)
-        q = l2_normed(q_ref[0, rows, :]) * scale
-        k = l2_normed(k_ref[0, rows, :])
+    def solve(first, size: int):
+        """The state-free work of ``size`` chunks from chunk ``first``, each
+        product one for the group, into the scratch."""
+        at = pl.ds(pl.multiple_of(first * c, c), size * c)
+        q = (l2_normed(q_ref[0, at, :]) * scale).reshape(size, c, -1)
+        k = l2_normed(k_ref[0, at, :]).reshape(size, c, -1)
         qd = q.astype(dtype)
-        k2 = jnp.concatenate([k] * per, axis=0)         # [m, key_dim]
+        k2 = jnp.concatenate([k] * per, axis=1)         # [size, m, key_dim]
         k2d = k2.astype(dtype)
-        r = r_ref[0, 0, s]                              # [2, m]
-        # the same scalars down the sublanes: [m, 1] each
-        cols = jnp.concatenate([r, fill], axis=0).T
-        cum, beta = cols[:, 0:1], cols[:, 1:2]
-        decay = jnp.exp(jnp.where(same & (i >= j), cum - r[0:1, :], -jnp.inf))
+        r = r_ref[0, 0, pl.ds(first, size)]             # [size, 2, m]
+        cols = jnp.stack([columns(r[x]) for x in range(size)])
+        cum, beta = cols[..., 0:1], cols[..., 1:2]      # [size, m, 1]
+        decay = jnp.exp(jnp.where(same & (i >= j), cum - r[:, 0:1, :],
+                                  -jnp.inf))
         t = unit_lower_inverse(
-            jnp.where(same & (i > j), beta * dot_t(k2d, k2d) * decay, 0.0),
+            jnp.where(same & (i > j), beta * bdot(k2d, k2d, 2) * decay, 0.0),
             block=c, precision=_SOLVE_PRECISION).astype(dtype)
         e = jnp.exp(cum)
-        v2 = jnp.concatenate([v_ref[0, rows, h * value_dim:(h + 1) * value_dim]
-                              for h in range(per)], axis=0).astype(f32)
-        w = dot(t, (beta * e * k2).astype(dtype)).astype(dtype)
-        u = dot(t, (beta * v2).astype(dtype))
-        qk = (dot_t(jnp.concatenate([qd] * per, axis=0), k2d)
-              * decay).astype(dtype)
-        # each value head's cumulative sum at its chunk's last position
-        lasts = [cols[h * c + c - 1:h * c + c, 0:1] for h in range(per)]
+        v2 = jnp.concatenate(
+            [v_ref[0, at, h * value_dim:(h + 1) * value_dim].reshape(
+                size, c, value_dim) for h in range(per)], axis=1).astype(f32)
+        lasts = ends(cols)
         last = lasts[0]
         for h in range(1, per):
             last = jnp.where(row >= h * c, lasts[h], last)
-        kt = (k2 * jnp.exp(last - cum)).astype(dtype)  # k decayed to the end
+        held = pl.ds(first, size)
+        qd_ref[held] = qd
+        w_ref[held] = bdot(t, (beta * e * k2).astype(dtype), 1).astype(dtype)
+        u_ref[held] = bdot(t, (beta * v2).astype(dtype), 1)
+        qk_ref[held] = (bdot(jnp.concatenate([qd] * per, axis=1), k2d, 2)
+                        * decay).astype(dtype)
+        # k decayed to the chunk's end
+        kt_ref[held] = (k2 * jnp.exp(last - cum)).astype(dtype)
+
+    def chain(s, carry):
+        """What reads the state, chunk ``s`` of the block."""
+        cols = columns(r_ref[0, 0, s])
+        e = jnp.exp(cols[:, 0:1])
+        lasts = ends(cols)
+        qd, w, u, kt = qd_ref[s], w_ref[s], u_ref[s], kt_ref[s]
         news, reads = [], []
         for h in range(per):
             at = slice(h * c, (h + 1) * c)
@@ -313,19 +391,28 @@ def _gdn_kernel(q_ref, k_ref, v_ref, r_ref, o_ref, st_ref, *,
             grow = jnp.exp(jnp.broadcast_to(lasts[h], (1, value_dim)))
             st_ref[h] = grow * st + dot(kt[at].T, new)
         o = e * jnp.concatenate(reads, axis=0) \
-            + dot(qk, jnp.concatenate(news, axis=0))
+            + dot(qk_ref[s], jnp.concatenate(news, axis=0))
+        rows = pl.ds(pl.multiple_of(s * c, c), c)
         for h in range(per):
             o_ref[0, rows, h * value_dim:(h + 1) * value_dim] = \
                 o[h * c:(h + 1) * c].astype(o_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, chunks, chunk, 0)
+    def one(x, carry):
+        solve(x * group, group)
+        return carry
+
+    whole, rest = divmod(chunks, group)
+    jax.lax.fori_loop(0, whole, one, 0)
+    if rest:
+        solve(whole * group, rest)
+    jax.lax.fori_loop(0, chunks, chain, 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "key_heads", "key_dim", "value_heads", "value_dim", "block"))
+    "key_heads", "key_dim", "value_heads", "value_dim", "block", "group"))
 def _gdn_call(qkv, g, beta, *, key_heads: int, key_dim: int,
-              value_heads: int, value_dim: int, block: int):
+              value_heads: int, value_dim: int, block: int, group: int):
     """The kernel call and the array code around it. Module-level and
     jitted so that equal call sites share a trace and a lowering."""
     import jax.experimental.pallas as pl
@@ -336,8 +423,10 @@ def _gdn_call(qkv, g, beta, *, key_heads: int, key_dim: int,
     per = value_heads // key_heads
     pad_l = -length % block
     blocks = (length + pad_l) // block
+    chunks = block // CHUNK
     _obs_registry().counter(GRID_STEPS_COUNTER).add(rows * key_heads * blocks)
     _obs_registry().gauge(CHUNK_GAUGE).set(block)
+    _obs_registry().gauge(GROUP_GAUGE).set(group)
 
     def tail(a):
         """``a`` with the positions that fill the last block, as zeros."""
@@ -362,7 +451,8 @@ def _gdn_call(qkv, g, beta, *, key_heads: int, key_dim: int,
                                              + offset))
 
     out = pl.pallas_call(
-        functools.partial(_gdn_kernel, per=per, value_dim=value_dim),
+        functools.partial(_gdn_kernel, per=per, value_dim=value_dim,
+                          group=group),
         grid=(rows, key_heads, blocks),
         in_specs=[
             moved(key_dim, 0), moved(key_dim, 1),
@@ -374,7 +464,13 @@ def _gdn_call(qkv, g, beta, *, key_heads: int, key_dim: int,
                                lambda r, h, i: (r, i, h)),
         out_shape=jax.ShapeDtypeStruct(
             (rows, length + pad_l, value_heads * value_dim), qkv.dtype),
-        scratch_shapes=[pltpu.VMEM((per, key_dim, value_dim), f32)],
+        scratch_shapes=[
+            pltpu.VMEM((per, key_dim, value_dim), f32),
+            pltpu.VMEM((chunks, CHUNK, key_dim), qkv.dtype),
+            pltpu.VMEM((chunks, per * CHUNK, key_dim), qkv.dtype),
+            pltpu.VMEM((chunks, per * CHUNK, value_dim), f32),
+            pltpu.VMEM((chunks, per * CHUNK, per * CHUNK), qkv.dtype),
+            pltpu.VMEM((chunks, per * CHUNK, key_dim), qkv.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="gated_delta",
@@ -399,13 +495,13 @@ def gated_delta(qkv, g, beta, *, key_heads: int, key_dim: int,
         raise ValueError(f"{value_heads} value heads on {key_heads} key "
                          "heads: a key head serves a whole number")
     per = value_heads // key_heads
-    block = block_positions(qkv.shape[1], per, key_dim, value_dim,
-                            qkv.dtype.itemsize)
+    block, group = _plan(qkv.shape[1], per, key_dim, value_dim,
+                         qkv.dtype.itemsize)
     if _takes_kernel(impl, "gated_delta", block > 0,
                      (CHUNK, key_dim, per * value_dim)):
         return _gdn_call(qkv, g, beta, key_heads=key_heads, key_dim=key_dim,
                          value_heads=value_heads, value_dim=value_dim,
-                         block=block)
+                         block=block, group=group)
     return gated_delta_reference(
         qkv, g, beta, key_heads=key_heads, key_dim=key_dim,
         value_heads=value_heads, value_dim=value_dim).astype(qkv.dtype)

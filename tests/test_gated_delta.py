@@ -100,11 +100,25 @@ def test_a_state_lost_at_chunk_boundaries_or_a_wrong_key_head_fails():
     np.testing.assert_array_equal(o[..., :DV], o[..., DV:2 * DV])
 
 
-def test_the_kernel_equals_the_array_form_in_float32():
-    qkv, g, beta = case(256, rows=1, seed=3)
-    kernel = np.asarray(gd.gated_delta(qkv, g, beta, impl="pallas", **SIZES))
+@pytest.mark.parametrize("length,group", [
+    (8, None),          # the init trace: one chunk, a group of one
+    (256, None),        # one block of 4 chunks, one group of 4
+    (200, None),        # a tail: the last chunk padded in the block
+    (768, None),        # 12 chunks in groups of 8: the last group of 4
+    (1024, None),       # two blocks of 8 chunks: the states cross them
+    (512, 2),           # 8 chunks, four groups of 2 in a loop
+    (448, 3),           # 7 chunks, two groups of 3 in a loop and one of 1
+])
+def test_the_kernel_equals_the_array_form_in_float32(length, group):
+    qkv, g, beta = case(length, rows=1, seed=3)
+    if group is None:
+        kernel = gd.gated_delta(qkv, g, beta, impl="pallas", **SIZES)
+    else:
+        kernel = gd._gdn_call(qkv, g, beta, block=gd.block_positions(
+            length, HV // HK, DK, DV, 4), group=group, **SIZES)
     array = np.asarray(gd.gated_delta(qkv, g, beta, impl="xla", **SIZES))
-    np.testing.assert_allclose(kernel, array, atol=1e-6, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(kernel), array, atol=1e-6,
+                               rtol=1e-5)
 
 
 @pytest.mark.parametrize("block", [None, 64])
@@ -125,10 +139,21 @@ def test_the_doubling_solve_inverts_the_unit_lower_triangle(block):
 
 
 def test_blocks_follow_the_shapes_and_odd_heads_give_way():
-    # the cell's rows: 8192 positions in blocks of 1024 (16 chunks)
-    assert gd.block_positions(8192, 2, 128, 128, 2) == 1024
+    # the cell's rows: 8192 positions in blocks of 1024 (16 chunks), the
+    # state-free work in groups of the longest length the rule allows
+    cell = (8192, 2, 128, 128, 2)
+    assert gd._plan(*cell) == (1024, gd._MAX_GROUP)
+    assert gd.vmem_bytes(1024, gd._MAX_GROUP, *cell[1:]) <= gd.VMEM_BUDGET
+    # a group is at most the block's chunks
+    assert gd._plan(8, 2, 128, 128, 4) == (64, 1)
+    assert gd._plan(192, 2, 128, 128, 4) == (192, 3)
     # a tail: blocks that divide the whole chunks the row needs
     assert gd.block_positions(200, 2, 128, 128, 4) == 256
+    # heads too wide for a group of two: groups of one chunk (the same two
+    # phases, each product a chunk's); wider still: no block at all
+    assert gd.vmem_bytes(128, 2, 2, 768, 768, 2) > gd.VMEM_BUDGET
+    assert gd._plan(8192, 2, 768, 768, 2) == (128, 1)
+    assert gd.block_positions(8192, 2, 1024, 1024, 2) == 0
     # heads that are no whole lane tile take the array form
     assert gd.block_positions(8192, 2, 16, 16, 2) == 0
     rng = np.random.default_rng(5)
@@ -151,3 +176,5 @@ def test_the_kernel_call_is_counted_when_traced():
     # one row x two key heads x one block
     assert registry().value(gd.GRID_STEPS_COUNTER) - before == 2
     assert registry().value(gd.CHUNK_GAUGE) == 128
+    # the block's two chunks, solved together
+    assert registry().value(gd.GROUP_GAUGE) == 2
