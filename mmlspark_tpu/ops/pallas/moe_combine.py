@@ -27,8 +27,7 @@ present picks lie in, by DMA from ``ys`` where it lies in HBM:
   block's picks of one expert are consecutive rows, so its tiles are a
   range an expert (from its least row's tile to its greatest's), at most
   ``k B / 8 + 2 held`` tiles for ``B`` tokens: a block reads 1.1-2.7x the
-  rows its present picks need at the benchmark's three expert shapes, not
-  8x;
+  rows its present picks need at the benchmark's expert shapes, not 8x;
 * the grid walks blocks of tokens in order. Each block's tiles, and each
   pick's row in the block's staging (``-1`` where absent), come from
   array code around the call that only reduces over the held experts
@@ -37,16 +36,32 @@ present picks lie in, by DMA from ``ys`` where it lies in HBM:
   SMEM does not bound the tokens. A block's tile copies go into one of
   two VMEM slots, so the copies of block ``i + 1`` are in flight while
   block ``i`` is worked; the staged rows are widened to float32 once
-  (a float32 row can be loaded from any sublane), and for every 8 tokens
-  each present pick's row is copied to its token's row of a ``[k, 8, d]``
-  tile. An absent pick copies nothing: its tile row holds whatever was
-  there before and is *selected* away, not multiplied (0 x NaN is NaN);
+  (a float32 row can be loaded from any sublane);
+* **the loops run over the picks a token holds, not over ``k``.** The
+  array code moves each token's held picks to the front of its ``k``, in
+  pick order (their staged rows, weights and held mask: a running count
+  over the picks and masked sums, :func:`_held_first`), and gives every
+  group of 8 tokens its trips: the most picks one of them holds. A group
+  visits only its first ``trips`` pick slots; each slot copies each
+  token's row to that token's row of a ``[k, 8, d]`` tile, untested (a
+  token past its own picks copies the staging's first row: a scalar test
+  costs more than a row copy), then weights, selects and adds the tile.
+  So an absent pick is neither tested, copied nor added where its group
+  holds fewer picks than ``k``, and the kernel's work is about
+  ``moe_held_pairs_per_token.score`` over ``k`` of a kernel that visits
+  every pick (1.25 of 10 at the Qwen3-Next cell, 1.0 of 4 at Mistral's,
+  3.0 of 6 at Nemotron's, 4 of 4 at LFM2's): that benchmark metric reads
+  the mechanism. A row past a token's picks is *selected* away, never
+  multiplied (0 x NaN is NaN);
 * ``ys`` is never copied, sliced or padded to feed the call, so the rung's
   row count does not matter to the kernel, and rows past the buffer's
   last group reach no token's sum;
 * the sum is the reference's, term for term: each term ``row.astype(f32)
   * w`` selected by ``present``, added in pick order to a float32
-  accumulator that starts at 0, so the answers are equal to the bit;
+  accumulator that starts at +0.0, so the answers are equal to the bit.
+  Skipping an absent pick's term is exact too: the reference adds +0.0
+  there, and a sum that starts at +0.0 never becomes -0.0, so adding +0.0
+  leaves it as it was, NaN and infinities included;
 * the block of tokens comes from the shapes and the staging budgets of
   :mod:`~mmlspark_tpu.ops.pallas.budget` alone (:func:`block_tokens`); a
   token count that is no multiple of it ends in a partial block whose
@@ -91,13 +106,20 @@ _BLOCKS = (512, 256, 128, 64, 32, 16)
 
 def moe_combine_reference(ys, back, present, weights):
     """The combine as array code: pick by pick, a row gather of ``ys`` at
-    ``back[:, j]``, weighted in float32 and selected by ``present``."""
-    y = jnp.zeros((back.shape[0], ys.shape[1]), jnp.float32)
-    for j in range(back.shape[1]):
+    ``back[:, j]``, weighted in float32 and selected by ``present``. The
+    picks are a loop, as the kernel's are: XLA's CPU backend contracts
+    ``y + row * w`` into one rounding inside a loop and only sometimes in
+    straight-line code, so on the CPU the two forms round alike only so
+    (the chip rounds the product and the sum apart in both)."""
+    def add(j, y):
         row = jnp.take(ys, back[:, j], axis=0, mode="clip")
-        y = y + jnp.where(present[:, j, None],
-                          row.astype(jnp.float32) * weights[:, j, None], 0.0)
-    return y
+        return y + jnp.where(present[:, j, None],
+                             row.astype(jnp.float32) * weights[:, j, None],
+                             0.0)
+
+    return jax.lax.fori_loop(
+        0, back.shape[1], add,
+        jnp.zeros((back.shape[0], ys.shape[1]), jnp.float32))
 
 
 def staged_tiles(block: int, top_k: int, held: int) -> int:
@@ -122,10 +144,10 @@ def _vmem_bytes(block: int, top_k: int, held: int, width: int,
 
 def _smem_bytes(tokens: int, block: int, top_k: int, held: int) -> int:
     """What the kernel's scalar operands take in SMEM: the tiles of two
-    blocks and the picks' staged rows of one, each double-buffered, and
-    every block's tile count."""
-    return 4 * (2 * (2 * staged_tiles(block, top_k, held) + block * top_k)
-                + -(-tokens // block))
+    blocks, the picks' staged rows of one and its groups' trips, each
+    double-buffered, and every block's tile count."""
+    return 4 * (2 * (2 * staged_tiles(block, top_k, held) + block * top_k
+                     + block // _ROWS) + -(-tokens // block))
 
 
 def block_tokens(tokens: int, top_k: int, held: int, width: int,
@@ -145,18 +167,20 @@ def block_tokens(tokens: int, top_k: int, held: int, width: int,
     return min(fits[0], need)
 
 
-def _combine_kernel(counts_ref, tiles_ref, next_ref, staged_ref, picks_ref,
-                    w_ref, ys_hbm, y_ref, stage, wide, rows, sems, *,
-                    top_k: int, block: int):
+def _combine_kernel(counts_ref, tiles_ref, next_ref, staged_ref, trips_ref,
+                    picks_ref, w_ref, ys_hbm, y_ref, stage, wide, rows, sems,
+                    *, top_k: int, block: int):
     # grid (block of tokens,), sequential. SMEM: counts_ref [blocks] (scalar
     # prefetch) how many tiles each block stages; blocks of one grid step:
     # tiles_ref / next_ref [1, most] the tiles this block and the next stage,
-    # staged_ref [1, block * top_k] each pick's row in this block's staging or
-    # -1. VMEM blocks: picks_ref (the picks' buffer rows, -1 where absent)
-    # and w_ref [block, top_k]; y_ref [block, d] float32; ys_hbm the whole
-    # buffer. Scratch: stage [2, most * 8, d] in ys's type (a slot a block),
-    # wide [most * 8, d] float32, rows [top_k, 8, d] float32, a DMA
-    # semaphore a slot
+    # staged_ref [1, block * top_k] each token's held picks' rows in this
+    # block's staging, held ones first in pick order (-1 past them),
+    # trips_ref [1, block / 8] the most picks a token of each group of 8
+    # holds. VMEM blocks: picks_ref (the held picks' buffer rows first, -1
+    # past them) and w_ref (their weights) [block, top_k]; y_ref [block, d]
+    # float32; ys_hbm the whole buffer. Scratch: stage [2, most * 8, d] in
+    # ys's type (a slot a block), wide [most * 8, d] float32, rows [top_k, 8,
+    # d] float32, a DMA semaphore a slot
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -204,20 +228,23 @@ def _combine_kernel(counts_ref, tiles_ref, next_ref, staged_ref, picks_ref,
 
     def tokens(i, carry):
         base = i * _ROWS * top_k
-        for p in range(_ROWS):
-            for j in range(top_k):
-                row = staged_ref[0, base + p * top_k + j]
-
-                @pl.when(row >= 0)
-                def _copy():
-                    rows[j, p:p + 1, :] = wide[pl.ds(row, 1), :]
-
         at = pl.ds(pl.multiple_of(i * _ROWS, _ROWS), _ROWS)
-        acc = jnp.zeros((_ROWS, y_ref.shape[1]), jnp.float32)
+        # the group's trips: the most picks one of its 8 tokens holds
+        trips = trips_ref[0, i]
+        y_ref[at, :] = jnp.zeros((_ROWS, y_ref.shape[1]), jnp.float32)
         for j in range(top_k):
-            held = picks_ref[at, j:j + 1] >= 0
-            acc = acc + jnp.where(held, rows[j] * w_ref[at, j:j + 1], 0.0)
-        y_ref[at, :] = acc
+            @pl.when(j < trips)
+            def _pick(j=j):
+                # the j-th held pick of each of the group's tokens: its row
+                # copied to its token's row of the j-th tile, untested (a
+                # token that holds fewer copies the staging's first row,
+                # which its select drops), weighted, selected and added
+                for p in range(_ROWS):
+                    row = jnp.maximum(staged_ref[0, base + p * top_k + j], 0)
+                    rows[j, p:p + 1, :] = wide[pl.ds(row, 1), :]
+                held = picks_ref[at, j:j + 1] >= 0
+                y_ref[at, :] = y_ref[at, :] + jnp.where(
+                    held, rows[j] * w_ref[at, j:j + 1], 0.0)
         return carry
 
     jax.lax.fori_loop(0, block // _ROWS, tokens, 0)
@@ -253,6 +280,30 @@ def _staging(rows, expert, block: int, most: int, held: int):
     return tiles.astype(jnp.int32), end[:, -1], staged.astype(jnp.int32)
 
 
+def _held_first(rows, staged, weights):
+    """``(trips [P / 8], rows, staged, weights)`` of the picks' buffer rows
+    ``rows [P, k]`` (``-1`` where absent), their staged rows and weights:
+    each token's held picks moved to the front of its ``k``, in pick order,
+    the slots past them ``-1`` (rows, staged) and 0 (weights); and for
+    every group of 8 tokens the most picks one of them holds. A held pick's
+    slot is the count of held picks before it, and each slot is a masked
+    sum over the picks, so nothing is sorted or scattered; the weights move
+    as their bits."""
+    k = rows.shape[1]
+    mine = rows >= 0
+    slot = jnp.cumsum(mine, axis=1, dtype=jnp.int32) - 1
+    into = mine[..., None] & (slot[..., None] == jnp.arange(k))
+
+    def moved(table, empty):
+        return jnp.sum(jnp.where(into, (table - empty)[..., None], 0),
+                       axis=1, dtype=jnp.int32) + empty
+
+    bits = jax.lax.bitcast_convert_type(weights, jnp.int32)
+    return (jnp.max(slot[:, -1].reshape(-1, _ROWS), axis=1) + 1,
+            moved(rows, -1), moved(staged, -1),
+            jax.lax.bitcast_convert_type(moved(bits, 0), jnp.float32))
+
+
 @functools.partial(jax.jit, static_argnames=("block", "held"))
 def _combine_call(ys, back, expert, weights, *, block: int, held: int):
     """The kernel call and the index arithmetic around it (the picks'
@@ -273,6 +324,8 @@ def _combine_call(ys, back, expert, weights, *, block: int, held: int):
                      -1)
     weights = jnp.pad(weights.astype(jnp.float32), pad)
     tiles, counts, staged = _staging(rows, expert, block, most, held)
+    trips, rows, staged, weights = _held_first(
+        rows, staged.reshape(-1, top_k), weights)
     picks = pl.BlockSpec((block, top_k), lambda i, *_: (i, 0))
 
     def smem(width, ahead=0):
@@ -290,7 +343,8 @@ def _combine_call(ys, back, expert, weights, *, block: int, held: int):
             num_scalar_prefetch=1,
             grid=(blocks,),
             in_specs=[smem(most), smem(most, ahead=1), smem(block * top_k),
-                      picks, picks, pl.BlockSpec(memory_space=pl.ANY)],
+                      smem(block // _ROWS), picks, picks,
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block, d), lambda i, *_: (i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, most * _ROWS, d), ys.dtype),
@@ -301,7 +355,8 @@ def _combine_call(ys, back, expert, weights, *, block: int, held: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         name="moe_combine",
-    )(counts, tiles[:, None], tiles[:, None], staged[:, None], rows,
+    )(counts, tiles[:, None], tiles[:, None],
+      staged.reshape(blocks, 1, -1), trips.reshape(blocks, 1, -1), rows,
       weights, ys)
 
 

@@ -182,9 +182,11 @@ def test_the_stack_is_one_scan_with_one_switch(seeded):
     module = lm.from_config(cfg)
     text = jax.jit(lambda p, t: module.apply({"params": p}, t, "logits")
                    ).lower(tree, jnp.zeros((1, 64))).as_text()
-    # the scan over layers, and in its Gated DeltaNet branch the array
-    # form's scan over chunks (on the chip that branch holds the kernel)
-    assert text.count("stablehlo.while") == 2
+    # the scan over layers, in its Gated DeltaNet branch the array form's
+    # scan over chunks (on the chip that branch holds the kernel), and in
+    # its expert layer the array combine's loop over the picks (on the chip
+    # the combine kernel)
+    assert text.count("stablehlo.while") == 3
     # the two mixers are the branches of ONE conditional in its body (16
     # experts held of 16, 64 tokens: the expert layer has one rung)
     assert text.count("stablehlo.case") == 1

@@ -175,9 +175,11 @@ def test_the_stack_is_one_scan_with_one_switch(seeded):
     module = lm.from_config(cfg)
     text = jax.jit(lambda p, t: module.apply({"params": p}, t, "logits")
                    ).lower(tree, jnp.zeros((1, 32))).as_text()
-    # the scan over layers, and in its Mamba-2 branch the array form's scan
-    # over chunks (on the chip that branch holds the kernel instead)
-    assert text.count("stablehlo.while") == 2
+    # the scan over layers, in its Mamba-2 branch the array form's scan
+    # over chunks (on the chip that branch holds the kernel instead), and
+    # in its expert layer the array combine's loop over the picks (on the
+    # chip the combine kernel)
+    assert text.count("stablehlo.while") == 3
     # the three mixers are the branches of ONE conditional in its body
     assert text.count("stablehlo.case") == 1
 

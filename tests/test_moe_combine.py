@@ -59,13 +59,40 @@ CASES = {
     # the buffer of a second rung: two first rungs worked one at a time
     # through lax.map and laid end to end
     "second_rung": (64, 6, 16, 8, 256, 1, 2),
+    # top-10 with one pick in eight held (the Qwen3-Next share's form):
+    # most groups of 8 tokens run two or three trips of ten
+    "top10_one_in_eight_held": (96, 10, 64, 8, 256, 8, 1),
+    # a block in which one group of 8 tokens holds no pick at all: it runs
+    # no trip, and its sums are the reference's +0.0
+    "group_with_no_held_pick": (64, 4, 16, 8, 128, 1, 1),
+    # every token's held picks lie apart, an absent pick between each two,
+    # so moving them to the front must keep their order
+    "held_picks_spread_out": (64, 6, 16, 8, 128, 8, 1),
 }
+
+
+def edited_picks(name, picks, held):
+    """``picks`` as the case asks for them."""
+    n, k = picks.shape
+    if name == "group_with_no_held_pick":
+        # the second group of the first block picks only absent experts
+        picks[8:16] = held + np.arange(k)
+    if name == "held_picks_spread_out":
+        # three held and three absent picks, alternating; every other
+        # token starts with an absent one
+        rng = np.random.default_rng(11)
+        mine = np.argsort(rng.random((n, held)), axis=1)[:, :k // 2]
+        away = held + np.argsort(rng.random((n, held)), axis=1)[:, :k // 2]
+        first = np.where(np.arange(n)[:, None] % 2 == 0, mine, away)
+        then = np.where(np.arange(n)[:, None] % 2 == 0, away, mine)
+        picks = np.stack([first, then], axis=2).reshape(n, k)
+    return picks
 
 
 def case_arrays(name, seed=3):
     n, k, width, held, d, tile, rungs = CASES[name]
-    back, expert, used = sorted_layout(picks_of(n, k, width, seed), held,
-                                        tile)
+    picks = edited_picks(name, picks_of(n, k, width, seed), held)
+    back, expert, used = sorted_layout(picks, held, tile)
     rng = np.random.default_rng(seed + 1)
     rows = -(-(used + 24) // (8 * rungs)) * 8 * rungs
     if rungs == 1:
@@ -101,9 +128,17 @@ def test_the_kernel_is_the_array_combine_to_the_bit(name, monkeypatch):
     assert not np.isnan(np.asarray(got)).any()     # no poisoned row read
     assert registry().counter(mc.GRID_STEPS_COUNTER).value - before \
         == -(-n // 32)
-    if name == "tokens_with_no_held_pick":
-        none = ~(np.asarray(args[2]) < held).any(axis=1)
+    mine = np.asarray(args[2]) < held
+    if name in ("tokens_with_no_held_pick", "group_with_no_held_pick"):
+        none = ~mine.any(axis=1)
         assert none.any() and not np.asarray(got)[none].any()
+    if name == "group_with_no_held_pick":
+        assert not mine[8:16].any() and mine[:8].any() and mine[16:].any()
+    if name == "top10_one_in_eight_held":
+        assert 0.08 < mine.mean() < 0.18
+    if name == "held_picks_spread_out":
+        # held and absent alternate along every token's picks
+        assert (mine[:, 1:] != mine[:, :-1]).all()
 
 
 @pytest.mark.parametrize("name", ["half_held", "every_pick_held"])
@@ -133,8 +168,73 @@ def test_each_block_stages_its_distinct_tiles_within_its_room(name):
         assert (staged[b][mine < 0] == -1).all()
 
 
+def test_a_nan_reaches_only_the_token_whose_held_row_it_is(monkeypatch):
+    """A NaN in a staged tile's row that no held pick reads (the row every
+    absent pick points at) reaches no token; a NaN in one held pick's row,
+    the first row of the second block's staging (the row a token past its
+    own picks copies there), reaches that pick's token alone. Absent picks
+    and the slots past a token's held picks are selected away, never
+    multiplied."""
+    monkeypatch.setattr(mc, "_BLOCKS", (32,))
+    (ys, back, expert, weights), held = case_arrays("half_held")
+    back, mine = np.asarray(back), np.asarray(expert) < held
+    taken = set(back[mine].tolist())
+    staged = {r // 8 for r in taken}
+    # a padding row of an expert's last tile: staged, and no pair's
+    spare = min(r for r in range(max(taken)) if r not in taken
+                and r // 8 in staged)
+    first = back[32:64][mine[32:64]].min() // 8 * 8
+    token = int(np.argwhere((back == first) & mine)[0, 0])
+    back = np.where(mine, back, spare)
+    ys = ys.at[spare].set(jnp.nan).at[first].set(jnp.nan)
+    args = (ys, jnp.asarray(back, jnp.int32), expert, weights)
+    got = np.asarray(mc.moe_combine(*args, held=held, impl="pallas"))
+    want = np.asarray(jax.jit(mc.moe_combine_reference)(
+        ys, args[1], expert < held, weights))
+    nan = np.isnan(got)
+    assert nan[token].all() and not np.delete(nan, token, axis=0).any()
+    np.testing.assert_array_equal(nan, np.isnan(want))
+    np.testing.assert_array_equal(bits(got)[~nan], bits(want)[~nan])
+
+
+def test_held_picks_move_to_the_front_in_pick_order():
+    """The compacted tables against a loop over tokens in numpy: each
+    token's held picks first, in pick order, the weights as their bits
+    (a -0.0 and a NaN among them), ``-1`` / 0 past them; and each group of
+    8 tokens' trips, the most picks one of them holds."""
+    rng = np.random.default_rng(7)
+    n, k = 64, 10
+    rows = np.where(rng.random((n, k)) < 0.3,
+                    rng.integers(0, 4000, (n, k)), -1)
+    rows[8:16] = -1                     # a group that holds nothing
+    rows[16] = np.arange(k)             # a token that holds every pick
+    staged = np.where(rows >= 0, rng.integers(0, 500, (n, k)), -1)
+    weights = rng.random((n, k)).astype(np.float32)
+    first = np.flatnonzero(rows[0] >= 0)
+    weights[0, first[:2]] = [-0.0, np.nan]
+    trips, got_rows, got_staged, got_weights = map(np.asarray, mc._held_first(
+        jnp.asarray(rows, jnp.int32), jnp.asarray(staged, jnp.int32),
+        jnp.asarray(weights)))
+    counts = []
+    for t in range(n):
+        at = np.flatnonzero(rows[t] >= 0)
+        counts.append(len(at))
+        pad = np.full(k - len(at), -1)
+        np.testing.assert_array_equal(got_rows[t],
+                                      np.concatenate([rows[t, at], pad]))
+        np.testing.assert_array_equal(got_staged[t],
+                                      np.concatenate([staged[t, at], pad]))
+        np.testing.assert_array_equal(
+            got_weights[t].view(np.int32),
+            np.concatenate([weights[t, at].view(np.int32), 0 * pad]))
+    np.testing.assert_array_equal(trips,
+                                  np.asarray(counts).reshape(-1, 8).max(1))
+    assert trips[1] == 0 and trips[2] == k
+
+
 def test_the_block_comes_from_the_shapes(monkeypatch):
-    # the three cells' shapes: the largest block whose staging fits
+    # the four cells' shapes: the largest block whose staging fits
+    assert mc.block_tokens(16384, 10, 64, 2048, 2) == 128
     assert mc.block_tokens(16384, 6, 64, 2688, 2) == 128
     assert mc.block_tokens(8192, 4, 32, 4096, 2) == 128
     assert mc.block_tokens(16384, 4, 32, 2048, 2) == 512

@@ -38,7 +38,10 @@ from mmlspark_tpu.ops.group_norm import group_norm  # noqa: E402
 from mmlspark_tpu.ops.pallas import attention as fa  # noqa: E402
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402
 from mmlspark_tpu.ops.pallas.gated_delta import gated_delta  # noqa: E402
-from mmlspark_tpu.ops.pallas.moe_combine import moe_combine  # noqa: E402
+from mmlspark_tpu.obs.metrics import registry  # noqa: E402
+from mmlspark_tpu.ops.pallas.budget import FALLBACK_COUNTER  # noqa: E402
+from mmlspark_tpu.ops.pallas.moe_combine import (  # noqa: E402
+    COMBINE_GAUGE, moe_combine)
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan  # noqa: E402
 from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan  # noqa: E402
 from mmlspark_tpu.parallel.moe import moe_dropless  # noqa: E402
@@ -174,6 +177,14 @@ KERNEL_CASES = {
                                                 held=64, impl="pallas"),
         (S((69120, 2688), BF16), S((16384, 6), jnp.int32),
          S((16384, 6), jnp.int32), S((16384, 6), F32))),
+    # and at the Qwen3-Next cell's first rung (bucket_ladder(163840, 64,
+    # 512)): picks of ten, about one in eight held, so a group of tokens
+    # runs to the most picks one of its tokens holds, not to ten
+    "moe_combine_ys31744_d2048_k10": (
+        lambda ys, back, expert, w: moe_combine(ys, back, expert, w,
+                                                held=64, impl="pallas"),
+        (S((31744, 2048), BF16), S((16384, 10), jnp.int32),
+         S((16384, 10), jnp.int32), S((16384, 10), F32))),
     # the Gated DeltaNet family's cell: two rows of 8192 positions, 16 key
     # heads of 128 on 32 value heads of 128; [q_j | k_j | v of j's value
     # heads] read where they lie in the convolved [2, 8192, 8192], blocks of
@@ -214,6 +225,8 @@ def test_kernel_lowers_for_tpu(name, monkeypatch):
     if name.startswith("moe_dropless"):
         # beside gmm the combine resolves by the backend: the chip's here
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fallbacks = registry().counter(FALLBACK_COUNTER, kernel="moe_combine")
+    fallen = fallbacks.value
     text = lower_for_tpu(fn, *specs)
     # the kernel itself is in the program — not its XLA reference
     assert "tpu_custom_call" in text
@@ -245,16 +258,20 @@ def test_kernel_lowers_for_tpu(name, monkeypatch):
         # the higher rung sharing the first's kernels; and a combine a rung
         assert "stablehlo.case" in text
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
-    if name == "moe_combine_ys69120_d2688_k6":
-        # one kernel, handed the buffer as it is: what touches the
-        # [69120, 2688] buffer is the call into the jitted entry and the
-        # kernel itself (no slice, pad, copy or gather of it)
+    if name.startswith("moe_combine_ys"):
+        # one kernel, handed the buffer as it is: what touches the buffer
+        # is ONE call into the jitted entry and the kernel itself (no
+        # slice, pad, copy or gather of it), and no fallback was taken
+        buffer = {"moe_combine_ys69120_d2688_k6": "69120x2688",
+                  "moe_combine_ys31744_d2048_k10": "31744x2048"}[name]
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
         uses = [line.split("=", 1)[1].split("(")[0].strip()
                 for line in text.splitlines()
-                if "69120x2688" in line and line.lstrip().startswith("%")]
+                if buffer in line and line.lstrip().startswith("%")]
         assert sorted(uses) == ["call @_combine_call",
                                 "stablehlo.custom_call @tpu_custom_call"]
+        assert registry().gauge(COMBINE_GAUGE).value == 1
+        assert fallbacks.value == fallen
     if name == "gated_delta_t8192_hk16_hv32_d128":
         # the wide operand goes in as it is, three times, and nothing of
         # the value heads' width is repeated to the key heads' count

@@ -2533,6 +2533,15 @@ def check_serve_fleet() -> dict:
         for t in threads[:n_burst // 2]:
             t.start()
         victim_bid, victim = next(iter(sup._backends.items()))
+        # kill while the router holds a request on the victim, so the
+        # kill tears a request in flight (a kill before the first lease
+        # lets the supervisor mark the victim down before any request
+        # reaches it, and nothing is re-routed)
+        deadline = time.monotonic() + 60.0
+        while pool.get(victim_bid).inflight < 1:
+            assert time.monotonic() < deadline, (
+                "timed out: a burst request in flight on the victim")
+            time.sleep(0.001)
         os.kill(victim.proc.pid, _signal.SIGKILL)
         for t in threads[n_burst // 2:]:
             t.start()
