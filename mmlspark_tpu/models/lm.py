@@ -46,6 +46,7 @@ the log-likelihood are float32.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
 from typing import Any
 
@@ -355,17 +356,9 @@ class LatentMoELM(nn.Module):
 
     @nn.compact
     def __call__(self, x, output: str = "logits"):
-        if output not in self.OUTPUT_NAMES:
-            raise ValueError(f"unknown output node {output!r}; available: "
-                             f"{self.OUTPUT_NAMES}")
         c = self.cfg
-        tokens = x.astype(jnp.int32)
-        b, n = tokens.shape
-        table = nn.Embed(c.vocab_size, c.hidden_size,
-                         param_dtype=c.param_dtype,
-                         embedding_init=jax.nn.initializers.normal(1.0),
-                         name="embed").embedding
-        h = jnp.take(table, tokens, axis=0).astype(c.dtype)
+        tokens, _, h = lm_input(self, x, output, 1.0)
+        n = tokens.shape[1]
         experts = ExpertStacks(
             c.num_hidden_layers, c.n_routed_experts, c.hidden_size,
             c.moe_intermediate_size, c.param_dtype, name="experts")()
@@ -374,24 +367,54 @@ class LatentMoELM(nn.Module):
             split_rngs={"params": True},
             in_axes=(nn.broadcast, 0, nn.broadcast),
             length=c.num_hidden_layers)
-        h, (load, bucket) = layers(c, name="layers")(
+        h, stats = layers(c, name="layers")(
             h, jnp.arange(n), jnp.arange(c.num_hidden_layers), experts)
-        if output == "expert_load":
-            # [layers, B, held] -> a row's picks on each held expert
-            return load.transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
-        if output == "moe_bucket":
-            # [layers] -> the step's rung on each of its rows
-            return jnp.broadcast_to(bucket[None, :], (b, bucket.shape[0]))
-        h = RMSNorm(c.rms_norm_eps, name="final_norm")(h)
-        if output == "features":
-            return jnp.mean(h, axis=1)
-        head = Kernel((c.hidden_size, c.vocab_size), c.param_dtype,
-                      name="head")().astype(c.dtype)
-        h = h.astype(c.dtype)
-        with jax.named_scope("lm/head"):
-            if output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-            return token_logprob(h, head, tokens, c.logprob_chunk)
+        return readout(c, output, tokens, h, stats,
+                       RMSNorm(c.rms_norm_eps, name="final_norm"))
+
+
+def lm_input(module, x, output: str, std: float) -> tuple:
+    """The head every family's ``__call__`` starts with: refuse an
+    ``output`` node ``module`` does not have, before anything runs; then the
+    ids ``x`` as int32 ``tokens``, the table ``embed/embedding`` ``[V, d]``
+    (drawn normal with ``std``) and the tokens' rows in it on ``dtype``."""
+    if output not in module.OUTPUT_NAMES:
+        raise ValueError(f"unknown output node {output!r}; available: "
+                         f"{module.OUTPUT_NAMES}")
+    c = module.cfg
+    tokens = x.astype(jnp.int32)
+    table = nn.Embed(c.vocab_size, c.hidden_size, param_dtype=c.param_dtype,
+                     embedding_init=jax.nn.initializers.normal(std),
+                     name="embed").embedding
+    return tokens, table, jnp.take(table, tokens, axis=0).astype(c.dtype)
+
+
+def readout(c, output: str, tokens, h, stats, norm, table=None):
+    """The tail every family's ``__call__`` ends in, from the stack's hidden
+    state ``h`` ``[B, L, d]``: ``expert_load`` / ``moe_bucket`` from the
+    expert layers' ``stats = (load [layers, B, held], bucket [layers])``;
+    else ``norm(h)`` (the family's final norm) and ``features``; else the
+    head ``[d, V]`` (the embedding ``table`` where it is tied, a
+    ``head/kernel`` of its own where ``table`` is ``None``) and ``logits`` /
+    ``token_logprob`` under ``lm/head``."""
+    b = tokens.shape[0]
+    if output == "expert_load":
+        # [layers, B, held] -> a row's picks on each held expert
+        return stats[0].transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
+    if output == "moe_bucket":
+        # [layers] -> the step's rung on each of its rows
+        return jnp.broadcast_to(stats[1][None, :], (b, stats[1].shape[0]))
+    h = norm(h)
+    if output == "features":
+        return jnp.mean(h, axis=1)
+    head = (table.astype(c.dtype).T if table is not None
+            else Kernel((c.hidden_size, c.vocab_size), c.param_dtype,
+                        name="head")().astype(c.dtype))
+    h = h.astype(c.dtype)
+    with jax.named_scope("lm/head"):
+        if output == "logits":
+            return jnp.dot(h, head, preferred_element_type=jnp.float32)
+        return token_logprob(h, head, tokens, c.logprob_chunk)
 
 
 def token_logprob(h, head, tokens, chunk: int):
@@ -433,40 +456,13 @@ def config_fields(cls, cfg: dict, **overrides) -> dict:
     return kw
 
 
-def _latent_lm(cfg: dict, overrides: dict) -> nn.Module:
-    rope = tuple(sorted(cfg["rope_parameters"].items()))
-    return LatentMoELM(LMConfig(**config_fields(
-        LMConfig, cfg, **{"rope_parameters": rope, **overrides})))
-
-
-def _conv_lm(cfg: dict, overrides: dict) -> nn.Module:
-    from mmlspark_tpu.models import lm_conv
-    return lm_conv.ConvMoELM(lm_conv.ConvLMConfig(
-        **config_fields(lm_conv.ConvLMConfig, cfg, **overrides)))
-
-
-def _jamba_lm(cfg: dict, overrides: dict) -> nn.Module:
-    from mmlspark_tpu.models import lm_ssm
-    return lm_ssm.JambaLM(lm_ssm.JambaConfig(
-        **config_fields(lm_ssm.JambaConfig, cfg, **overrides)))
-
-
-def _nemotron_h_lm(cfg: dict, overrides: dict) -> nn.Module:
-    from mmlspark_tpu.models import lm_mamba2
-    return lm_mamba2.NemotronHLM(lm_mamba2.NemotronHConfig(
-        **config_fields(lm_mamba2.NemotronHConfig, cfg, **overrides)))
-
-
-def _qwen3_next_lm(cfg: dict, overrides: dict) -> nn.Module:
-    from mmlspark_tpu.models import lm_gdn
-    return lm_gdn.Qwen3NextLM(lm_gdn.Qwen3NextConfig(
-        **config_fields(lm_gdn.Qwen3NextConfig, cfg, **overrides)))
-
-
-# ``model_type`` -> the family's builder; a type that is not here is built
-# as a LatentMoELM (``mistral4`` / DeepSeek-V3-style keys)
-FAMILIES = {"lfm2_moe": _conv_lm, "jamba": _jamba_lm,
-            "nemotron_h": _nemotron_h_lm, "qwen3_next": _qwen3_next_lm}
+# ``model_type`` -> the family's module, its module class and its config
+# class; a type that is not here is built as a LatentMoELM (``mistral4`` /
+# DeepSeek-V3-style keys)
+FAMILIES = {"lfm2_moe": ("lm_conv", "ConvMoELM", "ConvLMConfig"),
+            "jamba": ("lm_ssm", "JambaLM", "JambaConfig"),
+            "nemotron_h": ("lm_mamba2", "NemotronHLM", "NemotronHConfig"),
+            "qwen3_next": ("lm_gdn", "Qwen3NextLM", "Qwen3NextConfig")}
 
 
 def from_config(cfg: dict, **overrides) -> nn.Module:
@@ -480,7 +476,15 @@ def from_config(cfg: dict, **overrides) -> nn.Module:
     same two keys, ``num_experts`` the held count), anything else a
     :class:`LatentMoELM` (the same two keys for a share);
     ``overrides`` are further fields of the module's config."""
-    return FAMILIES.get(cfg.get("model_type"), _latent_lm)(cfg, overrides)
+    if cfg.get("model_type") not in FAMILIES:
+        rope = tuple(sorted(cfg["rope_parameters"].items()))
+        return LatentMoELM(LMConfig(**config_fields(
+            LMConfig, cfg, **{"rope_parameters": rope, **overrides})))
+    name, module, config = FAMILIES[cfg["model_type"]]
+    family = importlib.import_module(f"mmlspark_tpu.models.{name}")
+    config = getattr(family, config)
+    return getattr(family, module)(config(**config_fields(config, cfg,
+                                                          **overrides)))
 
 
 def publish_expert_load(load, tokens: int) -> dict:

@@ -52,14 +52,15 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
 from mmlspark_tpu.models.lm import (
-    ExpertStacks, _fan_in_normal, _near_one, rms_norm, token_logprob,
+    ExpertStacks, _fan_in_normal, _near_one, lm_input, readout, rms_norm,
 )
-from mmlspark_tpu.obs.metrics import registry as _obs_registry
-from mmlspark_tpu.ops.pallas.attention import flash_attention
+from mmlspark_tpu.models.lm_layers import (
+    _at, _dot, attention_leaves, gated_mlp, grouped_attention, layer_counts,
+    leaf_makers,
+)
 # ``causal_taps`` is the convolution's array-code reference, kept under the
 # name both families' tests know it by
 from mmlspark_tpu.ops.pallas.causal_conv import (  # noqa: F401
@@ -151,30 +152,6 @@ def segments(kinds: tuple) -> list:
     return out
 
 
-def rope_tables(positions, dim: int, theta: float) -> tuple:
-    """``(cos, sin)`` ``[L, dim / 2]`` float32: angle ``p * theta^(-i /
-    (dim / 2))``, no scaling."""
-    inv = theta ** (-np.arange(dim // 2, dtype=np.float64) / (dim // 2))
-    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
-        inv, jnp.float32)[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
-
-
-def apply_rope_half(x, cos, sin):
-    """Rotate the pairs ``(x[i], x[i + dim / 2])`` of the last axis; ``x``
-    ``[B, L, heads, dim]`` float32, tables ``[L, dim / 2]``."""
-    half = x.shape[-1] // 2
-    a, b = x[..., :half], x[..., half:]
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
-def _dot(x, w, dtype):
-    """A bias-free product on ``dtype`` operands, float32 out."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=jnp.float32)
-
-
 def short_conv(p: dict, x, c: ConvLMConfig):
     """The gated short convolution on normed ``x`` ``[B, L, d]``."""
     d = c.hidden_size
@@ -186,43 +163,6 @@ def short_conv(p: dict, x, c: ConvLMConfig):
                         post_at=d, dtype=c.dtype)
     with jax.named_scope("lm/conv/out"):
         return _dot(y, p["out_proj"], c.dtype)
-
-
-def grouped_attention(p: dict, x, positions, c):
-    """Causal attention of ``c.num_attention_heads`` query heads on
-    ``c.num_key_value_heads`` key/value heads, on normed ``x`` ``[B, L,
-    d]``. What it does beyond the projections and the core follows what it
-    is handed: per-head q/k RMSNorms where the tree ``p`` holds their
-    scales (``q_norm`` / ``k_norm``; eps ``c.norm_eps``), half-split RoPE
-    (``c.rope_theta``) where ``positions`` are given and no positional term
-    where they are ``None``. The core's scope is ``lm/mqa/attention`` on one
-    key/value head, ``lm/gqa/attention`` on more."""
-    b, n, _ = x.shape
-    h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    q = _dot(x, p["q"], c.dtype).reshape(b, n, h, hd)
-    k = _dot(x, p["k"], c.dtype).reshape(b, n, hkv, hd)
-    v = _dot(x, p["v"], c.dtype).reshape(b, n, hkv, hd)
-    if "q_norm" in p:
-        q = rms_norm(q, p["q_norm"], c.norm_eps)
-        k = rms_norm(k, p["k_norm"], c.norm_eps)
-    if positions is not None:
-        cos, sin = rope_tables(positions, hd, c.rope_theta)
-        q, k = apply_rope_half(q, cos, sin), apply_rope_half(k, cos, sin)
-
-    def bhtd(a):
-        return a.astype(c.dtype).transpose(0, 2, 1, 3)
-
-    with jax.named_scope(f"lm/{'mqa' if hkv == 1 else 'gqa'}/attention"):
-        o = flash_attention(bhtd(q), bhtd(k), bhtd(v), causal=True,
-                            scale=hd ** -0.5)
-    return _dot(o.transpose(0, 2, 1, 3).reshape(b, n, h * hd), p["o"],
-                c.dtype)
-
-
-def gated_mlp(p: dict, x, dtype):
-    """``(silu(x W_gate) * x W_up) W_down``, float32 out."""
-    return _dot(jax.nn.silu(_dot(x, p["gate"], dtype))
-                * _dot(x, p["up"], dtype), p["down"], dtype)
 
 
 def expert_layer(router: dict, routed: dict, layer, x, c: ConvLMConfig):
@@ -244,26 +184,6 @@ def expert_layer(router: dict, routed: dict, layer, x, c: ConvLMConfig):
     return y.reshape(b, n, d), load, bucket
 
 
-def _at(stack, index):
-    """Layer ``index`` (static or traced) of every leaf of a kind's stack."""
-    return jax.tree.map(lambda v: jax.lax.dynamic_index_in_dim(
-        v, index, keepdims=False), stack)
-
-
-class KindStack(nn.Module):
-    """The weights of all ``layers`` layers of one kind, each leaf a
-    ``[layers, ...]`` stack; ``leaves``: ``(name, shape of one layer's,
-    initializer, dtype)``."""
-
-    layers: int
-    leaves: tuple
-
-    @nn.compact
-    def __call__(self) -> dict:
-        return {name: self.param(name, init, (self.layers,) + shape, dtype)
-                for name, shape, init, dtype in self.leaves}
-
-
 class ConvMoELM(nn.Module):
     """See the module docstring; build one with
     :func:`mmlspark_tpu.models.lm.from_config`."""
@@ -278,34 +198,17 @@ class ConvMoELM(nn.Module):
         kind no layer has is left empty), and say what was built."""
         c = self.cfg
         d, hd = c.hidden_size, c.head_dim
-        count = {kind: sum(kind in pair for pair in c.kinds)
-                 for kind in LAYER_KINDS + FF_KINDS}
-        reg = _obs_registry()
-        for kind in LAYER_KINDS:
-            reg.gauge("lm.layers", kind=kind).set(count[kind])
-        for kind in FF_KINDS:
-            reg.gauge("lm.ff", kind=kind).set(count[kind])
-
-        def kernel(name, *shape):
-            return (name, shape, _fan_in_normal, c.param_dtype)
-
-        def scale(name, *shape):
-            return (name, shape, _near_one, jnp.float32)
-
-        def stack(name, layers, *leaves):
-            return KindStack(layers, leaves, name=name)() if layers else {}
-
-        q_width = c.num_attention_heads * hd
-        kv_width = c.num_key_value_heads * hd
-        stacks = {
+        ops, ffs = zip(*c.kinds)
+        count = {**layer_counts(ops, LAYER_KINDS),
+                 **layer_counts(ffs, FF_KINDS, "lm.ff")}
+        kernel, f32, stack = leaf_makers(c.param_dtype)
+        return {
             "conv": stack(
                 "conv", count["conv"], kernel("in_proj", d, 3 * d),
                 kernel("taps", c.conv_L_cache, d), kernel("out_proj", d, d)),
             "attn": stack(
-                "attn", count["full_attention"], kernel("q", d, q_width),
-                kernel("k", d, kv_width), kernel("v", d, kv_width),
-                kernel("o", q_width, d), scale("q_norm", hd),
-                scale("k_norm", hd)),
+                "attn", count["full_attention"], *attention_leaves(c, kernel),
+                f32("q_norm", _near_one, hd), f32("k_norm", _near_one, hd)),
             "dense": stack(
                 "dense", count["dense"], kernel("gate", d, c.intermediate_size),
                 kernel("up", d, c.intermediate_size),
@@ -315,16 +218,15 @@ class ConvMoELM(nn.Module):
             # only; the stand-in is drawn so that it moves some
             "router": stack(
                 "router", count["moe"],
-                ("kernel", (d, c.num_experts), _fan_in_normal, jnp.float32),
-                ("bias", (c.num_experts,), jax.nn.initializers.normal(0.05),
-                 jnp.float32)),
+                f32("kernel", _fan_in_normal, d, c.num_experts),
+                f32("bias", jax.nn.initializers.normal(0.05), c.num_experts)),
             "routed": ExpertStacks(
                 count["moe"], c.num_experts, d, c.moe_intermediate_size,
                 c.param_dtype, name="routed")() if count["moe"] else {},
+            **stack("norms", c.num_hidden_layers,
+                    f32("operator_norm", _near_one, d),
+                    f32("ffn_norm", _near_one, d)),
         }
-        norms = stack("norms", c.num_hidden_layers, scale("operator_norm", d),
-                      scale("ffn_norm", d))
-        return {**stacks, **norms}
 
     def _block(self, stacks: dict, h, positions, kind: tuple, layer,
                index: dict):
@@ -359,8 +261,8 @@ class ConvMoELM(nn.Module):
 
     def _run(self, stacks: dict, h, positions):
         """Every layer, a run of :func:`segments` at a time; returns the
-        hidden state and the expert layers' ``load [n, B, E]`` and
-        ``bucket [n]`` in layer order."""
+        hidden state and the expert layers' ``(load [n, B, E], bucket
+        [n])`` in layer order."""
         c = self.cfg
         kinds = c.kinds
         # each layer's place among the layers of its own kinds
@@ -394,37 +296,18 @@ class ConvMoELM(nn.Module):
                 load, bucket = (jnp.stack(a, axis=1) for a in zip(*stats))
                 loads.append(load.reshape((-1,) + load.shape[2:]))
                 buckets.append(bucket.reshape(-1))
-        return h, jnp.concatenate(loads), jnp.concatenate(buckets)
+        return h, (jnp.concatenate(loads), jnp.concatenate(buckets))
 
     @nn.compact
     def __call__(self, x, output: str = "logits"):
-        if output not in self.OUTPUT_NAMES:
-            raise ValueError(f"unknown output node {output!r}; available: "
-                             f"{self.OUTPUT_NAMES}")
         c = self.cfg
-        tokens = x.astype(jnp.int32)
-        b, n = tokens.shape
         # as the head the table is a fan-in-normal [d, V] matrix (logits of
         # a unit-rms state have unit variance); the first operator_norm
         # rescales the rows it hands out as embeddings
-        table = nn.Embed(
-            c.vocab_size, c.hidden_size, param_dtype=c.param_dtype,
-            embedding_init=jax.nn.initializers.normal(c.hidden_size ** -0.5),
-            name="embed").embedding
-        h = jnp.take(table, tokens, axis=0).astype(c.dtype)
-        h, load, bucket = self._run(self._stacks(), h, jnp.arange(n))
-        if output == "expert_load":
-            # [expert layers, B, experts] -> a row's picks on each expert
-            return load.transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
-        if output == "moe_bucket":
-            return jnp.broadcast_to(bucket[None, :], (b, bucket.shape[0]))
-        h = rms_norm(h, self.param("embedding_norm", _near_one,
-                                   (c.hidden_size,), jnp.float32), c.norm_eps)
-        if output == "features":
-            return jnp.mean(h, axis=1)
-        head = table.astype(c.dtype).T
-        h = h.astype(c.dtype)
-        with jax.named_scope("lm/head"):
-            if output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-            return token_logprob(h, head, tokens, c.logprob_chunk)
+        tokens, table, h = lm_input(self, x, output, c.hidden_size ** -0.5)
+        h, stats = self._run(self._stacks(), h, jnp.arange(tokens.shape[1]))
+        return readout(
+            c, output, tokens, h, stats,
+            lambda h: rms_norm(h, self.param(
+                "embedding_norm", _near_one, (c.hidden_size,), jnp.float32),
+                c.norm_eps), table)

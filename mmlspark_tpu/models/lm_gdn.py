@@ -47,10 +47,10 @@ the family's zero-centred norm:
 layers in layer order, ``attn/*`` the attention layers, ``router/*``,
 ``routed/*`` (``[layers, held, ...]``, read in place), ``shared/*`` every
 layer's experts, ``norms/*`` every layer's two norms; ``embed``, ``head``,
-``norm_f``. **The stack runs as ONE ``lax.scan`` over all layers** whose body
-picks its mixer by ``lax.switch`` and then runs the expert layer (as
-:mod:`~mmlspark_tpu.models.lm_mamba2` does): each kernel is ONE instruction
-of the program whose device time sums all its layers.
+``norm_f``. **The stack runs as ONE ``lax.scan`` over all layers**
+(:func:`~mmlspark_tpu.models.lm_layers.run_layers`: the mixer by the
+layer's kind, then the expert layer): each kernel is ONE instruction of the
+program whose device time sums all its layers.
 
 Input, output nodes and dtypes as :class:`~mmlspark_tpu.models.lm.
 LatentMoELM`: ``features``, ``expert_load`` (``[B, layers * held]``),
@@ -64,26 +64,21 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
 from mmlspark_tpu.models.lm import (
-    ExpertStacks, Kernel, _fan_in_normal, _near_one, rms_norm, token_logprob,
+    ExpertStacks, _fan_in_normal, _near_one, lm_input, readout, rms_norm,
 )
-from mmlspark_tpu.models.lm_conv import (
-    KindStack, _at, _dot, apply_rope_half, gated_mlp, rope_tables,
+from mmlspark_tpu.models.lm_layers import (
+    _a_log, _at, _dot, _dt_bias, apply_rope_half, gated_mlp, layer_counts,
+    leaf_makers, rope_tables, run_layers,
 )
-from mmlspark_tpu.models.lm_mamba2 import _a_log, _dt_bias
-from mmlspark_tpu.obs.metrics import registry as _obs_registry
 from mmlspark_tpu.ops.pallas.attention import flash_attention
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
 from mmlspark_tpu.ops.pallas.gated_delta import gated_delta
 from mmlspark_tpu.parallel.moe import moe_dropless
 
 LAYER_KINDS = ("gdn", "attention")
-# the step sizes a fresh Gated DeltaNet head starts from, as the Mamba
-# layers' (a head forgets over 1 to 1,000 positions)
-DT_RANGE = (1e-3, 1e-1, 1e-4)
 
 
 def _zero_centred(key, shape, dtype):
@@ -315,21 +310,10 @@ class Qwen3NextLM(nn.Module):
         kind no layer has is left empty), and say what was built."""
         c = self.cfg
         d, hd, layers = c.hidden_size, c.head_dim, c.num_hidden_layers
-        count = {kind: c.kinds.count(kind) for kind in LAYER_KINDS}
-        reg = _obs_registry()
-        for kind in LAYER_KINDS:
-            reg.gauge("lm.layers", kind=kind).set(count[kind])
-        reg.gauge("lm.layers", kind="moe").set(layers)
-
-        def kernel(name, *shape):
-            return (name, shape, _fan_in_normal, c.param_dtype)
-
-        def f32(name, init, *shape):
-            return (name, shape, init, jnp.float32)
-
-        def stack(name, n, *leaves):
-            return KindStack(n, leaves, name=name)() if n else {}
-
+        # every layer has its expert layer besides its mixer
+        count = layer_counts(c.kinds + ("moe",) * layers,
+                             LAYER_KINDS + ("moe",))
+        kernel, f32, stack = leaf_makers(c.param_dtype)
         hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
         dv = c.linear_value_head_dim
         fs = c.shared_expert_intermediate_size
@@ -340,7 +324,7 @@ class Qwen3NextLM(nn.Module):
                 kernel("in_proj_ba", d, 2 * hv),
                 kernel("conv_taps", c.linear_conv_kernel_dim,
                        hk * c.conv_group),
-                f32("dt_bias", _dt_bias(*DT_RANGE), hv),
+                f32("dt_bias", _dt_bias(), hv),
                 f32("A_log", _a_log, hv), f32("norm", _near_one, dv),
                 kernel("out_proj", hv * dv, d)),
             "attn": stack(
@@ -353,8 +337,7 @@ class Qwen3NextLM(nn.Module):
                 f32("k_norm", _zero_centred, hd)),
             "router": stack(
                 "router", layers,
-                ("kernel", (d, c.routed_width), _fan_in_normal,
-                 jnp.float32)),
+                f32("kernel", _fan_in_normal, d, c.routed_width)),
             "routed": ExpertStacks(layers, c.num_experts, d,
                                    c.moe_intermediate_size, c.param_dtype,
                                    name="routed")(),
@@ -365,73 +348,32 @@ class Qwen3NextLM(nn.Module):
                     f32("post_norm", _zero_centred, d)),
         }
 
-    def _run(self, stacks: dict, h):
-        """Every layer, as one scan whose body picks its mixer and then runs
-        the expert layer; returns the hidden state and ``load [layers, B,
-        held]`` and ``bucket [layers]``."""
+    @nn.compact
+    def __call__(self, x, output: str = "logits"):
         c = self.cfg
-        kinds = c.kinds
-        present = [k for k in LAYER_KINDS if k in kinds]
-        # each layer's place among the layers of its own kind
-        index = np.asarray([kinds[:i].count(k) for i, k in enumerate(kinds)],
-                           np.int32)
-        branch = np.asarray([present.index(k) for k in kinds], np.int32)
         eps = c.rms_norm_eps
+        tokens, _, h = lm_input(self, x, output, 1.0)
+        stacks = self._stacks()
 
-        def block(h, xs):
-            layer, which, at = xs
-            x = rms0(h, _at(stacks["input_norm"], layer), eps)
+        def gdn(at, x):
+            with jax.named_scope("lm/gdn"):
+                return gdn_mixer(_at(stacks["gdn"], at), x, c)
 
-            def gdn():
-                with jax.named_scope("lm/gdn"):
-                    return gdn_mixer(_at(stacks["gdn"], at), x, c)
+        def attention(at, x):
+            with jax.named_scope("lm/gqa"):
+                return gated_attention(_at(stacks["attn"], at), x, c)
 
-            def attention():
-                with jax.named_scope("lm/gqa"):
-                    return gated_attention(_at(stacks["attn"], at), x, c)
-
-            mixers = {"gdn": gdn, "attention": attention}
-            branches = [mixers[k] for k in present]
-            mixed = (branches[0]() if len(branches) == 1
-                     else jax.lax.switch(which, branches))
-            h = (h.astype(jnp.float32) + mixed).astype(c.dtype)
-            y, load, bucket = expert_layer(
+        def experts(h, layer):
+            return expert_layer(
                 _at(stacks["router"], layer), stacks["routed"],
                 _at(stacks["shared"], layer), layer,
                 rms0(h, _at(stacks["post_norm"], layer), eps), c)
-            return (h.astype(jnp.float32) + y).astype(c.dtype), (load, bucket)
 
-        return jax.lax.scan(block, h, (jnp.arange(len(kinds)),
-                                       jnp.asarray(branch),
-                                       jnp.asarray(index)))
-
-    @nn.compact
-    def __call__(self, x, output: str = "logits"):
-        if output not in self.OUTPUT_NAMES:
-            raise ValueError(f"unknown output node {output!r}; available: "
-                             f"{self.OUTPUT_NAMES}")
-        c = self.cfg
-        tokens = x.astype(jnp.int32)
-        b = tokens.shape[0]
-        table = nn.Embed(c.vocab_size, c.hidden_size,
-                         param_dtype=c.param_dtype,
-                         embedding_init=jax.nn.initializers.normal(1.0),
-                         name="embed").embedding
-        h = jnp.take(table, tokens, axis=0).astype(c.dtype)
-        h, (load, bucket) = self._run(self._stacks(), h)
-        if output == "expert_load":
-            # [layers, B, held] -> a row's picks on each held expert
-            return load.transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
-        if output == "moe_bucket":
-            return jnp.broadcast_to(bucket[None, :], (b, bucket.shape[0]))
-        h = rms0(h, self.param("norm_f", _zero_centred, (c.hidden_size,),
-                               jnp.float32), c.rms_norm_eps)
-        if output == "features":
-            return jnp.mean(h, axis=1)
-        head = Kernel((c.hidden_size, c.vocab_size), c.param_dtype,
-                      name="head")().astype(c.dtype)
-        h = h.astype(c.dtype)
-        with jax.named_scope("lm/head"):
-            if output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-            return token_logprob(h, head, tokens, c.logprob_chunk)
+        h, stats = run_layers(
+            h, c.kinds,
+            lambda h, i: rms0(h, _at(stacks["input_norm"], i), eps),
+            {"gdn": gdn, "attention": attention}, c.dtype, after=experts)
+        return readout(
+            c, output, tokens, h, stats,
+            lambda h: rms0(h, self.param(
+                "norm_f", _zero_centred, (c.hidden_size,), jnp.float32), eps))

@@ -38,7 +38,7 @@ names (``docs/lm.md`` has the equations):
 * **attention** (``*``): ``num_attention_heads`` query heads of
   ``head_dim`` (a key of its own: 128, not ``hidden_size / heads``) on
   ``num_key_value_heads`` key/value heads, NO positional term
-  (:func:`~mmlspark_tpu.models.lm_conv.grouped_attention` handed no
+  (:func:`~mmlspark_tpu.models.lm_layers.grouped_attention` handed no
   positions and a tree without norm leaves; the Mamba layers carry
   position).
 
@@ -46,9 +46,9 @@ names (``docs/lm.md`` has the equations):
 layer order, ``attn/*`` the attention layers, ``router/*``, ``routed/*``
 (``[expert layers, held, ...]``, read in place) and ``shared/*`` the expert
 layers, ``norms/norm`` every layer; ``embed``, ``head``, ``norm_f``. **The
-stack runs as ONE ``lax.scan`` over all layers** whose body picks its mixer
-by ``lax.switch`` on the layer's kind (as :mod:`~mmlspark_tpu.models.lm_ssm`
-does with two): any layer order, one body to trace, and each kernel ONE
+stack runs as ONE ``lax.scan`` over all layers**
+(:func:`~mmlspark_tpu.models.lm_layers.run_layers`: the mixer by the
+layer's kind): any layer order, one body to trace, and each kernel ONE
 instruction of the program whose device time sums all its layers.
 
 Input, output nodes and dtypes as :class:`~mmlspark_tpu.models.lm.
@@ -59,7 +59,6 @@ held]``), ``moe_bucket``, ``token_logprob``, ``logits``.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
@@ -68,10 +67,12 @@ import numpy as np
 from flax import linen as nn
 
 from mmlspark_tpu.models.lm import (
-    ExpertStacks, Kernel, _fan_in_normal, _near_one, rms_norm, token_logprob,
+    ExpertStacks, _fan_in_normal, _near_one, lm_input, readout, rms_norm,
 )
-from mmlspark_tpu.models.lm_conv import KindStack, _at, _dot, grouped_attention
-from mmlspark_tpu.obs.metrics import registry as _obs_registry
+from mmlspark_tpu.models.lm_layers import (
+    _a_log, _at, _dot, _dt_bias, attention_leaves, grouped_attention,
+    layer_counts, leaf_makers, run_layers,
+)
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
 from mmlspark_tpu.ops.pallas.ssd_scan import ssd_scan
 from mmlspark_tpu.parallel.moe import _activation, moe_dropless
@@ -83,9 +84,6 @@ LAYER_KINDS = tuple(PATTERN.values())
 # the guard in the normalisation of the picked weights (the family's public
 # implementation; no key of the configuration states it)
 NORM_TOPK_EPS = 1e-20
-# the decay rates a fresh layer's heads start from (Dao & Gu,
-# arXiv:2405.21060: ``A`` uniform over a range, one scalar a head)
-A_INIT = (1.0, 16.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,23 +181,6 @@ class NemotronHConfig:
         return tuple(PATTERN[ch] for ch in self.hybrid_override_pattern)
 
 
-def _a_log(key, shape, dtype):
-    """``log A``, ``A`` uniform over ``A_INIT``: one decay rate a head."""
-    return jnp.log(jax.random.uniform(key, shape, jnp.float32, *A_INIT)
-                   ).astype(dtype)
-
-
-def _dt_bias(lo: float, hi: float, floor: float):
-    """``softplus^-1`` of step sizes log-uniform over ``[lo, hi]``, floored
-    at ``floor``."""
-    def init(key, shape, dtype):
-        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
-                                        math.log(lo), math.log(hi)))
-        dt = jnp.maximum(dt, floor)
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return init
-
-
 def gated_group_norm(y, z, scale, groups: int, eps: float):
     """``rms(y * silu(z))`` within each of ``groups`` groups of the last
     axis, times ``scale``: the gate first, then the norm; float32."""
@@ -272,24 +253,11 @@ class NemotronHLM(nn.Module):
         """Declare the by-kind stacks, ``kind -> {leaf: [layers, ...]}`` (a
         kind no layer has is left empty), and say what was built."""
         c = self.cfg
-        d, d_i, hd = c.hidden_size, c.d_inner, c.head_dim
-        count = {kind: c.kinds.count(kind) for kind in LAYER_KINDS}
-        for kind in LAYER_KINDS:
-            _obs_registry().gauge("lm.layers", kind=kind).set(count[kind])
-
-        def kernel(name, *shape):
-            return (name, shape, _fan_in_normal, c.param_dtype)
-
-        def f32(name, init, *shape):
-            return (name, shape, init, jnp.float32)
-
-        def stack(name, layers, *leaves):
-            return KindStack(layers, leaves, name=name)() if layers else {}
-
-        q_width = c.num_attention_heads * hd
-        kv_width = c.num_key_value_heads * hd
+        d, d_i = c.hidden_size, c.d_inner
+        count = layer_counts(c.kinds, LAYER_KINDS)
+        kernel, f32, stack = leaf_makers(c.param_dtype)
         shared = c.n_shared_experts * c.moe_shared_expert_intermediate_size
-        stacks = {
+        return {
             "mamba2": stack(
                 "mamba2", count["mamba2"],
                 kernel("in_proj", d, d_i + c.conv_dim + c.mamba_num_heads),
@@ -301,18 +269,15 @@ class NemotronHLM(nn.Module):
                 f32("A_log", _a_log, c.mamba_num_heads),
                 f32("D", _near_one, c.mamba_num_heads),
                 f32("norm", _near_one, d_i), kernel("out_proj", d_i, d)),
-            "attn": stack(
-                "attn", count["attention"], kernel("q", d, q_width),
-                kernel("k", d, kv_width), kernel("v", d, kv_width),
-                kernel("o", q_width, d)),
+            "attn": stack("attn", count["attention"],
+                          *attention_leaves(c, kernel)),
             # ``bias`` is a buffer of the checkpoint (the load balancer's
             # running correction), not a trained weight: it moves the picks
             # only; the stand-in is drawn so that it moves some
             "router": stack(
                 "router", count["moe"],
-                ("kernel", (d, c.routed_width), _fan_in_normal, jnp.float32),
-                ("bias", (c.routed_width,), jax.nn.initializers.normal(0.05),
-                 jnp.float32)),
+                f32("kernel", _fan_in_normal, d, c.routed_width),
+                f32("bias", jax.nn.initializers.normal(0.05), c.routed_width)),
             "routed": ExpertStacks(
                 count["moe"], c.n_routed_experts, d, c.moe_intermediate_size,
                 c.param_dtype, gated=False, name="routed")()
@@ -320,83 +285,36 @@ class NemotronHLM(nn.Module):
             "shared": stack(
                 "shared", count["moe"] if shared else 0,
                 kernel("up", d, shared), kernel("down", shared, d)),
+            **stack("norms", c.num_hidden_layers, f32("norm", _near_one, d)),
         }
-        norms = stack("norms", c.num_hidden_layers, f32("norm", _near_one, d))
-        return {**stacks, **norms}
-
-    def _run(self, stacks: dict, h):
-        """Every layer, as one scan whose body picks its mixer; returns the
-        hidden state and the expert layers' ``load [n, B, held]`` and
-        ``bucket [n]`` in layer order."""
-        c = self.cfg
-        kinds = c.kinds
-        present = [k for k in LAYER_KINDS if k in kinds]
-        # each layer's place among the layers of its own kind
-        index = np.asarray([kinds[:i].count(k) for i, k in enumerate(kinds)],
-                           np.int32)
-        branch = np.asarray([present.index(k) for k in kinds], np.int32)
-        no_stats = (jnp.zeros((h.shape[0], c.n_routed_experts), jnp.int32),
-                    jnp.zeros((), jnp.int32))
-
-        def block(h, xs):
-            layer, which, at = xs
-            x = rms_norm(h, _at(stacks["norm"], layer), c.layer_norm_epsilon)
-
-            def mamba2():
-                with jax.named_scope("lm/mamba2"):
-                    return mamba2_mixer(_at(stacks["mamba2"], at), x,
-                                        c), no_stats
-
-            def moe():
-                y, load, bucket = expert_layer(
-                    _at(stacks["router"], at), stacks["routed"],
-                    _at(stacks["shared"], at), at, x, c)
-                return y, (load, bucket)
-
-            def attention():
-                with jax.named_scope("lm/gqa"):
-                    return grouped_attention(_at(stacks["attn"], at), x,
-                                             None, c), no_stats
-
-            mixers = {"mamba2": mamba2, "moe": moe, "attention": attention}
-            branches = [mixers[k] for k in present]
-            mixed, stats = (branches[0]() if len(branches) == 1
-                            else jax.lax.switch(which, branches))
-            return (h.astype(jnp.float32) + mixed).astype(c.dtype), stats
-
-        h, (load, bucket) = jax.lax.scan(
-            block, h, (jnp.arange(len(kinds)), jnp.asarray(branch),
-                       jnp.asarray(index)))
-        experts = np.flatnonzero(np.asarray(kinds) == "moe")
-        return h, load[experts], bucket[experts]
 
     @nn.compact
     def __call__(self, x, output: str = "logits"):
-        if output not in self.OUTPUT_NAMES:
-            raise ValueError(f"unknown output node {output!r}; available: "
-                             f"{self.OUTPUT_NAMES}")
         c = self.cfg
-        tokens = x.astype(jnp.int32)
-        b = tokens.shape[0]
-        table = nn.Embed(c.vocab_size, c.hidden_size,
-                         param_dtype=c.param_dtype,
-                         embedding_init=jax.nn.initializers.normal(1.0),
-                         name="embed").embedding
-        h = jnp.take(table, tokens, axis=0).astype(c.dtype)
-        h, load, bucket = self._run(self._stacks(), h)
-        if output == "expert_load":
-            # [expert layers, B, held] -> a row's picks on each held expert
-            return load.transpose(1, 0, 2).reshape(b, -1).astype(jnp.float32)
-        if output == "moe_bucket":
-            return jnp.broadcast_to(bucket[None, :], (b, bucket.shape[0]))
-        h = rms_norm(h, self.param("norm_f", _near_one, (c.hidden_size,),
-                                   jnp.float32), c.layer_norm_epsilon)
-        if output == "features":
-            return jnp.mean(h, axis=1)
-        head = Kernel((c.hidden_size, c.vocab_size), c.param_dtype,
-                      name="head")().astype(c.dtype)
-        h = h.astype(c.dtype)
-        with jax.named_scope("lm/head"):
-            if output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-            return token_logprob(h, head, tokens, c.logprob_chunk)
+        tokens, _, h = lm_input(self, x, output, 1.0)
+        stacks = self._stacks()
+
+        def mamba2(at, x):
+            with jax.named_scope("lm/mamba2"):
+                return mamba2_mixer(_at(stacks["mamba2"], at), x, c)
+
+        def moe(at, x):
+            return expert_layer(_at(stacks["router"], at), stacks["routed"],
+                                _at(stacks["shared"], at), at, x, c)
+
+        def attention(at, x):
+            with jax.named_scope("lm/gqa"):
+                return grouped_attention(_at(stacks["attn"], at), x, None, c)
+
+        h, (load, bucket) = run_layers(
+            h, c.kinds, lambda h, i: rms_norm(h, _at(stacks["norm"], i),
+                                              c.layer_norm_epsilon),
+            {"mamba2": mamba2, "moe": moe, "attention": attention}, c.dtype,
+            no_stats=(jnp.zeros((h.shape[0], c.n_routed_experts), jnp.int32),
+                      jnp.zeros((), jnp.int32)))
+        experts = np.flatnonzero(np.asarray(c.kinds) == "moe")
+        return readout(
+            c, output, tokens, h, (load[experts], bucket[experts]),
+            lambda h: rms_norm(h, self.param(
+                "norm_f", _near_one, (c.hidden_size,), jnp.float32),
+                c.layer_norm_epsilon))

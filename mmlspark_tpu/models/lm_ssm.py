@@ -20,7 +20,7 @@ from the configuration's own key names (``docs/lm.md`` has the equations):
 * **attention**: ``num_attention_heads`` query heads on
   ``num_key_value_heads`` key/value heads (20 on ONE in the published
   model) and NO positional term of any kind, no q/k norm:
-  :func:`~mmlspark_tpu.models.lm_conv.grouped_attention` handed no
+  :func:`~mmlspark_tpu.models.lm_layers.grouped_attention` handed no
   positions and a tree without norm leaves (the Mamba layers carry
   position);
 * ``num_experts`` must be 1 (every feed-forward layer the dense MLP): a
@@ -29,10 +29,9 @@ from the configuration's own key names (``docs/lm.md`` has the equations):
 **The parameter tree is by kind** (as :mod:`~mmlspark_tpu.models.lm_conv`):
 ``mamba/*`` stacks the Mamba layers in layer order, ``attn/*`` the
 attention layers, ``dense/*`` and ``norms/*`` every layer. **The stack runs
-as ONE ``lax.scan`` over all layers** whose body picks the mixer by
-``lax.cond`` on the layer's kind (static per-layer arrays of kind and
-index-within-kind ride along as the scan's inputs; the by-kind stacks are
-indexed inside the branch): any layer order, one body to compile, and each
+as ONE ``lax.scan`` over all layers**
+(:func:`~mmlspark_tpu.models.lm_layers.run_layers`: the mixer by the
+layer's kind, then the MLP): any layer order, one body to compile, and each
 kernel ONE instruction of the program whose device time sums all its
 layers.
 
@@ -44,29 +43,21 @@ nodes ``features``, ``token_logprob``, ``logits`` (there is no router, so no
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
-from mmlspark_tpu.models.lm import (
-    _fan_in_normal, _near_one, rms_norm, token_logprob,
+from mmlspark_tpu.models.lm import _near_one, lm_input, readout, rms_norm
+from mmlspark_tpu.models.lm_layers import (
+    _at, _dot, _dt_bias, attention_leaves, gated_mlp, grouped_attention,
+    layer_counts, leaf_makers, run_layers,
 )
-from mmlspark_tpu.models.lm_conv import (
-    KindStack, _at, _dot, gated_mlp, grouped_attention,
-)
-from mmlspark_tpu.obs.metrics import registry as _obs_registry
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv
 from mmlspark_tpu.ops.pallas.selective_scan import selective_scan
 
 LAYER_KINDS = ("mamba", "attention")
-# the step sizes a fresh Mamba layer starts from (Gu & Dao, arXiv:2312.00752,
-# section 3.6): log-uniform, so that a channel forgets over 10 to 1,000+
-# positions
-DT_INIT = (1e-3, 1e-1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,13 +126,6 @@ def _a_log(key, shape, dtype):
             ).astype(dtype)
 
 
-def _dt_bias(key, shape, dtype):
-    """``softplus^-1`` of step sizes log-uniform over ``DT_INIT``."""
-    lo, hi = (math.log(v) for v in DT_INIT)
-    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
 def mamba_mixer(p: dict, x, c: JambaConfig):
     """The Mamba mixer on normed ``x`` ``[B, L, d]``; float32 out."""
     d_i, n, r = c.d_inner, c.mamba_d_state, c.mamba_dt_rank
@@ -179,24 +163,11 @@ class JambaLM(nn.Module):
         """Declare the by-kind stacks, ``kind -> {leaf: [layers, ...]}`` (a
         kind no layer has is left empty), and say what was built."""
         c = self.cfg
-        d, d_i, hd = c.hidden_size, c.d_inner, c.head_dim
+        d, d_i = c.hidden_size, c.d_inner
         n, r = c.mamba_d_state, c.mamba_dt_rank
-        count = {kind: c.kinds.count(kind) for kind in LAYER_KINDS}
-        for kind in LAYER_KINDS:
-            _obs_registry().gauge("lm.layers", kind=kind).set(count[kind])
-
-        def kernel(name, *shape):
-            return (name, shape, _fan_in_normal, c.param_dtype)
-
-        def f32(name, init, *shape):
-            return (name, shape, init, jnp.float32)
-
-        def stack(name, layers, *leaves):
-            return KindStack(layers, leaves, name=name)() if layers else {}
-
-        q_width = c.num_attention_heads * hd
-        kv_width = c.num_key_value_heads * hd
-        stacks = {
+        count = layer_counts(c.kinds, LAYER_KINDS)
+        kernel, f32, stack = leaf_makers(c.param_dtype)
+        return {
             "mamba": stack(
                 "mamba", count["mamba"], kernel("in_proj", d, 2 * d_i),
                 kernel("conv_taps", c.mamba_d_conv, d_i),
@@ -204,87 +175,49 @@ class JambaLM(nn.Module):
                 kernel("x_proj", d_i, r + 2 * n),
                 f32("dt_norm", _near_one, r), f32("b_norm", _near_one, n),
                 f32("c_norm", _near_one, n), kernel("dt_proj", r, d_i),
-                f32("dt_bias", _dt_bias, d_i), f32("A_log", _a_log, d_i, n),
+                f32("dt_bias", _dt_bias(), d_i),
+                f32("A_log", _a_log, d_i, n),
                 f32("D", _near_one, d_i), kernel("out_proj", d_i, d)),
-            "attn": stack(
-                "attn", count["attention"], kernel("q", d, q_width),
-                kernel("k", d, kv_width), kernel("v", d, kv_width),
-                kernel("o", q_width, d)),
+            "attn": stack("attn", count["attention"],
+                          *attention_leaves(c, kernel)),
             "dense": stack(
                 "dense", c.num_hidden_layers,
                 kernel("gate", d, c.intermediate_size),
                 kernel("up", d, c.intermediate_size),
                 kernel("down", c.intermediate_size, d)),
+            **stack("norms", c.num_hidden_layers,
+                    f32("input_layernorm", _near_one, d),
+                    f32("pre_ff_layernorm", _near_one, d)),
         }
-        norms = stack("norms", c.num_hidden_layers,
-                      f32("input_layernorm", _near_one, d),
-                      f32("pre_ff_layernorm", _near_one, d))
-        return {**stacks, **norms}
-
-    def _run(self, stacks: dict, h):
-        """Every layer, as one scan whose body picks its mixer."""
-        c = self.cfg
-        kinds = c.kinds
-        # each layer's place among the layers of its own kind
-        index = np.asarray([kinds[:i].count(k) for i, k in enumerate(kinds)],
-                           np.int32)
-        attention = np.asarray([k == "attention" for k in kinds])
-
-        def block(h, xs):
-            layer, is_attention, at = xs
-            x = rms_norm(h, _at(stacks["input_layernorm"], layer),
-                         c.rms_norm_eps)
-
-            def mamba():
-                with jax.named_scope("lm/mamba"):
-                    return mamba_mixer(_at(stacks["mamba"], at), x, c)
-
-            def mqa():
-                with jax.named_scope("lm/mqa"):
-                    return grouped_attention(_at(stacks["attn"], at), x,
-                                             None, c)
-
-            if attention.all():
-                mixed = mqa()
-            elif not attention.any():
-                mixed = mamba()
-            else:
-                mixed = jax.lax.cond(is_attention, mqa, mamba)
-            h = (h.astype(jnp.float32) + mixed).astype(c.dtype)
-            x = rms_norm(h, _at(stacks["pre_ff_layernorm"], layer),
-                         c.rms_norm_eps)
-            with jax.named_scope("lm/dense"):
-                y = gated_mlp(_at(stacks["dense"], layer), x, c.dtype)
-            return (h.astype(jnp.float32) + y).astype(c.dtype), None
-
-        h, _ = jax.lax.scan(
-            block, h, (jnp.arange(len(kinds)), jnp.asarray(attention),
-                       jnp.asarray(index)))
-        return h
 
     @nn.compact
     def __call__(self, x, output: str = "logits"):
-        if output not in self.OUTPUT_NAMES:
-            raise ValueError(f"unknown output node {output!r}; available: "
-                             f"{self.OUTPUT_NAMES}")
         c = self.cfg
-        tokens = x.astype(jnp.int32)
+        eps = c.rms_norm_eps
         # as the head the table is a fan-in-normal [d, V] matrix; the first
         # input_layernorm rescales the rows it hands out as embeddings
-        table = nn.Embed(
-            c.vocab_size, c.hidden_size, param_dtype=c.param_dtype,
-            embedding_init=jax.nn.initializers.normal(c.hidden_size ** -0.5),
-            name="embed").embedding
-        h = jnp.take(table, tokens, axis=0).astype(c.dtype)
-        h = self._run(self._stacks(), h)
-        h = rms_norm(h, self.param("final_layernorm", _near_one,
-                                   (c.hidden_size,), jnp.float32),
-                     c.rms_norm_eps)
-        if output == "features":
-            return jnp.mean(h, axis=1)
-        head = table.astype(c.dtype).T
-        h = h.astype(c.dtype)
-        with jax.named_scope("lm/head"):
-            if output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-            return token_logprob(h, head, tokens, c.logprob_chunk)
+        tokens, table, h = lm_input(self, x, output, c.hidden_size ** -0.5)
+        stacks = self._stacks()
+
+        def mamba(at, x):
+            with jax.named_scope("lm/mamba"):
+                return mamba_mixer(_at(stacks["mamba"], at), x, c)
+
+        def mqa(at, x):
+            with jax.named_scope("lm/mqa"):
+                return grouped_attention(_at(stacks["attn"], at), x, None, c)
+
+        def mlp(h, layer):
+            x = rms_norm(h, _at(stacks["pre_ff_layernorm"], layer), eps)
+            with jax.named_scope("lm/dense"):
+                return gated_mlp(_at(stacks["dense"], layer), x, c.dtype)
+
+        h, _ = run_layers(
+            h, c.kinds,
+            lambda h, i: rms_norm(h, _at(stacks["input_layernorm"], i), eps),
+            {"mamba": mamba, "attention": mqa}, c.dtype, after=mlp)
+        return readout(
+            c, output, tokens, h, None,
+            lambda h: rms_norm(h, self.param(
+                "final_layernorm", _near_one, (c.hidden_size,), jnp.float32),
+                eps), table)

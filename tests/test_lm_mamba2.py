@@ -24,7 +24,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from mmlspark_tpu.models import lm, lm_mamba2  # noqa: E402
-from mmlspark_tpu.models.lm_conv import grouped_attention  # noqa: E402
+from mmlspark_tpu.models.lm_layers import grouped_attention  # noqa: E402
 from mmlspark_tpu.obs.metrics import registry  # noqa: E402
 from mmlspark_tpu.ops.pallas import ssd_scan as ss  # noqa: E402
 from mmlspark_tpu.ops.pallas.causal_conv import causal_conv  # noqa: E402

@@ -22,7 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark.reference import jamba as ref  # noqa: E402
-from mmlspark_tpu.models import lm, lm_conv, lm_ssm  # noqa: E402
+from mmlspark_tpu.models import lm, lm_conv, lm_layers, lm_ssm  # noqa: E402
 from mmlspark_tpu.obs.metrics import registry  # noqa: E402
 from mmlspark_tpu.ops.pallas import budget, selective_scan as ss  # noqa: E402
 
@@ -355,7 +355,7 @@ def test_the_mixer_matches_the_reference_and_the_control_fails(seeded):
     at = ref.layers_of(cfg, "mamba").index(layer)
     with jax.default_matmul_precision("highest"):
         got = np.asarray(lm_ssm.mamba_mixer(
-            lm_conv._at(tree["mamba"], at), x, c))
+            lm_layers._at(tree["mamba"], at), x, c))
         want = np.stack([np.asarray(ref.mamba_mixer(
             params["layers"][layer], row, cfg)) for row in x])
         control = np.stack([np.asarray(ref.mamba_mixer(
@@ -373,8 +373,8 @@ def test_attention_without_positions_matches_the_reference(seeded):
     layer = 7
     at = ref.layers_of(cfg, "attention").index(layer)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(lm_conv.grouped_attention(
-            lm_conv._at(tree["attn"], at), x, None, c))
+        got = np.asarray(lm_layers.grouped_attention(
+            lm_layers._at(tree["attn"], at), x, None, c))
         want = np.stack([np.asarray(ref.attention(
             params["layers"][layer], row, cfg)) for row in x])
         control = np.stack([np.asarray(ref.attention(
@@ -465,7 +465,7 @@ def test_short_conv_answers_to_the_bit_as_before_the_tap_loop_moved():
         # ``lm_conv.short_conv`` as it stood before ``causal_taps``
         d, taps = c.hidden_size, p["taps"].astype(jnp.float32)
         n = x.shape[1]
-        bcu = lm_conv._dot(x, p["in_proj"], c.dtype)
+        bcu = lm_layers._dot(x, p["in_proj"], c.dtype)
         gate_b, gate_c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
         z = gate_b * u
         lead = taps.shape[0] - 1
@@ -473,8 +473,8 @@ def test_short_conv_answers_to_the_bit_as_before_the_tap_loop_moved():
         mixed = taps[lead] * z
         for j in range(lead):
             mixed = mixed + taps[j] * padded[:, j:j + n]
-        return lm_conv._dot((gate_c * mixed).astype(c.dtype), p["out_proj"],
-                            c.dtype)
+        return lm_layers._dot((gate_c * mixed).astype(c.dtype),
+                              p["out_proj"], c.dtype)
 
     np.testing.assert_array_equal(
         np.asarray(jax.jit(lm_conv.short_conv, static_argnums=2)(p, x, c)),
@@ -511,10 +511,10 @@ def test_attention_follows_what_it_is_handed():
          "o": r.standard_normal((8 * hd, d)) / 8}
     p = {k: jnp.asarray(v, jnp.float32) for k, v in p.items()}
     x = jnp.asarray(r.standard_normal((1, 10, d)), jnp.float32)
-    plain = np.asarray(lm_conv.grouped_attention(p, x, None, c))
+    plain = np.asarray(lm_layers.grouped_attention(p, x, None, c))
     ones = dict(p, q_norm=jnp.ones(hd), k_norm=jnp.ones(hd))
-    normed = np.asarray(lm_conv.grouped_attention(ones, x, None, c))
-    roped = np.asarray(lm_conv.grouped_attention(p, x, jnp.arange(10), c))
+    normed = np.asarray(lm_layers.grouped_attention(ones, x, None, c))
+    roped = np.asarray(lm_layers.grouped_attention(p, x, jnp.arange(10), c))
     # position 0 attends to itself alone and its rotation is the identity;
     # later positions see the norm and the rotation
     np.testing.assert_allclose(roped[:, 0], plain[:, 0], atol=1e-6)
